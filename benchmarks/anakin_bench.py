@@ -284,57 +284,57 @@ def bench_anakin_population(
     }
 
 
-def _compile_probe(num_envs: int, rollout_steps: int, cache_dir: Optional[str]) -> None:
-    """Child-process half of the compile bench: optionally enable the persistent
-    cache, then time the FIRST dispatch (trace + compile + execute) of the fused
-    PPO program and print one JSON line."""
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+def _compile_probe(num_envs: int, rollout_steps: int, cache_dir: str) -> None:
+    """Child-process half of the compile bench: enable the persistent cache
+    through the shared resolver, then time the FIRST dispatch (trace + compile +
+    execute) of the fused PPO program and print one JSON line naming the device
+    this process measured on."""
+    from sheeprl_tpu.parallel.mesh import device_identity
+    from sheeprl_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache({"enabled": True, "dir": cache_dir})
     iteration, member_carry = _population_setup(num_envs, rollout_steps, seed=0)
     dispatch = jax.jit(iteration, donate_argnums=(0,))
     t0 = time.perf_counter()
     carry, metrics = dispatch(member_carry(0), 0.2, 0.0)
-    jax.device_get(metrics)
-    print(json.dumps({"first_dispatch_seconds": time.perf_counter() - t0}))
+    jax.block_until_ready(metrics)
+    print(json.dumps({"first_dispatch_seconds": time.perf_counter() - t0, **device_identity()}))
 
 
 def bench_compile_cache(num_envs: int, rollout_steps: int) -> Dict[str, float]:
-    """Cold-vs-warm first-dispatch seconds across two fresh subprocesses sharing
-    one persistent XLA compilation cache directory."""
+    """Cold-vs-warm first-dispatch seconds across two fresh subprocesses, one at
+    a time, sharing one persistent XLA compilation cache directory: a fixed
+    sub-directory of the resolved cache, emptied first.  The children hold the
+    accelerator, so this must run BEFORE the calling process initialises a JAX
+    backend (``main`` runs it first)."""
     import subprocess
-    import tempfile
 
-    cache_dir = tempfile.mkdtemp(prefix="anakin_xla_cache_")
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "SHEEPRL_TPU_QUIET": "1"}
-    times = []
-    try:
-        for _ in range(2):
-            proc = subprocess.run(
-                [
-                    sys.executable,
-                    os.path.abspath(__file__),
-                    "--compile-probe",
-                    "--compile-cache-dir", cache_dir,
-                    "--pop-envs", str(num_envs),
-                    "--pop-rollout", str(rollout_steps),
-                ],
-                capture_output=True,
-                text=True,
-                env=env,
-                timeout=600,
-            )
-            if proc.returncode != 0:
-                raise RuntimeError(f"compile probe failed: {proc.stderr[-500:]}")
-            row = json.loads(proc.stdout.strip().splitlines()[-1])
-            times.append(float(row["first_dispatch_seconds"]))
-    finally:
-        import shutil
+    from sheeprl_tpu.distributed import chips
+    from sheeprl_tpu.utils.compile_cache import empty_cold_start_dir
 
-        shutil.rmtree(cache_dir, ignore_errors=True)
-    cold, warm = times
-    return {"cold_seconds": cold, "warm_seconds": warm, "speedup": cold / max(warm, 1e-9)}
+    cache_dir = empty_cold_start_dir("anakin_compile")
+    env = {**chips.accelerator_env(os.environ), "SHEEPRL_TPU_QUIET": "1"}
+    rows = []
+    for _ in range(2):
+        proc = subprocess.run(
+            [
+                sys.executable,
+                os.path.abspath(__file__),
+                "--compile-probe",
+                "--compile-cache-dir", cache_dir,
+                "--pop-envs", str(num_envs),
+                "--pop-rollout", str(rollout_steps),
+            ],
+            capture_output=True,
+            text=True,
+            env=env,
+            timeout=600,
+        )
+        if proc.returncode != 0:
+            raise RuntimeError(f"compile probe failed: {proc.stderr[-500:]}")
+        rows.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+    cold, warm = (float(r.pop("first_dispatch_seconds")) for r in rows)
+    return {"cold_seconds": cold, "warm_seconds": warm, "speedup": cold / max(warm, 1e-9), "device": rows[1]}
 
 
 def main(argv: Optional[List[str]] = None) -> Dict[str, float]:
@@ -371,6 +371,13 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, float]:
         _compile_probe(args.pop_envs, args.pop_rollout, args.compile_cache_dir)
         return {}
 
+    # First, while this process has not initialised a backend: the two probe
+    # children need the chip to themselves.
+    cc = bench_compile_cache(args.pop_envs, args.pop_rollout) if args.compile_bench else None
+
+    from sheeprl_tpu.parallel.mesh import device_identity
+
+    identity = device_identity()
     host_sps = bench_host_sync_vector(args.host_envs, args.host_steps)
     raw_envs = min(args.num_envs, 64)  # the python loop saturates long before 64
     host_raw = bench_host_raw_gym(raw_envs, max(args.host_steps // 2, 16))
@@ -416,8 +423,8 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, float]:
                 "per_member_efficiency": round(pop["per_member_efficiency"], 3),
             }
         )
-    if args.compile_bench:
-        cc = bench_compile_cache(args.pop_envs, args.pop_rollout)
+    rows = [{**row, **identity} for row in rows]
+    if cc is not None:
         rows.append(
             {
                 "metric": "anakin_compile_seconds",
@@ -428,6 +435,7 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, float]:
                 ),
                 "cold_seconds": round(cc["cold_seconds"], 3),
                 "warm_speedup": round(cc["speedup"], 2),
+                **cc["device"],  # as the warm probe child saw it
             }
         )
     for row in rows:

@@ -2,7 +2,7 @@
 """Diff two ``BENCH_*.json`` reports and flag per-metric regressions.
 
 Usage:
-    python benchmarks/bench_compare.py BENCH_r04.json BENCH_r05.json [--threshold 0.10] [--json]
+    python benchmarks/bench_compare.py OLD_REPORT.json NEW_REPORT.json [--threshold 0.10] [--json]
     python benchmarks/bench_compare.py --latest 2 [--strict]
 
 A BENCH report is the collector's dict whose ``tail`` embeds one JSON object per

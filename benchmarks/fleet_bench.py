@@ -13,8 +13,12 @@ lower-better by exact name):
   front's exit summary at the highest replica count, front p50 and the share of
   rerouted requests as extras.
 
-All replicas share one persistent compile cache, so replica 2..N start warm —
-the same mechanism the autoscaler leans on for fast scale-up.  The served
+All replicas share one persistent compile cache (wherever
+``sheeprl_tpu/utils/compile_cache.py`` resolves it), so replica 2..N start warm —
+the same mechanism the autoscaler leans on for fast scale-up.  This driver never
+initialises JAX: each replica is a child holding a chip of its own (a sweep past
+the host's chip count is refused), the front is placed on the CPU backend, and
+every row carries the device its replicas reported.  The served
 artifact is the untrained tiny PPO from ``serve_bench`` (serving cost does not
 depend on how good the weights are).
 
@@ -46,9 +50,11 @@ os.environ.setdefault("SHEEPRL_TPU_QUIET", "1")
 from serve_bench import MODEL_NAME, Replica, build_artifact  # noqa: E402
 
 
-def _child_env() -> Dict[str, str]:
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+def _front_env() -> Dict[str, str]:
+    """The front runs no policy: placed on the CPU backend by statement."""
+    from sheeprl_tpu.distributed import chips
+
+    env = chips.cpu_env(os.environ)
     for var in ("SHEEPRL_TPU_SERVE_SUMMARY", "SHEEPRL_TPU_FLEET_SUMMARY", "SHEEPRL_TPU_FLEET"):
         env.pop(var, None)
     return env
@@ -72,7 +78,7 @@ class Front:
             f"serve.fleet.summary_path={self.summary_file}",
         ]
         self.proc = subprocess.Popen(
-            args, cwd=REPO, env=_child_env(),
+            args, cwd=REPO, env=_front_env(),
             stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT,
         )
 
@@ -145,23 +151,29 @@ def drive_fleet_clients(
 def run_fleet(
     tmp: Path,
     registry: Path,
-    cache_dir: Path,
     obs_template: Dict[str, tuple],
     n_replicas: int,
     clients: int,
     requests: int,
     max_batch: int,
-) -> Tuple[float, Dict]:
+) -> Tuple[float, Dict, Dict]:
     """Spawn ``n_replicas`` + one front, drive the clients through the front,
-    tear everything down; returns ``(replies_per_sec, front_summary)``."""
+    tear everything down; returns ``(replies_per_sec, front_summary, device)``.
+    Every replica holds a chip of its own (replica ``i`` pinned to chip ``i`` on
+    a multi-chip host); more replicas than chips is refused before any spawn."""
+    from sheeprl_tpu.distributed import chips
+
+    n_chips = chips.check_chip_budget(n_replicas, f"a {n_replicas}-replica fleet")
     workdir = tmp / f"fleet_{n_replicas}r"
     replicas = [
-        Replica(registry, workdir / f"replica{i}", max_batch, cache_dir)
+        Replica(registry, workdir / f"replica{i}", max_batch, chip=i if n_chips > 1 else None)
         for i in range(n_replicas)
     ]
     front = None
     try:
-        endpoints = [f"127.0.0.1:{r.wait_ready()['port']}" for r in replicas]
+        readies = [r.wait_ready() for r in replicas]
+        endpoints = [f"127.0.0.1:{ready['port']}" for ready in readies]
+        device = {k: readies[0][k] for k in ("platform", "device_kind", "device_count")}
         front = Front(workdir / "front", endpoints)
         ready = front.wait_ready()
         wall, total = drive_fleet_clients(ready["port"], obs_template, clients, requests)
@@ -169,7 +181,7 @@ def run_fleet(
         front = None
         if summary["replied"] != total or summary["errors"]:
             raise RuntimeError(f"front lost replies: drove {total}, summary {summary}")
-        return (total / wall if wall > 0 else 0.0), summary
+        return (total / wall if wall > 0 else 0.0), summary, device
     finally:
         if front is not None:
             front.proc.kill()
@@ -191,14 +203,13 @@ def main(argv: Optional[List[str]] = None) -> None:
 
     tmp = Path(tempfile.mkdtemp(prefix="fleet_bench_"))
     registry, obs_template = build_artifact(tmp)
-    cache_dir = tmp / "xla_cache"
 
     sweep: Dict[int, float] = {}
     summary: Dict = {}
+    device: Dict = {}
     for n in range(1, args.max_replicas + 1):
-        sweep[n], summary = run_fleet(
-            tmp, registry, cache_dir, obs_template, n,
-            args.clients, args.requests, args.max_batch,
+        sweep[n], summary, device = run_fleet(
+            tmp, registry, obs_template, n, args.clients, args.requests, args.max_batch
         )
 
     top_n = max(sweep)
@@ -214,6 +225,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         ),
         **extras,
         "scaling_vs_1_replica": round(sweep[top_n] / sweep[1], 2) if sweep.get(1) else None,
+        **device,  # per replica, as each replica's ready file reports it
     }))
     p99 = summary.get("p99_ms")
     p50 = summary.get("p50_ms")
@@ -224,6 +236,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         "p50_ms": round(p50, 3) if isinstance(p50, (int, float)) else None,
         "rerouted": summary.get("rerouted", 0),
         "replied": summary.get("replied", 0),
+        **device,
     }))
 
 

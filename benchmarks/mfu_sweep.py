@@ -3,8 +3,8 @@
 Round 3 left MFU at ~0.17 for size S with the unmeasured claim that the T=64 RSSM /
 H=15 imagination scans are latency-bound at S and that larger models lift arithmetic
 intensity.  This probe measures grad-steps/s + MFU for sizes S/M/L (same batch 16 ×
-seq 64 × 64×64×3 config) on the real chip and prints one JSON line per size, feeding
-``PROFILE_r04.md``.
+seq 64 × 64×64×3 config) and prints one JSON line per size, each naming the device
+it was measured on.
 
 Usage: ``python benchmarks/mfu_sweep.py [S M L S:64]`` — ``SIZE:BATCH`` entries
 override the batch size (default 16), probing the arithmetic-intensity lever.
@@ -23,6 +23,8 @@ from bench import bench_train_only  # noqa: E402
 
 
 def main() -> None:
+    from sheeprl_tpu.parallel.mesh import device_identity
+
     entries = sys.argv[1:] or ["S", "M", "L"]
     for entry in entries:
         size, _, batch = entry.partition(":")
@@ -30,7 +32,13 @@ def main() -> None:
         gsps, mfu = bench_train_only(size, batch=batch)
         print(
             json.dumps(
-                {"size": size, "batch": batch, "grad_steps_per_sec": round(gsps, 4), "mfu": round(mfu, 4)}
+                {
+                    "size": size,
+                    "batch": batch,
+                    "grad_steps_per_sec": round(gsps, 4),
+                    "mfu": round(mfu, 4),
+                    **device_identity(),
+                }
             ),
             flush=True,
         )

@@ -3,8 +3,8 @@ vs EnvPool at DreamerV3 walker shapes (4 envs, 64x64x3 uint8 pixels + a small
 proprio vector, 6-dim continuous actions).
 
 The env is a dummy pixel env with a configurable simulated step cost
-(``--step-ms``, default 2 ms ≈ the single-env MuJoCo+GL cost PROFILE_r05 §1
-measured per DreamerV3 walker step at action_repeat 2).  On a multi-core host
+(``--step-ms``, default 2 ms, the order of a single-env MuJoCo+GL DreamerV3
+walker step at action_repeat 2).  On a multi-core host
 the pool's concurrent workers should sustain >=2x the serial SyncVectorEnv
 rate at that cost; ``--step-ms 0`` measures pure dispatch/IPC overhead instead.
 

@@ -72,8 +72,11 @@ BASE_OVERRIDES = [
 
 
 def _child_env(summary: Optional[str] = None) -> Dict[str, str]:
+    """The platform is the caller's: the launcher puts the learner on the
+    accelerator and the actors on the CPU backend (``distributed/chips.py``), the
+    thread baseline is one process on the accelerator.  This driver itself never
+    imports JAX, and the children run one topology at a time."""
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env.pop("SHEEPRL_TPU_SEBULBA_SUMMARY", None)
     if summary:
         env["SHEEPRL_TPU_SEBULBA_SUMMARY"] = summary
@@ -168,6 +171,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         "xfer_bytes_received": int(two["bytes_received"]),
         "xfer_bytes_published": int(two["bytes_published"]),
         "publishes": int(two["publishes"]),
+        **two["device"],  # as the learner that measured it saw it
     }))
     print(json.dumps({
         "metric": "sebulba_env_steps_per_sec",
@@ -177,6 +181,8 @@ def main(argv: Optional[List[str]] = None) -> None:
         "thread_decoupled_env_steps_per_sec": round(thread_sps, 3),
         "actor_scaling_2x_over_1x": round(two_sps / one_sps, 3) if one_sps > 0 else None,
         "speedup_vs_thread_decoupled": round(two_sps / thread_sps, 3) if thread_sps > 0 else None,
+        "actors_platform": "cpu",  # the launcher's stated placement
+        **two["device"],
     }))
 
     # Fleet-exporter overhead rides along (BENCH_OBS=0 skips it): the telemetry

@@ -14,11 +14,19 @@ directions: ``serve_*`` is higher-better by prefix, with ``serve_p99_ms`` and
   from its exit summary, naive p99 as an extra.
 * ``serve_startup_seconds`` — spawn→ready wall of a WARM replica start (value)
   vs the COLD start that populated the persistent compile cache (extra): the
-  AOT ladder deserializes from disk instead of recompiling.
+  AOT ladder deserializes from disk instead of recompiling.  The cold start
+  gets a fixed sub-directory of the resolved cache, emptied first
+  (``sheeprl_tpu/utils/compile_cache.py``).
 
 The served artifact is built without training: a freshly-initialised tiny PPO
 agent on ``jax_cartpole`` is checkpointed and registered — serving cost does not
 depend on how good the weights are.
+
+Process discipline: this driver never initialises JAX.  The artifact is built by
+a child placed on the CPU backend by statement (it measures nothing), and each
+replica — one alive at a time — is a child that holds the accelerator; every row
+carries the ``platform`` / ``device_kind`` / ``device_count`` its replica wrote
+into its ready file.
 
 Usage::
 
@@ -61,8 +69,21 @@ TINY_PPO = [
 
 
 def build_artifact(tmp: Path) -> Tuple[Path, Dict[str, tuple]]:
-    """Checkpoint + register an untrained tiny PPO policy; returns
-    ``(registry_dir, obs_template)``."""
+    """Checkpoint + register an untrained tiny PPO policy in a child process on
+    the CPU backend (so the driver never holds the chip its replicas need);
+    returns ``(registry_dir, obs_template)``."""
+    from sheeprl_tpu.distributed import chips
+
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--build-artifact", str(tmp)],
+        cwd=REPO, env=chips.cpu_env(os.environ), check=True, capture_output=True, text=True,
+    )
+    template = json.loads(proc.stdout.strip().splitlines()[-1])
+    return tmp / "registry", {k: (tuple(shape), dtype) for k, (shape, dtype) in template.items()}
+
+
+def _build_artifact_here(tmp: Path) -> None:
+    """Child half of :func:`build_artifact`; prints the obs template as JSON."""
     import jax
 
     from sheeprl_tpu.config.core import compose, save_config
@@ -80,14 +101,16 @@ def build_artifact(tmp: Path) -> Tuple[Path, Dict[str, tuple]]:
 
     ckpt_path = CheckpointManager(tmp / "run" / "checkpoints").save(0, {"params": params})
     save_config(cfg, tmp / "run" / "config.yaml")
-    registry = tmp / "registry"
-    LocalModelManager(registry_dir=str(registry)).register_model(str(ckpt_path), MODEL_NAME)
-    return registry, policy.obs_template
+    LocalModelManager(registry_dir=str(tmp / "registry")).register_model(str(ckpt_path), MODEL_NAME)
+    print(json.dumps({k: (list(shape), str(dtype)) for k, (shape, dtype) in policy.obs_template.items()}))
 
 
-def _child_env() -> Dict[str, str]:
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+def replica_env(chip: Optional[int] = None) -> Dict[str, str]:
+    """A replica holds the accelerator (``chip`` pins it to one chip of a
+    multi-chip host); its platform is never defaulted to the CPU here."""
+    from sheeprl_tpu.distributed import chips
+
+    env = chips.accelerator_env(os.environ, chip=chip)
     env.pop("SHEEPRL_TPU_SERVE_SUMMARY", None)
     return env
 
@@ -95,7 +118,14 @@ def _child_env() -> Dict[str, str]:
 class Replica:
     """One server subprocess: spawn, wait-ready, SIGTERM-drain, summary."""
 
-    def __init__(self, registry: Path, workdir: Path, max_batch: int, cache_dir: Path):
+    def __init__(
+        self,
+        registry: Path,
+        workdir: Path,
+        max_batch: int,
+        cache_dir: Optional[str] = None,
+        chip: Optional[int] = None,
+    ):
         self.ready_file = workdir / "ready.json"
         self.summary_file = workdir / "summary.json"
         workdir.mkdir(parents=True, exist_ok=True)
@@ -110,11 +140,12 @@ class Replica:
             f"serve.summary_path={self.summary_file}",
             "serve.log_every_s=0",
             "compile_cache.enabled=True",
-            f"compile_cache.dir={cache_dir}",
         ]
+        if cache_dir:  # else: wherever utils/compile_cache.py resolves it
+            args.append(f"compile_cache.dir={cache_dir}")
         self.t_spawn = time.perf_counter()
         self.proc = subprocess.Popen(
-            args, cwd=REPO, env=_child_env(),
+            args, cwd=REPO, env=replica_env(chip),
             stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT,
         )
         self.startup_seconds: Optional[float] = None
@@ -194,11 +225,17 @@ def main(argv: Optional[List[str]] = None) -> None:
     parser.add_argument("--clients", type=int, default=32)
     parser.add_argument("--requests", type=int, default=100, help="round-trips per client")
     parser.add_argument("--max-batch", type=int, default=32)
+    parser.add_argument("--build-artifact", default=None, help=argparse.SUPPRESS)
     args = parser.parse_args(argv)
+    if args.build_artifact:  # child mode of build_artifact
+        _build_artifact_here(Path(args.build_artifact))
+        return
+
+    from sheeprl_tpu.utils.compile_cache import empty_cold_start_dir
 
     tmp = Path(tempfile.mkdtemp(prefix="serve_bench_"))
     registry, obs_template = build_artifact(tmp)
-    cache_dir = tmp / "xla_cache"
+    cache_dir = empty_cold_start_dir("serve_startup")
 
     # -- cold start: empty persistent cache, every ladder bucket compiles.
     replica = Replica(registry, tmp / "cold", args.max_batch, cache_dir)
@@ -210,6 +247,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     replica = Replica(registry, tmp / "warm", args.max_batch, cache_dir)
     ready = replica.wait_ready()
     warm_startup = replica.startup_seconds
+    device = {k: ready[k] for k in ("platform", "device_kind", "device_count")}
 
     # -- continuous batching throughput on the warm replica.
     wall, total = drive_clients(ready["port"], obs_template, args.clients, args.requests)
@@ -237,6 +275,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         "batch_fill": round(batched.get("Serve/batch_fill", 0.0), 3),
         "replies": total,
         "recompiles": batched_summary["recompiles"],
+        **device,
     }))
     print(json.dumps({
         "metric": "serve_p99_ms",
@@ -244,6 +283,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         "unit": f"ms enqueue->reply p99 (continuous batching, {args.clients} clients)",
         "p50_ms": round(batched.get("Serve/latency_ms/p50", float("nan")), 3),
         "naive_p99_ms": round(naive.get("Serve/latency_ms/p99", float("nan")), 3),
+        **device,
     }))
     print(json.dumps({
         "metric": "serve_startup_seconds",
@@ -251,6 +291,8 @@ def main(argv: Optional[List[str]] = None) -> None:
         "unit": "s spawn->ready, warm persistent compile cache",
         "cold_startup_seconds": round(cold_startup, 2),
         "warm_speedup": round(cold_startup / warm_startup, 2) if warm_startup else None,
+        "compile_cache_dir": cache_dir,
+        **device,
     }))
 
 

@@ -507,7 +507,7 @@ def main(ctx, cfg) -> None:
 
     def _pipeline_post(fetched):
         # ONE device_get for everything the host needs (per-array fetches would
-        # each pay a transfer round trip on a remote accelerator).
+        # each pay their own dispatch and device→host sync).
         stored_np, acts_list = fetched
         stored_actions = np.asarray(stored_np)
         acts_np = [np.asarray(a) for a in acts_list]

@@ -239,7 +239,7 @@ def main(ctx, cfg, exploration_cfg=None) -> None:
                 player_params(), player_state, obs_t, jnp.asarray(is_first_np), ctx.local_rng()
             )
             # ONE device_get for everything the host needs (per-array fetches
-            # would each pay a transfer round trip on a remote accelerator).
+            # would each pay their own dispatch and device→host sync).
             stored_np, acts_list = jax.device_get((stored, list(actions)))
             stored_actions = np.asarray(stored_np)
             acts_np = [np.asarray(a) for a in acts_list]

@@ -97,22 +97,18 @@ def make_optimizer(
             return optax.sgd(learning_rate, momentum=opt_cfg.get("momentum", 0.0))
 
     elif name == "rmsprop_tf":
-        # TF-style RMSProp: eps inside the sqrt (reference optim/rmsprop_tf.py:14-156).
-        # optax moved the eps placement behind an ``eps_in_sqrt`` kwarg whose default
-        # is deprecating (>=0.2.4); pin the TF behavior explicitly where the kwarg
-        # exists, and fall back cleanly on older optax whose rmsprop ALWAYS put the
-        # eps inside the sqrt — both paths compute the same update.
-        import inspect
-
-        rmsprop_kwargs = dict(
-            decay=opt_cfg.get("alpha", 0.99), eps=opt_cfg.get("eps", 1e-8),
-            centered=opt_cfg.get("centered", False), momentum=opt_cfg.get("momentum", 0.0),
-        )
-        if "eps_in_sqrt" in inspect.signature(optax.rmsprop).parameters:
-            rmsprop_kwargs["eps_in_sqrt"] = True
+        # TF-style RMSProp: eps inside the sqrt (reference optim/rmsprop_tf.py:14-156),
+        # pinned explicitly because optax is deprecating that default.
 
         def base(learning_rate):
-            return optax.rmsprop(learning_rate, **rmsprop_kwargs)
+            return optax.rmsprop(
+                learning_rate,
+                decay=opt_cfg.get("alpha", 0.99),
+                eps=opt_cfg.get("eps", 1e-8),
+                centered=opt_cfg.get("centered", False),
+                momentum=opt_cfg.get("momentum", 0.0),
+                eps_in_sqrt=True,
+            )
 
     else:
         raise ValueError(f"Unknown optimizer: {name}")

@@ -60,9 +60,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("-q", "--quiet", action="store_true", help="suppress progress/summary lines")
     args = parser.parse_args(argv)
 
-    # Force CPU BEFORE jax initialises a backend: the audit runs on dev boxes and
-    # CI runners; the IR properties it checks are backend-independent.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # The audit is a CPU tool (dev boxes, CI runners; irbudgets.json holds CPU
+    # compile-memory numbers and the IR properties checked are backend-
+    # independent), so unless told otherwise it places itself on the CPU backend
+    # — BEFORE jax initialises one — and says so: it must never take a chip a
+    # training process needs.
+    if not os.environ.get("JAX_PLATFORMS"):
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        if not args.quiet:
+            print("jaxlint-ir: JAX_PLATFORMS unset, auditing on the CPU backend", file=sys.stderr)
 
     from sheeprl_tpu.analysis.core import filter_baseline, load_baseline
     from sheeprl_tpu.analysis.ir import (

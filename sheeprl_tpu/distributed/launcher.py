@@ -22,6 +22,13 @@ Lifecycle policy:
   connects to the learner and sends an ``abandon`` control message so the
   learner stops waiting for that slot instead of starving.
 
+Device placement is explicit, never inherited (``distributed/chips.py``): the
+learner is the one chip-holding child and keeps the launcher's own platform
+setting; every actor is placed on the CPU backend (``JAX_PLATFORMS=cpu``) —
+actors step environments and run a small policy forward, and a chip belongs to
+one process at a time.  Each spawn line says where the child runs.  The
+launcher itself never imports JAX.
+
 Children write their logs into distinct run dirs — the learner keeps the pinned
 ``run_name``; actor *i* gets ``<run_name>_actor<i>`` — so the versioned log-dir
 machinery never races across processes.
@@ -36,6 +43,7 @@ import sys
 import time
 from typing import Any, Dict, List, Optional
 
+from sheeprl_tpu.distributed import chips
 from sheeprl_tpu.distributed.placement import (
     GENERATION_ENV_VAR,
     ROLE_ACTOR,
@@ -77,8 +85,16 @@ def _spawn(
         f"run_name={run_name}",
         "fault.autoresume=False",
     ]
-    _log(f"spawning {log_prefix}: {' '.join(cmd[3:])}")
+    _log(f"spawning {log_prefix} on {chips.describe(env)}: {' '.join(cmd[3:])}")
     return subprocess.Popen(cmd, env=env)
+
+
+def role_env(role: str) -> Dict[str, str]:
+    """Device placement by role: the learner holds the accelerator (under the
+    launcher's own platform setting), actors run on the CPU backend."""
+    if role == ROLE_LEARNER:
+        return chips.accelerator_env(os.environ)
+    return chips.cpu_env(os.environ)
 
 
 def _abandon(spec: PlacementSpec, port: int, actor_id: int) -> None:
@@ -136,8 +152,10 @@ def launch(args: Optional[List[str]] = None) -> int:
         except OSError as e:
             _log(f"fleet telemetry disabled: {e}")
 
+    # Exactly one chip-holding child (the learner), so the topology can never
+    # over-subscribe the host's chips, whatever distributed.num_actors is.
     def child_env(role: str, generation: int = 0) -> Dict[str, str]:
-        env = dict(os.environ)
+        env = role_env(role)
         # The summary sink is learner-only; role/ids travel as overrides.
         env.pop(SUMMARY_ENV_VAR, None)
         if role == ROLE_LEARNER and os.environ.get(SUMMARY_ENV_VAR):

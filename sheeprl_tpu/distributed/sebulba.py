@@ -59,6 +59,7 @@ from sheeprl_tpu.obs import perf as obs_perf
 from sheeprl_tpu.obs import flight_recorder as _flight_recorder
 from sheeprl_tpu.obs import tracer as _tracer
 from sheeprl_tpu.obs.fleet import maybe_exporter
+from sheeprl_tpu.parallel.mesh import device_identity
 from sheeprl_tpu.rollout.sharding import shard_pool_cfg
 
 HELLO_KIND = "hello"
@@ -1042,6 +1043,8 @@ def _learner_loop(
                     "events": inbox.events,
                     "t_start": t_start,
                     "error": error,
+                    # the device the learner measured on, read in this process
+                    "device": device_identity(),
                 }
             )
     if logger is not None:

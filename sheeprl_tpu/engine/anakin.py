@@ -1,8 +1,8 @@
 """Anakin training mode: acting + env stepping + update fused into ONE dispatch.
 
-PROFILE_r05 §1 measured the two remaining end-to-end walls as architectural:
-~125 ms/iteration of player round trip (the host fetches one action from the
-policy jit per env step) and ~150 ms of single-core host env stepping.  The
+The host loops have two end-to-end walls that are architectural: the player
+round trip (the host fetches one action from the policy jit per env step, a
+dispatch plus a device→host sync) and single-core host env stepping.  The
 Podracer "Anakin" architecture (arxiv 2104.06272) removes both: the environment
 itself is a pure JAX function (``sheeprl_tpu/envs/jax``), N instances vmap into
 one tensor program, and env step, acting, transition writes and the gradient

@@ -2,8 +2,8 @@
 (Podracer, arxiv 2104.06272; ROADMAP item 1).
 
 The host envs (``sheeprl_tpu/utils/env.py``) step numpy worlds one python call at
-a time; PROFILE_r05 §1 measures that wall at ~150 ms/iteration plus ~125 ms of
-player round trip.  A :class:`JaxEnv` instead expresses the WHOLE environment as
+a time, and every step pays the player's dispatch + device→host sync on top.  A
+:class:`JaxEnv` instead expresses the WHOLE environment as
 a pure function over a small state pytree::
 
     params = env.default_params()

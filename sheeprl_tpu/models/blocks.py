@@ -161,9 +161,8 @@ class LayerNormGRUCell(nn.Module):
     instead of six small ones.  The update gate gets a ``-1`` bias (Hafner) so the cell
     starts out remembering.
 
-    The post-matmul chain (LayerNorm + gates + state blend) can run as ONE fused Pallas
-    VMEM pass (``sheeprl_tpu/ops/gru.py``) — enable with ``SHEEPRL_TPU_FUSED_GRU=1``
-    (same param tree either way; the kernel consumes this cell's ``ln_scale``/``ln_bias``).
+    The post-matmul chain (LayerNorm + gates + state blend) is
+    ``sheeprl_tpu.ops.gru.reference_layernorm_gru``, plain ``jax.numpy``.
     """
 
     hidden_size: int
@@ -173,7 +172,7 @@ class LayerNormGRUCell(nn.Module):
 
     @nn.compact
     def __call__(self, h: jax.Array, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        from sheeprl_tpu.ops import fused_gru_enabled
+        from sheeprl_tpu.ops.gru import reference_layernorm_gru
 
         inp = jnp.concatenate([x, h], axis=-1).astype(self.dtype)
         fused = nn.Dense(3 * self.hidden_size, use_bias=not self.layer_norm, dtype=self.dtype)(inp)
@@ -183,17 +182,7 @@ class LayerNormGRUCell(nn.Module):
             # LayerNorm_0/{scale,bias} -> ln_scale/ln_bias rename).
             gamma = self.param("ln_scale", nn.initializers.ones, (3 * self.hidden_size,), jnp.float32)
             beta = self.param("ln_bias", nn.initializers.zeros, (3 * self.hidden_size,), jnp.float32)
-            h_cast = h.astype(self.dtype)
-            from sheeprl_tpu.ops.gru import fused_supported
-
-            if fused_gru_enabled() and fused.ndim == 2 and fused_supported(fused.shape[0]):
-                from sheeprl_tpu.ops.gru import fused_layernorm_gru
-
-                h_new = fused_layernorm_gru(fused, h_cast, gamma, beta, self.norm_eps)
-            else:
-                from sheeprl_tpu.ops.gru import reference_layernorm_gru
-
-                h_new = reference_layernorm_gru(fused, h_cast, gamma, beta, self.norm_eps)
+            h_new = reference_layernorm_gru(fused, h.astype(self.dtype), gamma, beta, self.norm_eps)
             return h_new, h_new
         reset, cand, update = jnp.split(fused, 3, axis=-1)
         reset = jax.nn.sigmoid(reset)

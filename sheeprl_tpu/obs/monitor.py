@@ -77,7 +77,6 @@ class TrainingMonitor:
         # otherwise-untraced run.
         self._capture = None
         self._capturing = False
-        self._session = None
         self._annotation = None
         self._host_tracer_level = int(obs_cfg.get("host_tracer_level", 0))
         self._perf_capture_remaining = 0
@@ -288,37 +287,38 @@ class TrainingMonitor:
 
     # ------------------------------------------------------------------ capture window
     def _start_capture(self) -> None:
-        """Open an XProf profiler session writing to ``<log_dir>/xprof``.
+        """Open an XProf trace writing to ``<log_dir>/xprof`` (``.xplane.pb`` under
+        ``plugins/profile/<stamp>/``).
 
-        Uses the low-level ``ProfilerSession`` (what ``jax.profiler.start_trace``
-        wraps) so the TSL *host* tracer level is controllable: at its default level
-        the host tracer installs thread hooks that SEGFAULT when certain third-party
-        threads are alive (observed with dm_control/glfw render threads + a
-        SummaryWriter event thread).  ``obs.host_tracer_level=0`` (the default) skips
-        host tracing entirely — device/XLA events, the part the span tracer cannot
-        see, are still captured — and is the only level safe everywhere."""
+        ``jax.profiler.ProfileOptions`` carries the TSL *host* tracer level: at its
+        default level the host tracer installs thread hooks that SEGFAULT when
+        certain third-party threads are alive (observed with dm_control/glfw render
+        threads + a SummaryWriter event thread).  ``obs.host_tracer_level=0`` (the
+        default) skips host tracing entirely — device/XLA events, the part the span
+        tracer cannot see, are still captured — and is the only level safe
+        everywhere.  A trace that cannot start (one is already running) warns and
+        leaves training alone."""
+        import jax
+
         path = os.path.join(self.log_dir, "xprof")
+        opts = jax.profiler.ProfileOptions()
+        opts.host_tracer_level = self._host_tracer_level
+        opts.python_tracer_level = 0
         try:
-            from jax._src.lib import xla_client
-
-            opts = xla_client.profiler.ProfileOptions()
-            opts.host_tracer_level = self._host_tracer_level
-            opts.python_tracer_level = 0
-            self._session = xla_client.profiler.ProfilerSession(opts)
-            self._capture_path = path
-            self._capturing = True
-        except Exception as e:  # no private API / profiler already active: don't kill training
-            self._session = None
+            jax.profiler.start_trace(path, profiler_options=opts)
+        except RuntimeError as e:
             warnings.warn(f"obs.capture_steps: could not start XProf trace at {path}: {e}")
+            return
+        self._capturing = True
 
     def _stop_capture(self) -> None:
-        if self._session is not None:
-            try:
-                self._session.stop_and_export(self._capture_path)
-            except Exception as e:
-                warnings.warn(f"obs.capture_steps: could not export XProf trace: {e}")
-            self._session = None
+        import jax
+
         self._capturing = False
+        try:
+            jax.profiler.stop_trace()
+        except RuntimeError as e:
+            warnings.warn(f"obs.capture_steps: could not export XProf trace: {e}")
 
     # ------------------------------------------------------------------ teardown
     def trace_path(self) -> str:

@@ -7,9 +7,13 @@ and a handful of free functions used from the lowering seams:
    ``cost_analysis()`` FLOPs + bytes once, at first call, via
    :func:`instrument` (training dispatches) or :func:`register_compiled`
    (serve batch buckets, which already hold ``Compiled`` objects).  The
-   registration uses ``Lowered.cost_analysis()`` — a cheap abstract re-trace,
-   no compile, no device transfer — so it is safe under
-   ``jax.transfer_guard("disallow")`` and buffer donation.  After that, the
+   registration reads ``Compiled.cost_analysis()`` — the one form every
+   backend implements (``Lowered.cost_analysis()`` is unimplemented on the TPU
+   runtime) — from ``jitted.lower(*args).compile()`` just before the first
+   call; that call then reuses the executable (JAX shares the lowering and
+   executable caches between the two paths), so nothing compiles twice and no
+   buffer is consumed.  A hot path whose cost model cannot be read is named
+   once in the log and in ``perf_report.json``.  After that, the
    wrapper only bumps a per-name call counter: the existing step timers turn
    call deltas into zero-extra-sync ``Perf/{mfu,hbm_bw_util,
    achieved_flops_per_sec}`` gauges at every log flush.
@@ -34,6 +38,7 @@ models into each other.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import threading
 import time
@@ -46,8 +51,8 @@ __all__ = [
     "GoodputLedger",
     "PerfPlane",
     "StepTimeWatchdog",
+    "UnknownDeviceError",
     "analyze_compiled",
-    "analyze_lowered",
     "instrument",
     "mfu_from_flops",
     "peak_flops",
@@ -62,6 +67,8 @@ __all__ = [
 
 PERF_REPORT_ENV_VAR = "SHEEPRL_TPU_PERF_REPORT"
 
+_log = logging.getLogger(__name__)
+
 # Peak dense bf16 FLOP/s per chip (public figures).  bench.py imports this
 # table — keep it the single source of truth for both offline and in-run MFU.
 PEAK_FLOPS = {
@@ -74,7 +81,6 @@ PEAK_FLOPS = {
     "TPU v6 lite": 918e12,  # v6e/Trillium's device_kind
     "TPU v6e": 918e12,
 }
-_DEFAULT_PEAK_FLOPS = 275e12  # assume v4 when unknown
 # A CPU backend has no published bf16 matrix peak; a nominal figure keeps the
 # MFU gauge finite and nonzero in CI smokes without pretending to be accurate.
 _CPU_PEAK_FLOPS = 5e11
@@ -91,11 +97,16 @@ PEAK_HBM_BW = {
     "TPU v6 lite": 1640e9,
     "TPU v6e": 1640e9,
 }
-_DEFAULT_PEAK_HBM_BW = 1200e9
 _CPU_PEAK_HBM_BW = 50e9
 
 
-def _lookup(table: Mapping[str, float], device: Any, default: float, cpu: float) -> float:
+class UnknownDeviceError(LookupError):
+    """The accelerator's ``device_kind`` is in neither peak table.  A utilisation
+    against a guessed peak is not a measurement: add the device's published
+    figures to ``PEAK_FLOPS`` / ``PEAK_HBM_BW`` instead."""
+
+
+def _lookup(table: Mapping[str, float], device: Any, cpu: float) -> float:
     kind = str(getattr(device, "device_kind", "") or "")
     for name, peak in table.items():
         if kind.startswith(name):
@@ -103,30 +114,32 @@ def _lookup(table: Mapping[str, float], device: Any, default: float, cpu: float)
     platform = str(getattr(device, "platform", "") or "")
     if platform == "cpu" or kind.lower() in ("cpu", "host"):
         return cpu
-    return default
+    raise UnknownDeviceError(
+        f"no published peak for device_kind={kind!r} (platform={platform!r}); "
+        f"known kinds: {sorted(table)}"
+    )
 
 
 def peak_flops(device: Any = None) -> float:
-    """Peak dense bf16 FLOP/s for ``device`` (default: ``jax.devices()[0]``)."""
+    """Peak dense bf16 FLOP/s for ``device`` (default: ``jax.devices()[0]``);
+    raises :class:`UnknownDeviceError` for an accelerator not in the table."""
     if device is None:
         device = _default_device()
-    return _lookup(PEAK_FLOPS, device, _DEFAULT_PEAK_FLOPS, _CPU_PEAK_FLOPS)
+    return _lookup(PEAK_FLOPS, device, _CPU_PEAK_FLOPS)
 
 
 def peak_hbm_bw(device: Any = None) -> float:
-    """Peak HBM bytes/s for ``device`` (default: ``jax.devices()[0]``)."""
+    """Peak HBM bytes/s for ``device`` (default: ``jax.devices()[0]``);
+    raises :class:`UnknownDeviceError` for an accelerator not in the table."""
     if device is None:
         device = _default_device()
-    return _lookup(PEAK_HBM_BW, device, _DEFAULT_PEAK_HBM_BW, _CPU_PEAK_HBM_BW)
+    return _lookup(PEAK_HBM_BW, device, _CPU_PEAK_HBM_BW)
 
 
 def _default_device() -> Any:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0]
-    except Exception:
-        return None
+    return jax.devices()[0]
 
 
 def mfu_from_flops(flops_per_step: float, steps_per_sec: float, device: Any = None) -> float:
@@ -178,7 +191,7 @@ class _Entry:
     window's MFU, never the registry itself.
     """
 
-    __slots__ = ("name", "flops", "bytes_accessed", "info", "calls", "attempted")
+    __slots__ = ("name", "flops", "bytes_accessed", "info", "calls", "attempted", "error")
 
     def __init__(self, name: str) -> None:
         self.name = name
@@ -187,6 +200,7 @@ class _Entry:
         self.info: Dict[str, Any] = {}
         self.calls = 0
         self.attempted = False
+        self.error: Optional[str] = None
 
 
 _lock = threading.Lock()
@@ -210,6 +224,22 @@ def register_cost_model(name: str, flops: float, bytes_accessed: float = 0.0, **
         entry.bytes_accessed = float(bytes_accessed or 0.0)
         entry.info.update(info)
         entry.attempted = True
+
+
+def record_registration_failure(name: str, exc: BaseException) -> None:
+    """A hot path whose cost model could not be read: said once in the log by
+    name, and carried into ``perf_report.json`` (``registration_failures``) so
+    an absent ``Perf/mfu`` is explained instead of silent."""
+    entry = _ensure_entry(name)
+    with _lock:
+        entry.attempted = True
+        entry.error = f"{type(exc).__name__}: {exc}"
+    _log.warning("perf: no cost model for %s (%s); Perf/mfu will not count it", name, entry.error)
+
+
+def registration_failures() -> Dict[str, str]:
+    with _lock:
+        return {name: e.error for name, e in _registry.items() if e.error}
 
 
 def record_call(name: str, n: int = 1) -> None:
@@ -241,17 +271,11 @@ def reset() -> None:
 
 
 def _cost_dict(cost: Any) -> Dict[str, Any]:
-    # Lowered.cost_analysis() returns a plain dict; Compiled.cost_analysis()
-    # returns a list of per-executable dicts — normalize both shapes.
+    # Compiled.cost_analysis() returns a dict or (older form) a list of
+    # per-executable dicts — normalize both shapes.
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}
     return dict(cost or {})
-
-
-def analyze_lowered(lowered: Any) -> Tuple[float, float]:
-    """``(flops, bytes_accessed)`` from a ``jax.stages.Lowered`` (no compile)."""
-    cost = _cost_dict(lowered.cost_analysis())
-    return float(cost.get("flops", 0.0) or 0.0), float(cost.get("bytes accessed", 0.0) or 0.0)
 
 
 def analyze_compiled(compiled: Any) -> Tuple[float, float]:
@@ -279,14 +303,16 @@ def _memory_info(compiled: Any) -> Dict[str, float]:
 
 
 def register_compiled(name: str, compiled: Any) -> None:
-    """Register a cost model straight from a ``Compiled`` (serve batch buckets)."""
+    """Register a cost model straight from a ``Compiled``."""
     try:
         flops, bytes_accessed = analyze_compiled(compiled)
-        register_cost_model(name, flops, bytes_accessed, **_memory_info(compiled))
-    except Exception:
-        # Never let attribution kill a serving path; mark the attempt so the
-        # report shows the bucket with a zero model instead of omitting it.
-        register_cost_model(name, 0.0, 0.0)
+    except Exception as exc:  # attribution must not kill the hot path it measures
+        record_registration_failure(name, exc)
+        return
+    if flops <= 0.0:
+        record_registration_failure(name, ValueError("cost_analysis() reported no flops on this backend"))
+        return
+    register_cost_model(name, flops, bytes_accessed, **_memory_info(compiled))
 
 
 def _unwrap_jit(fn: Any) -> Optional[Any]:
@@ -303,10 +329,10 @@ def _unwrap_jit(fn: Any) -> Optional[Any]:
 def instrument(cfg: Any, name: str, fn: Callable[..., Any]) -> Callable[..., Any]:
     """Wrap a jitted hot path: register its cost model once, count every call.
 
-    Identity when ``obs.perf.enabled`` is off.  The first call re-lowers the
-    underlying jitted function with the live arguments — an abstract trace
-    (cheap, no compile, no transfers) — and records XLA's FLOPs/bytes estimate
-    under ``name``.  Every call bumps the per-name counter the
+    Identity when ``obs.perf.enabled`` is off.  The first call compiles the
+    underlying jitted function ahead of time with the live arguments (the
+    executable the call itself then reuses) and records XLA's FLOPs/bytes
+    estimate under ``name``.  Every call bumps the per-name counter the
     :class:`PerfPlane` turns into MFU at flush time.
     """
     if not perf_enabled(cfg):
@@ -319,10 +345,11 @@ def instrument(cfg: Any, name: str, fn: Callable[..., Any]) -> Callable[..., Any
             target = _unwrap_jit(fn)
             if target is not None:
                 try:
-                    flops, bytes_accessed = analyze_lowered(target.lower(*args, **kwargs))
-                    register_cost_model(name, flops, bytes_accessed)
-                except Exception:
-                    pass
+                    compiled = target.lower(*args, **kwargs).compile()
+                except Exception as exc:  # let the call below raise the real error
+                    record_registration_failure(name, exc)
+                else:
+                    register_compiled(name, compiled)
         entry.calls += 1
         return fn(*args, **kwargs)
 
@@ -632,6 +659,7 @@ class PerfPlane:
             "anomalies": self.watchdog.anomalies,
             "anomaly_events": list(self.anomaly_events),
             "cost_models": registered_cost_models(),
+            "registration_failures": registration_failures(),
         }
 
     def write_report(self, path: str) -> Optional[str]:

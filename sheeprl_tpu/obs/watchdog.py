@@ -91,22 +91,9 @@ class RecompileWatchdog:
         }
 
     def close(self) -> None:
+        if not self._active:
+            return
         self._active = False
-        # Best-effort listener removal through whatever the installed JAX exposes
-        # publicly; no private jax._src import, so a JAX upgrade can only degrade
-        # this to the no-op fallback (the _active flag already neutralises the
-        # listener either way).
-        try:
-            from jax import monitoring as _m
+        from jax import monitoring
 
-            for name in (
-                "unregister_event_duration_secs_listener",
-                "unregister_event_duration_listener_by_callback",
-                "_unregister_event_duration_listener_by_callback",
-            ):
-                unregister = getattr(_m, name, None)
-                if callable(unregister):
-                    unregister(self._listener)
-                    break
-        except Exception:
-            pass
+        monitoring.unregister_event_duration_listener(self._listener)

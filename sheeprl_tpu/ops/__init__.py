@@ -1,22 +1,4 @@
-"""TPU-native kernels (Pallas) for the framework's hot ops.
-
-Currently: ``gru.fused_layernorm_gru`` — the RSSM GRU cell's post-matmul chain
-(LayerNorm + gates + state blend) as one VMEM pass.  Default ``auto``: enabled on real
-TPU backends (measured +2.8% on the full DV3-S train step), off elsewhere; override
-with ``SHEEPRL_TPU_FUSED_GRU=0|1``.
+"""Hot ops of the framework: the LayerNorm-GRU gate math (``gru``), exact ring
+attention over the ``sequence`` mesh axis (``ring_attention``), and an
+experimental fully-fused Pallas RSSM step that nothing calls (``rssm_step``).
 """
-
-from __future__ import annotations
-
-import os
-
-
-def fused_gru_enabled() -> bool:
-    flag = os.environ.get("SHEEPRL_TPU_FUSED_GRU", "auto").lower()
-    if flag in ("1", "true", "yes", "on"):
-        return True
-    if flag == "auto":
-        import jax
-
-        return jax.default_backend() == "tpu"
-    return False

@@ -1,34 +1,24 @@
-"""Fused LayerNorm-GRU gate kernel (Pallas, TPU).
+"""LayerNorm-GRU gate math — the RSSM's hot loop after the matmul.
 
 The RSSM's hot loop (SURVEY §3.1 hot loop 1: 64 sequential GRU steps per gradient
-step) is ``h' = GRUGates(LayerNorm(concat(x, h) @ W), h)``.  The matmul belongs on the
-MXU and is left to XLA; everything AFTER it — LayerNorm over the fused ``3H``
-projection, the three gate nonlinearities and the state blend — is a chain of
-HBM-bandwidth-bound elementwise ops.  This kernel runs that whole chain in ONE VMEM
-pass per batch tile (one HBM read of the projection + one write of the new state,
-instead of XLA's worst case of several intermediate materialisations inside a scan).
+step) is ``h' = GRUGates(LayerNorm(concat(x, h) @ W), h)``.  The matmul is a Dense
+layer; everything AFTER it — LayerNorm over the fused ``3H`` projection, the three
+gate nonlinearities and the state blend — is :func:`reference_layernorm_gru`, plain
+``jax.numpy`` with f32 statistics that XLA fuses on its own.  ``LayerNormGRUCell``
+(``sheeprl_tpu/models/blocks.py``) calls it directly.
 
-A hand-derived VJP keeps it differentiable: the backward kernel recomputes the LN/gate
-intermediates in VMEM from the saved ``(proj, h)`` residuals — rematerialisation is
-cheaper than storing five intermediates per scan step.
-
-Used by ``LayerNormGRUCell`` (``sheeprl_tpu/models/blocks.py``) when the
-``SHEEPRL_TPU_FUSED_GRU`` switch is on (default ``auto`` = TPU backends only), or call
-``fused_layernorm_gru(proj, h, gamma, beta, eps)`` directly.  Off-TPU backends run the
-same kernel in interpreter mode, so tests exercise identical code paths.
+There is deliberately no hand-written kernel for this chain: on a TPU v5e one made
+no measurable difference to the DreamerV3-S train step, and a Mosaic custom call
+cannot be partitioned by GSPMD, so it stopped every multi-chip run at lowering
+(PERF.md, PR 21).  ``_ln`` / ``_gates`` are shared with ``ops/rssm_step.py``.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _ln(p: jax.Array, gamma: jax.Array, beta: jax.Array, eps: float) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -47,136 +37,13 @@ def _gates(n: jax.Array, h: jax.Array, hidden: int):
     return out, reset, cand, update
 
 
-def _fwd_kernel(proj_ref, h_ref, gamma_ref, beta_ref, out_ref, *, hidden: int, eps: float):
-    p = proj_ref[:].astype(jnp.float32)
-    n, _, _ = _ln(p, gamma_ref[:].astype(jnp.float32), beta_ref[:].astype(jnp.float32), eps)
-    out, _, _, _ = _gates(n, h_ref[:].astype(jnp.float32), hidden)
-    out_ref[:] = out.astype(out_ref.dtype)
-
-
-def _bwd_kernel(proj_ref, h_ref, gamma_ref, beta_ref, g_ref, dproj_ref, dh_ref, dgamma_ref, dbeta_ref, *, hidden: int, eps: float):
-    p = proj_ref[:].astype(jnp.float32)
-    h = h_ref[:].astype(jnp.float32)
-    gamma = gamma_ref[:].astype(jnp.float32)
-    beta = beta_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-
-    # Recompute the forward intermediates in VMEM.
-    n, unit, inv = _ln(p, gamma, beta, eps)
-    _, reset, cand, update = _gates(n, h, hidden)
-
-    # Gate gradients.
-    dh = g * (1.0 - update)
-    du = g * (cand - h)
-    dn_u = du * update * (1.0 - update)
-    dcand = g * update
-    dtanh = dcand * (1.0 - jnp.square(cand))
-    n_c = n[:, hidden : 2 * hidden]
-    dreset = dtanh * n_c
-    dn_c = dtanh * reset
-    dn_r = dreset * reset * (1.0 - reset)
-    dn = jnp.concatenate([dn_r, dn_c, dn_u], axis=-1)
-
-    # LayerNorm backward (per-row statistics over the fused 3H axis).
-    dg_hat = dn * gamma
-    m1 = jnp.mean(dg_hat, -1, keepdims=True)
-    m2 = jnp.mean(dg_hat * unit, -1, keepdims=True)
-    dp = (dg_hat - m1 - unit * m2) * inv
-
-    dproj_ref[:] = dp.astype(dproj_ref.dtype)
-    dh_ref[:] = dh.astype(dh_ref.dtype)
-    # Per-tile partial parameter gradients; summed over the grid outside.
-    dgamma_ref[:] = jnp.sum(dn * unit, axis=0, keepdims=True).astype(dgamma_ref.dtype)
-    dbeta_ref[:] = jnp.sum(dn, axis=0, keepdims=True).astype(dbeta_ref.dtype)
-
-
-def _block(batch: int) -> int:
-    for tile in (256, 128, 64, 32, 16, 8):
-        if batch % tile == 0:
-            return tile
-    return batch
-
-
-def fused_supported(batch: int) -> bool:
-    """The kernel runs single-tile only: the backward's per-tile ``dgamma``/``dbeta``
-    partials have ``[1, 3H]`` blocks, which Mosaic rejects when the grid has more
-    than one tile (first block dim 1 is neither 8-divisible nor the array dim).
-    Multi-tile batches (e.g. the continuous-actor imagination path at T*B rows)
-    fall back to the reference implementation."""
-    return _block(batch) == batch and batch <= 256
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def fused_layernorm_gru(proj: jax.Array, h: jax.Array, gamma: jax.Array, beta: jax.Array, eps: float = 1e-3) -> jax.Array:
-    """``h' = GRUGates(LN(proj) * gamma + beta, h)`` fused in one VMEM pass.
-
-    ``proj``: [B, 3H] fused projection of ``concat(x, h)``; ``h``: [B, H];
-    ``gamma``/``beta``: [3H] LayerNorm parameters.  Returns [B, H].
-    """
-    return _fused_fwd(proj, h, gamma, beta, eps)[0]
-
-
-def _fused_fwd(proj, h, gamma, beta, eps=1e-3):
-    batch, three_h = proj.shape
-    hidden = three_h // 3
-    bt = _block(batch)
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, hidden=hidden, eps=eps),
-        grid=(batch // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, three_h), lambda i: (i, 0)),
-            pl.BlockSpec((bt, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((three_h,), lambda i: (0,)),
-            pl.BlockSpec((three_h,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bt, hidden), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, hidden), h.dtype),
-        interpret=_interpret(),
-    )(proj, h, gamma, beta)
-    return out, (proj, h, gamma, beta)
-
-
-def _fused_bwd(eps, residuals, g):
-    proj, h, gamma, beta = residuals
-    batch, three_h = proj.shape
-    hidden = three_h // 3
-    bt = _block(batch)
-    n_tiles = batch // bt
-    dproj, dh, dgamma_t, dbeta_t = pl.pallas_call(
-        functools.partial(_bwd_kernel, hidden=hidden, eps=eps),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((bt, three_h), lambda i: (i, 0)),
-            pl.BlockSpec((bt, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((three_h,), lambda i: (0,)),
-            pl.BlockSpec((three_h,), lambda i: (0,)),
-            pl.BlockSpec((bt, hidden), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bt, three_h), lambda i: (i, 0)),
-            pl.BlockSpec((bt, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((1, three_h), lambda i: (i, 0)),
-            pl.BlockSpec((1, three_h), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, three_h), proj.dtype),
-            jax.ShapeDtypeStruct((batch, hidden), h.dtype),
-            jax.ShapeDtypeStruct((n_tiles, three_h), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, three_h), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(proj, h, gamma, beta, g)
-    return dproj, dh, dgamma_t.sum(0).astype(gamma.dtype), dbeta_t.sum(0).astype(beta.dtype)
-
-
-fused_layernorm_gru.defvjp(_fused_fwd, _fused_bwd)
-
-
 def reference_layernorm_gru(
     proj: jax.Array, h: jax.Array, gamma: jax.Array, beta: jax.Array, eps: float = 1e-3
 ) -> jax.Array:
-    """Plain-XLA implementation of the same math (f32 statistics, any batch rank);
-    also the LayerNormGRUCell's non-fused path — parity is structural, not test-only."""
+    """``h' = GRUGates(LN(proj) * gamma + beta, h)`` (f32 statistics, any batch
+    rank).  ``proj``: [..., 3H] fused projection of ``concat(x, h)``; ``h``:
+    [..., H]; ``gamma``/``beta``: [3H] LayerNorm parameters.  Returns [..., H] in
+    ``h``'s dtype."""
     p = proj.astype(jnp.float32)
     n, _, _ = _ln(p, gamma.astype(jnp.float32), beta.astype(jnp.float32), eps)
     out, _, _, _ = _gates(n, h.astype(jnp.float32), h.shape[-1])

@@ -121,10 +121,8 @@ def make_ring_attention(
     ``segment_ids``."""
     spec = P(None, axis_name)
     body = functools.partial(ring_attention, axis_name=axis_name, causal=causal, window=window)
-    from sheeprl_tpu.parallel.mesh import shard_map_compat
-
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    fn_seg = shard_map_compat(body, mesh=mesh, in_specs=(spec, spec, spec, spec), out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    fn_seg = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec, spec), out_specs=spec)
 
     def apply(q, k, v, segment_ids=None):
         sharding = NamedSharding(mesh, spec)

@@ -1,8 +1,9 @@
 """Fully-fused RSSM GRU step (Pallas, TPU): matmul + LayerNorm + gates in ONE kernel.
 
-VERDICT r4 #4: the post-matmul fusion (``ops/gru.py``) lifts size-S MFU only ~3%
-because the ``[B, K] @ [K, 3H]`` projection still runs as its own tiny XLA GEMM with
-an HBM round trip for the ``[B, 3H]`` intermediate between it and the gate chain.
+Experimental, and called by nothing outside its tests and
+``benchmarks/fused_step_bench.py`` (ROADMAP D4).  In plain XLA the ``[B, K] @ [K, 3H]``
+projection runs as its own small GEMM with an HBM round trip for the ``[B, 3H]``
+intermediate between it and the gate chain (``ops/gru.py``).
 This kernel keeps the WHOLE step VMEM-resident: weights (``[K, 3H]`` bf16, ~3 MB at
 size S), the concat input row block, the projection, and the gate chain never touch
 HBM between the matmul and the new state.
@@ -17,6 +18,11 @@ LN/gate intermediates in VMEM from the saved ``(xh, h)`` residuals, then forms
 
 Single-tile kernel (whole batch in one block): the RSSM scan runs at B = 16–64 rows,
 far under one (8, 128) tile budget in VMEM; ``fused_step_supported`` gates callers.
+On a TPU v5e (PR 21) it compiles and matches the reference at the size-S shapes
+(B=16, K=1024, H=512; bf16 and f32) and runs out of VMEM at size XL (the bf16
+``[5120, 12288]`` weight alone is 120 MB), as that gate predicts.  Like every Mosaic
+custom call it cannot be partitioned by GSPMD: inside a multi-device ``jit`` it must
+be wrapped in ``shard_map``.
 Reference hot loop: ``/root/reference/sheeprl/algos/dreamer_v3/dreamer_v3.py:134-145``
 (the 64-step recurrent unroll this step implements one iteration of).
 """

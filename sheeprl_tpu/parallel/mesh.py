@@ -89,15 +89,16 @@ def sync_global_devices_with_timeout(name: str, timeout_s: Optional[float] = Non
     _wait_with_timeout(lambda: multihost_utils.sync_global_devices(name), name, timeout_s)
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` moved out of ``jax.experimental`` only in newer releases;
-    dispatch to whichever spelling this jax has so shard_map consumers (the
-    sharded replay mirror, ring attention) work on both (0.4.x ships
-    ``jax.experimental.shard_map.shard_map`` only)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def device_identity() -> Dict[str, Any]:
+    """The device THIS process's JAX runs on, as every benchmark row, ready file
+    and summary names it — read where the measurement happens, never inherited
+    from a parent's idea of the platform."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 #: Env-var spellings of ``mesh.distributed.*`` so the Sebulba launcher and
@@ -290,8 +291,8 @@ class MeshContext:
             getattr(x, "ndim", 0) > batch_axis and x.shape[batch_axis] % dp == 0 for x in leaves
         )
         if dp <= 1 or all_divisible:
-            # ONE pytree device_put — per-leaf dispatches would each pay the
-            # round-trip overhead on remote accelerators.
+            # ONE pytree device_put — per-leaf calls would each pay their own
+            # host-side dispatch.
             return jax.device_put(tree, sh if dp > 1 else rep)
 
         def _put(x):
@@ -352,8 +353,8 @@ class MeshContext:
 
     # -- rng ----------------------------------------------------------------
     # Keys are drawn in batches of _RNG_BATCH: jax.random.split is an eager device
-    # op, and on a remote accelerator one dispatch per key would cost a round trip
-    # per training-loop iteration.  Amortised, the chain stays deterministic:
+    # op, so one split per key would put a dispatch on the critical path of every
+    # training-loop iteration.  Amortised, the chain stays deterministic:
     # refill r of a chain yields keys split(chain_r)[1:], chain_{r+1}=split(chain_r)[0].
     _RNG_BATCH = 64
 

@@ -1,9 +1,9 @@
 """``EnvPool``: a shared-memory, multi-process, fault-tolerant vector env.
 
-The gap it closes (PROFILE_r05 §1): ``gym.vector.SyncVectorEnv`` steps envs
-serially on the host thread and ``AsyncVectorEnv`` pays a pickle round-trip per
-step; at DreamerV3 walker shapes that is ~150 ms/iter of single-core MuJoCo+GL
-while the device sits idle.  ``EnvPool`` runs one worker process per env
+The gap it closes: ``gym.vector.SyncVectorEnv`` steps envs serially on the host
+thread and ``AsyncVectorEnv`` pays a pickle round-trip per step; at DreamerV3
+walker shapes that is single-core MuJoCo+GL time during which the device sits
+idle.  ``EnvPool`` runs one worker process per env
 *group*, all groups stepping concurrently, with obs/reward/done slabs in shared
 memory (``shared.py``) so the per-step host cost is a pipe ack and a memcpy.
 

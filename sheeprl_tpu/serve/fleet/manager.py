@@ -24,6 +24,13 @@ manager owns processes, not requests:
   candidate version (``serve.policies=[spec]``); it is never autoscaled away
   and the front routes the canary fraction to it.
 
+Device placement is explicit (``distributed/chips.py``): the front runs no JAX;
+every replica is a chip-holding child.  On a host with one chip the single
+replica gets it; on a multi-chip host replica slot *i* is pinned to chip *i*;
+on a host without TPUs (or under ``JAX_PLATFORMS=cpu``) replicas share the CPU
+backend.  ``max_replicas`` (+ the canary) beyond the host's chip count is
+refused at launch, and each spawn line says where the child runs.
+
 Like every supervising loop, the manager writes a lifetime summary JSON
 (``fault.summary_path`` / ``SHEEPRL_TPU_SUPERVISE_SUMMARY``) on ALL exit
 paths: spawns, respawns, scale events, per-slot retry/preemption counts.
@@ -42,6 +49,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
+from sheeprl_tpu.distributed import chips
 from sheeprl_tpu.fault import preemption as fault_preemption
 from sheeprl_tpu.fault.counters import RESTARTS_ENV_VAR
 from sheeprl_tpu.fault.preemption import RESUMABLE_EXIT_CODE
@@ -113,6 +121,12 @@ class FleetManager:
         self.max_backoff = float(f_cfg.get("backoff_max_s", 60.0))
         self.drain_timeout_s = float(cfg.serve.drain_timeout_s)
 
+        # Every replica slot the fleet can ever fill holds a chip of its own.
+        self.chips = chips.check_chip_budget(
+            self.max_replicas + (1 if self.canary_spec else 0),
+            "serve.fleet.max_replicas" + (" + the canary" if self.canary_spec else ""),
+        )
+
         self.slots: Dict[str, _Slot] = {}
         self.fleet = None  # FleetAggregator (obs.fleet.dir)
         self.trace_id: Optional[str] = None
@@ -166,13 +180,22 @@ class FleetManager:
         self.slots[name] = slot
         return slot
 
+    def _slot_env(self, slot: _Slot) -> Dict[str, str]:
+        """Device placement by slot: the front runs no policy (CPU backend);
+        a replica holds a chip — on a multi-chip host the chip whose id is the
+        slot index, which is unique among live replicas (the canary sits at
+        ``max_replicas``)."""
+        if slot.role == "front":
+            return chips.cpu_env(os.environ)
+        return chips.accelerator_env(os.environ, chip=slot.index if self.chips > 1 else None)
+
     def _spawn(self, slot: _Slot) -> None:
         if slot.ready_file is not None:
             slot.ready_file.unlink(missing_ok=True)
         if slot.record_path is not None:
             slot.record_path.unlink(missing_ok=True)
         slot.ready_recorded = False
-        env = dict(os.environ)
+        env = self._slot_env(slot)
         env[RESTARTS_ENV_VAR] = str(slot.generation)
         if slot.role == "replica":
             env[SERVE_SLOT_ENV_VAR] = str(slot.index)
@@ -186,7 +209,9 @@ class FleetManager:
         argv = self._front_argv() if slot.role == "front" else self._replica_argv(slot)
         slot.proc = subprocess.Popen(argv, env=env)
         self._event("spawn", slot, generation=slot.generation, pid=slot.proc.pid)
-        self._log(f"spawned {slot.name} (gen {slot.generation}, pid {slot.proc.pid})")
+        self._log(
+            f"spawned {slot.name} (gen {slot.generation}, pid {slot.proc.pid}) on {chips.describe(env)}"
+        )
 
     def _event(self, kind: str, slot: Optional[_Slot] = None, **extra: Any) -> None:
         row = {"kind": kind, "time": time.time(), **extra}

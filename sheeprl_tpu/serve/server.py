@@ -583,6 +583,8 @@ class PolicyServer:
 
     # ------------------------------------------------------------------ artifacts
     def _write_ready_file(self) -> None:
+        from sheeprl_tpu.parallel.mesh import device_identity
+
         ready = self.serve_cfg.ready_file
         if not ready:
             return
@@ -594,6 +596,8 @@ class PolicyServer:
             "precompile_seconds": self.precompile_seconds,
             "precision": self.precision,
             "parity": self.parity,
+            # where THIS replica's JAX runs: benchmark rows copy it from here
+            **device_identity(),
         }
         _atomic_write_json(Path(ready), doc)
 

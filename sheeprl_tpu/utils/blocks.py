@@ -1,9 +1,10 @@
 """Batched gradient-step dispatch: run G gradient steps as ONE jitted call.
 
 The reference dispatches each gradient step eagerly (its train() call per step,
-``/root/reference/sheeprl/algos/dreamer_v3/dreamer_v3.py:682``); on a remote
-accelerator every dispatch is a host→device round trip, and with replay ratios of
-0.5–1 the per-call latency — not the math — floors the end-to-end step rate.  Here
+``/root/reference/sheeprl/algos/dreamer_v3/dreamer_v3.py:682``); every dispatch
+costs host time (argument handling, the runtime call, a pass of params/opt-state
+through the program boundary), and with replay ratios of 0.5–1 and a ~20 ms step
+that per-call overhead is paid once per gradient step.  Here
 the per-step batches are stacked to ``[G, T, B, ...]`` and a ``lax.scan`` over the
 leading axis executes the whole block inside one jit:
 
@@ -65,8 +66,8 @@ def make_train_block(step_fn: Callable, target_update_freq: int = 1, count_offse
 
     def block(carry, step_batches, base_key, start_count):
         # Stack the per-step batches INSIDE the jit: an eager jnp.stack per leaf
-        # would cost one dispatch round trip each on a remote accelerator — the
-        # exact latency this block exists to remove.
+        # would cost one dispatch each — the exact per-call overhead this block
+        # exists to remove.
         if len(step_batches) == 1:
             stacked = jax.tree.map(lambda x: x[None], step_batches[0])
         else:

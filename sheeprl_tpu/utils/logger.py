@@ -12,7 +12,7 @@ import json
 import os
 import pathlib
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
@@ -91,6 +91,32 @@ class TensorBoardLogger:
         if self._jsonl is not None:
             self._jsonl.close()
             self._jsonl = None
+
+
+def read_scalars(log_dir: os.PathLike) -> Dict[str, List[float]]:
+    """``{tag: [values in write order]}`` from the TensorBoard event files
+    :class:`TensorBoardLogger` wrote into ``log_dir``.  Parsed with the protobuf
+    schema of the library that wrote them (tensorboardX), so a process that
+    holds the chip reads its own metrics back without importing TensorFlow (what
+    ``tensorboard``'s ``EventAccumulator`` does)."""
+    import struct
+
+    from tensorboardX.proto import event_pb2
+
+    out: Dict[str, List[float]] = {}
+    for path in sorted(pathlib.Path(log_dir).glob("events.out.tfevents.*")):
+        data = path.read_bytes()
+        pos = 0
+        # TFRecord framing: u64 length, u32 crc, payload, u32 crc
+        while pos + 12 <= len(data):
+            (length,) = struct.unpack("<Q", data[pos : pos + 8])
+            event = event_pb2.Event()
+            event.ParseFromString(data[pos + 12 : pos + 12 + length])
+            pos += 12 + length + 4
+            for value in event.summary.value:
+                if value.HasField("simple_value"):
+                    out.setdefault(value.tag, []).append(float(value.simple_value))
+    return out
 
 
 class MlflowLogger:

@@ -127,13 +127,15 @@ def test_ir003_gate_and_top_level_callback_are_clean():
 
 # ------------------------------------------------------------------------ IR004
 def test_ir004_flags_collective_in_single_mesh_graph():
-    from sheeprl_tpu.parallel.mesh import build_mesh, shard_map_compat
+    from sheeprl_tpu.parallel.mesh import build_mesh
     from jax.sharding import PartitionSpec as P
 
     mesh = build_mesh(devices=jax.devices()[:1])
 
     def f(x):
-        return shard_map_compat(lambda v: jax.lax.psum(v, "data"), mesh, (P("data"),), P())(x)
+        return jax.shard_map(
+            lambda v: jax.lax.psum(v, "data"), mesh=mesh, in_specs=(P("data"),), out_specs=P()
+        )(x)
 
     art = lower_entry(_entry(jax.jit(f), (jnp.zeros((8,)),)))
     findings = check_collectives(art)

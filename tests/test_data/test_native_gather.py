@@ -95,3 +95,18 @@ def test_replay_buffer_native_vs_fallback(lib_available, monkeypatch):
     assert set(a) == set(b)
     for k in b:
         np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+def test_library_name_is_derived_from_the_committed_source(lib_available, tmp_path, monkeypatch):
+    """A library left on disk by another checkout or an older gather.cpp cannot
+    stand in for the committed source: the file name carries the source hash."""
+    import hashlib
+
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native.library_path().name == f"_gather_{digest}.so"
+    assert native.status in ("built", "loaded") and native.library_path().is_file()
+
+    changed = tmp_path / "gather.cpp"
+    changed.write_bytes(native._SRC.read_bytes() + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", changed)
+    assert native.library_path().name != f"_gather_{digest}.so"  # no library yet: it would be built

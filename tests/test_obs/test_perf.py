@@ -4,10 +4,8 @@ exactly-once semantics, MFU agreement with ``bench.py``, and the monitor e2e
 (perf_report.json + forced slowdown -> ONE auto-capture + ONE perf_regression
 flight-recorder event)."""
 
-import importlib.util
 import json
 import math
-import pathlib
 import time
 
 import jax
@@ -23,8 +21,6 @@ from sheeprl_tpu.obs.perf import (
     PerfPlane,
     StepTimeWatchdog,
 )
-
-REPO = pathlib.Path(__file__).resolve().parents[2]
 
 
 @pytest.fixture(autouse=True)
@@ -218,20 +214,51 @@ def test_register_compiled_from_aot_executable():
     assert perf.registered_cost_models()["serve/test/b8"]["calls"] == 5
 
 
-def test_mfu_agreement_bench_vs_perf_plane():
-    """Satellite (b): bench.py sources FLOPs + peak figures from the perf
-    registry helpers — the offline MFU and ``Perf/mfu`` share one definition."""
-    spec = importlib.util.spec_from_file_location("bench_under_test", REPO / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench.PEAK_FLOPS is perf.PEAK_FLOPS
-    assert bench._peak_flops is perf.peak_flops
-
+def test_mfu_from_flops_divides_by_the_device_peak():
+    """The one MFU definition ``bench.py`` (offline) and ``Perf/mfu`` (in-run) share."""
     device = jax.devices()[0]
     flops, steps_per_sec = 4.2e9, 12.5
     expected = flops * steps_per_sec / perf.peak_flops(device)
     assert math.isclose(perf.mfu_from_flops(flops, steps_per_sec, device), expected)
     assert perf.peak_flops(device) > 0 and perf.peak_hbm_bw(device) > 0
+
+
+def test_instrument_compiles_once_and_reports_a_failed_registration(tmp_path, caplog):
+    """Registration reads ``Compiled.cost_analysis()`` (the form every backend
+    has) from the executable the first call reuses — ONE backend compile — and a
+    hot path whose cost model cannot be read is named in the log and in
+    ``perf_report.json`` instead of silently dropping out of ``Perf/mfu``."""
+    from sheeprl_tpu.obs.watchdog import RecompileWatchdog
+
+    cfg = {"obs": {"perf": {"enabled": True}}}
+    step = jax.jit(lambda x: jnp.tanh(x @ x) * 3.0)
+    x = jnp.ones((24, 24), jnp.float32)
+    jax.block_until_ready(x)
+    dog = RecompileWatchdog()
+    try:
+        jax.block_until_ready(perf.instrument(cfg, "once/step", step)(x))
+        assert dog.total_compiles == 1
+    finally:
+        dog.close()
+    assert perf.registered_cost_models()["once/step"]["flops"] > 0
+    assert perf.registration_failures() == {}
+
+    class _NoCostModel:
+        def lower(self, *args):
+            raise NotImplementedError("no cost model on this backend")
+
+        def __call__(self, x):
+            return x
+
+    with caplog.at_level("WARNING", logger="sheeprl_tpu.obs.perf"):
+        wrapped = perf.instrument(cfg, "broken/step", _NoCostModel())
+        assert wrapped(x) is x and wrapped(x) is x
+    assert len([r for r in caplog.records if "broken/step" in r.getMessage()]) == 1
+    assert "NotImplementedError" in perf.registration_failures()["broken/step"]
+    plane = PerfPlane(cfg)
+    path = str(tmp_path / "perf_report.json")
+    plane.write_report(path)
+    assert "broken/step" in json.load(open(path))["registration_failures"]
 
 
 def test_peak_flops_table_device_kinds():
@@ -242,8 +269,11 @@ def test_peak_flops_table_device_kinds():
 
     assert perf.peak_flops(_Dev("TPU v4")) == perf.PEAK_FLOPS["TPU v4"]
     assert perf.peak_flops(_Dev("TPU v5 lite")) == perf.PEAK_FLOPS["TPU v5 lite"]
-    # unknown accelerator falls to the v4 default; CPUs get the nominal figure
-    assert perf.peak_flops(_Dev("TPU v9")) == 275e12
+    # an accelerator the table does not know is an error, never a guessed peak
+    with pytest.raises(perf.UnknownDeviceError, match="TPU v9"):
+        perf.peak_flops(_Dev("TPU v9"))
+    with pytest.raises(perf.UnknownDeviceError, match="TPU v9"):
+        perf.peak_hbm_bw(_Dev("TPU v9"))
     assert 0 < perf.peak_flops(_Dev("cpu", platform="cpu")) < 1e12
 
 

@@ -71,7 +71,7 @@ def test_fleet_survives_a_sigkilled_replica_with_zero_lost_replies(registry, tmp
     fleet_dir = tmp_path / "fleet"
     summary_path = tmp_path / "supervisor_summary.json"
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     for var in ("SHEEPRL_TPU_FLEET", "SHEEPRL_TPU_FLEET_SUMMARY", "SHEEPRL_TPU_SUPERVISE_SUMMARY"):
         env.pop(var, None)
     sup = subprocess.Popen(

@@ -1,39 +1,9 @@
-"""CLI-level observability glue: the JAX_PLATFORMS late-init warning and obs config
-validation."""
+"""CLI-level observability glue: obs config validation."""
 
-import warnings
-
-import jax
 import pytest
 
-from sheeprl_tpu.cli import _honor_platform_env, check_configs
+from sheeprl_tpu.cli import check_configs
 from sheeprl_tpu.config.core import compose
-
-
-def test_honor_platform_env_warns_on_backend_mismatch(monkeypatch):
-    jax.devices()  # force backend initialisation (idempotent under the test suite)
-    prev = jax.config.jax_platforms
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # request != the live cpu backend
-    try:
-        with pytest.warns(UserWarning, match="already initialized"):
-            _honor_platform_env()
-    finally:
-        jax.config.update("jax_platforms", prev)
-
-
-def test_honor_platform_env_silent_when_request_already_satisfied(monkeypatch):
-    jax.devices()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")  # the live backend IS cpu: no warning
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        _honor_platform_env()
-
-
-def test_honor_platform_env_silent_when_unset(monkeypatch):
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        _honor_platform_env()
 
 
 def _ppo_cfg(*overrides):

@@ -9,10 +9,13 @@ The wall-clock half of the story is the ``anakin_compile_seconds`` BENCH row
 """
 
 import json
+from pathlib import Path
 
+import jax
 import pytest
 
 from sheeprl_tpu.cli import run
+from sheeprl_tpu.utils import compile_cache
 
 TINY_ANAKIN = [
     "exp=ppo",
@@ -35,6 +38,70 @@ TINY_ANAKIN = [
     "metric.log_every=1",
     "buffer.memmap=False",
 ]
+
+
+@pytest.fixture
+def restore_cache_config():
+    """enable_compile_cache edits process-global jax config: put it back."""
+    keys = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    before = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
+
+
+def test_env_var_places_the_cache_and_the_code_sets_no_directory(tmp_path, monkeypatch, restore_cache_config):
+    """JAX_COMPILATION_CACHE_DIR wins over compile_cache.dir, and the resolver
+    leaves jax_compilation_cache_dir alone (JAX reads the variable itself)."""
+    outside, configured = tmp_path / "from_outside", tmp_path / "from_config"
+    monkeypatch.setenv(compile_cache.CACHE_DIR_ENV_VAR, str(outside))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache({"enabled": True, "dir": str(configured)}) == str(outside)
+    assert jax.config.jax_compilation_cache_dir == before
+    assert compile_cache.resolve_cache_dir(str(configured)) == str(outside)
+    assert not configured.exists()
+    assert compile_cache.empty_cold_start_dir("row").startswith(str(outside))
+
+
+def test_unset_env_var_means_one_fixed_path_inside_the_checkout(tmp_path, monkeypatch):
+    """Never from ~, the working directory, tempfile, a pid or the clock."""
+    monkeypatch.delenv(compile_cache.CACHE_DIR_ENV_VAR, raising=False)
+    repo = Path(__file__).resolve().parents[2]
+    expected = str(repo / ".xla_cache")
+    assert compile_cache.resolve_cache_dir() == expected
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    assert compile_cache.resolve_cache_dir() == compile_cache.resolve_cache_dir(None) == expected
+    assert ".xla_cache/" in (repo / ".gitignore").read_text().splitlines()
+    # an explicit compile_cache.dir is still honoured when nothing outside placed it
+    assert compile_cache.resolve_cache_dir(str(tmp_path / "mine")) == str(tmp_path / "mine")
+
+
+def test_cold_start_dir_is_fixed_and_emptied(tmp_path, monkeypatch):
+    monkeypatch.setenv(compile_cache.CACHE_DIR_ENV_VAR, str(tmp_path))
+    first = Path(compile_cache.empty_cold_start_dir("serve_startup"))
+    assert first == tmp_path / "cold_start" / "serve_startup" and first.is_dir()
+    (first / "stale-entry").write_text("x")
+    assert Path(compile_cache.empty_cold_start_dir("serve_startup")) == first
+    assert list(first.iterdir()) == []
+
+
+def test_no_code_but_the_resolver_sets_the_cache_dir():
+    repo = Path(__file__).resolve().parents[2]
+    offenders = [
+        str(path.relative_to(repo))
+        for root in ("sheeprl_tpu", "benchmarks")
+        for path in (repo / root).rglob("*.py")
+        if "jax_compilation_cache_dir" in path.read_text()
+    ] + [name for name in ("bench.py", "chip_smoke.py") if "jax_compilation_cache_dir" in (repo / name).read_text()]
+    assert offenders == ["sheeprl_tpu/utils/compile_cache.py"]
 
 
 def _cache_files(cache_dir):
