@@ -60,6 +60,7 @@ from sheeprl_tpu.distributions import (
 )
 from sheeprl_tpu.obs import TrainingMonitor, flight_recorder
 from sheeprl_tpu.obs.health import diagnostics, health_enabled, replay_age_metrics
+from sheeprl_tpu.obs.perf import scope
 from sheeprl_tpu.rollout import PipelinedPlayer, rollout_metrics
 from sheeprl_tpu.utils.env import make_vector_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
@@ -108,8 +109,20 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
         decoupled = wm_cfg.get("decoupled_rssm", False)
 
         def wm_loss_fn(wm_params):
-            embed = world_model.apply(wm_params, batch_obs, method=WorldModel.encode)  # [T,B,E]
+            with scope("world_model/encoder"):
+                embed = world_model.apply(wm_params, batch_obs, method=WorldModel.encode)  # [T,B,E]
+            with scope("world_model/rssm"):
+                posts, recs, post_logits, prior_logits = rssm_unroll(wm_params, embed)
+            latents = jnp.concatenate([posts, recs], -1)  # [T,B,L]
+            with scope("world_model/heads"):
+                recon = world_model.apply(wm_params, latents, method=WorldModel.decode)
+                reward_logits = world_model.apply(wm_params, latents, method=WorldModel.reward)
+                continue_logits = world_model.apply(wm_params, latents, method=WorldModel.continues)
+            with scope("world_model/loss"):
+                rec_loss, metrics = wm_loss(recon, reward_logits, continue_logits, post_logits, prior_logits)
+            return rec_loss, (posts, recs, metrics)
 
+        def rssm_unroll(wm_params, embed):
             if decoupled:
                 # DecoupledRSSM (reference agent.py:501-593): q(z|o) has no recurrent
                 # dependency, so the WHOLE posterior batch is one vectorized call and
@@ -151,9 +164,9 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
                 _, (recs, posts, post_logits, prior_logits) = jax.lax.scan(
                     step, init, (batch_actions, embed, is_first, keys), unroll=8
                 )
-            latents = jnp.concatenate([posts, recs], -1)  # [T,B,L]
-            recon = world_model.apply(wm_params, latents, method=WorldModel.decode)
+            return posts, recs, post_logits, prior_logits
 
+        def wm_loss(recon, reward_logits, continue_logits, post_logits, prior_logits):
             obs_lp = 0.0
             for k in cnn_keys:
                 target = data[k].astype(jnp.float32) / 255.0 - 0.5
@@ -162,14 +175,8 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
             for k in mlp_keys:
                 obs_lp = obs_lp + SymlogDistribution(recon[k], dims=1).log_prob(data[k])
 
-            reward_lp = TwoHotEncodingDistribution(
-                world_model.apply(wm_params, latents, method=WorldModel.reward), dims=1
-            ).log_prob(data["rewards"])
-            continue_lp = (
-                Independent(
-                    BernoulliSafeMode(world_model.apply(wm_params, latents, method=WorldModel.continues)), 1
-                ).log_prob(1.0 - data["terminated"])
-            )
+            reward_lp = TwoHotEncodingDistribution(reward_logits, dims=1).log_prob(data["rewards"])
+            continue_lp = Independent(BernoulliSafeMode(continue_logits), 1).log_prob(1.0 - data["terminated"])
 
             post_logits_s = post_logits.reshape(T, B, stoch, discrete)
             prior_logits_s = prior_logits.reshape(T, B, stoch, discrete)
@@ -191,13 +198,14 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
             metrics["State/prior_entropy"] = (
                 Independent(OneHotCategorical(prior_logits_s), 1).entropy().mean()
             )
-            return rec_loss, (posts, recs, metrics)
+            return rec_loss, metrics
 
         (rec_loss, (posts, recs, wm_metrics)), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(
             params["world_model"]
         )
-        wm_updates, new_wm_opt = wm_opt.update(wm_grads, opt_states["world_model"], params["world_model"])
-        new_wm_params = optax.apply_updates(params["world_model"], wm_updates)
+        with scope("wm_optimizer"):
+            wm_updates, new_wm_opt = wm_opt.update(wm_grads, opt_states["world_model"], params["world_model"])
+            new_wm_params = optax.apply_updates(params["world_model"], wm_updates)
 
         # ------------------------------------------------ imagination + actor
         latent0 = jax.lax.stop_gradient(jnp.concatenate([posts, recs], -1)).reshape(T * B, -1)
@@ -206,6 +214,12 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
         true_continue0 = (1.0 - data["terminated"]).reshape(T * B, 1)
 
         def actor_loss_fn(actor_params):
+            with scope("imagination"):
+                traj, imagined_actions = imagine(actor_params)
+            with scope("actor"):
+                return actor_loss(actor_params, traj, imagined_actions)
+
+        def imagine(actor_params):
             a0_tuple, _ = actor.apply(actor_params, latent0, k_a0)
             a0 = jnp.concatenate(a0_tuple, -1)
 
@@ -222,7 +236,9 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
             _, (latents_img, actions_img) = jax.lax.scan(img_step, (prior0, rec0, a0), keys, unroll=5)
             traj = jnp.concatenate([latent0[None], latents_img], 0)  # [H+1, TB, L]
             imagined_actions = jnp.concatenate([a0[None], actions_img], 0)  # [H+1, TB, A]
+            return traj, imagined_actions
 
+        def actor_loss(actor_params, traj, imagined_actions):
             values = TwoHotEncodingDistribution(critic.apply(params["critic"], traj), dims=1).mean
             rewards_img = TwoHotEncodingDistribution(
                 world_model.apply(new_wm_params, traj, method=WorldModel.reward), dims=1
@@ -281,8 +297,9 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
             return policy_loss, aux
 
         (policy_loss, actor_aux), actor_grads = jax.value_and_grad(actor_loss_fn, has_aux=True)(params["actor"])
-        actor_updates, new_actor_opt = actor_opt.update(actor_grads, opt_states["actor"], params["actor"])
-        new_actor_params = optax.apply_updates(params["actor"], actor_updates)
+        with scope("actor_optimizer"):
+            actor_updates, new_actor_opt = actor_opt.update(actor_grads, opt_states["actor"], params["actor"])
+            new_actor_params = optax.apply_updates(params["actor"], actor_updates)
 
         # ------------------------------------------------ critic
         traj = actor_aux["traj"]
@@ -297,16 +314,21 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
             loss = -qv.log_prob(lambda_values) - qv.log_prob(jax.lax.stop_gradient(target_values))
             return jnp.mean(loss * discount[:-1][..., 0])
 
-        value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
-        critic_updates, new_critic_opt = critic_opt.update(critic_grads, opt_states["critic"], params["critic"])
-        new_critic_params = optax.apply_updates(params["critic"], critic_updates)
+        with scope("critic"):
+            value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
+        with scope("critic_optimizer"):
+            critic_updates, new_critic_opt = critic_opt.update(critic_grads, opt_states["critic"], params["critic"])
+            new_critic_params = optax.apply_updates(params["critic"], critic_updates)
 
         # EMA target critic (reference dreamer_v3.py:674-680).
-        new_target = jax.lax.cond(
-            update_target,
-            lambda: jax.tree.map(lambda tp, cp: (1 - tau) * tp + tau * cp, params["target_critic"], new_critic_params),
-            lambda: params["target_critic"],
-        )
+        with scope("target_ema"):
+            new_target = jax.lax.cond(
+                update_target,
+                lambda: jax.tree.map(
+                    lambda tp, cp: (1 - tau) * tp + tau * cp, params["target_critic"], new_critic_params
+                ),
+                lambda: params["target_critic"],
+            )
 
         new_params = {
             "world_model": new_wm_params,
@@ -318,21 +340,23 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
         metrics = dict(wm_metrics)
         metrics["Loss/policy_loss"] = policy_loss
         metrics["Loss/value_loss"] = value_loss
-        metrics["Grads/world_model"] = optax.global_norm(wm_grads)
-        metrics["Grads/actor"] = optax.global_norm(actor_grads)
-        metrics["Grads/critic"] = optax.global_norm(critic_grads)
-        if health_enabled(cfg):  # trace-time constant (obs/health.py)
-            metrics.update(
-                diagnostics(
-                    grads={"world_model": wm_grads, "actor": actor_grads, "critic": critic_grads},
-                    params=new_params,
-                    updates={"world_model": wm_updates, "actor": actor_updates, "critic": critic_updates},
-                    aux={"critic_value_mean": lambda_values.mean(), "critic_value_std": lambda_values.std()},
+        # default-on instrumentation under one name, so that a capture prices it
+        with scope("health"):
+            metrics["Grads/world_model"] = optax.global_norm(wm_grads)
+            metrics["Grads/actor"] = optax.global_norm(actor_grads)
+            metrics["Grads/critic"] = optax.global_norm(critic_grads)
+            if health_enabled(cfg):  # trace-time constant (obs/health.py)
+                metrics.update(
+                    diagnostics(
+                        grads={"world_model": wm_grads, "actor": actor_grads, "critic": critic_grads},
+                        params=new_params,
+                        updates={"world_model": wm_updates, "actor": actor_updates, "critic": critic_updates},
+                        aux={"critic_value_mean": lambda_values.mean(), "critic_value_std": lambda_values.std()},
+                    )
                 )
-            )
-        metrics = maybe_inject_nonfinite(cfg, metrics)
-        if strict_enabled(cfg):  # trace-time constant: callback exists only in strict runs
-            nan_scan(metrics, "dreamer_v3/train_step")
+            metrics = maybe_inject_nonfinite(cfg, metrics)
+            if strict_enabled(cfg):  # trace-time constant: callback exists only in strict runs
+                nan_scan(metrics, "dreamer_v3/train_step")
         return new_params, new_opt_states, actor_aux["moments"], metrics
 
     return train_step, init_opt_states

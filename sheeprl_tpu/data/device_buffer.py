@@ -762,6 +762,7 @@ def make_device_replay(
     from sheeprl_tpu.obs import flight_recorder
     from sheeprl_tpu.obs import perf as obs_perf
     from sheeprl_tpu.utils.blocks import BlockDispatcher, IndexedBlockDispatcher
+    from sheeprl_tpu.utils.timer import timer
 
     kwargs = dict(dispatcher_kwargs or {})
     kwargs.setdefault("base_key", ctx.rng())
@@ -808,23 +809,28 @@ def make_device_replay(
         prefetcher, rb_lock = None, contextlib.nullcontext()
         dp = mirror.local_dp if multiprocess else mirror.dp
 
+        # The three parts of a block's host time are spans of their own (children of
+        # the loop's Time/phase_dispatch): sample, stage, and the jitted call(s)
+        # (Time/dispatch_call, in the dispatcher).
         def run_block(carry, n: int, start_count: int, stage_next: bool = True):
-            envs_idx, starts_idx = sample_index_block(rb, batch_size, seq_len, n, dp=dp)
+            with timer("Time/dispatch_sample"):
+                envs_idx, starts_idx = sample_index_block(rb, batch_size, seq_len, n, dp=dp)
             if recorder is not None:
                 # Mirror rings are donated per scatter, so row references cannot
                 # outlive the dispatch: stage the sampled indices (the dump then
                 # carries state + indices; the batch is reconstructible from the
                 # host buffer, which stays the source of truth).
-                recorder.stage_step(
-                    carry=carry,
-                    base_key=base_key,
-                    scalars={
-                        "start_count": int(start_count),
-                        "n_steps": int(n),
-                        "envs_idx": np.asarray(envs_idx).tolist(),
-                        "starts_idx": np.asarray(starts_idx).tolist(),
-                    },
-                )
+                with timer("Time/dispatch_stage"):
+                    recorder.stage_step(
+                        carry=carry,
+                        base_key=base_key,
+                        scalars={
+                            "start_count": int(start_count),
+                            "n_steps": int(n),
+                            "envs_idx": np.asarray(envs_idx).tolist(),
+                            "starts_idx": np.asarray(starts_idx).tolist(),
+                        },
+                    )
             arrays = mirror.global_view() if multiprocess else mirror.arrays
             return dispatcher.dispatch(carry, arrays, envs_idx, starts_idx, start_count)
 
@@ -835,14 +841,16 @@ def make_device_replay(
         prefetcher, rb_lock, sample_block = make_replay_prefetcher(rb, ctx, cfg, batch_size, seq_len)
 
         def run_block(carry, n: int, start_count: int, stage_next: bool = True):
-            sample = prefetcher.get(n, stage_next=stage_next) if prefetcher is not None else sample_block(n)
+            with timer("Time/dispatch_sample"):
+                sample = prefetcher.get(n, stage_next=stage_next) if prefetcher is not None else sample_block(n)
             if recorder is not None:  # device-array references only: no host sync
-                recorder.stage_step(
-                    batches=sample,
-                    carry=carry,
-                    base_key=base_key,
-                    scalars={"start_count": int(start_count), "n_steps": len(sample)},
-                )
+                with timer("Time/dispatch_stage"):
+                    recorder.stage_step(
+                        batches=sample,
+                        carry=carry,
+                        base_key=base_key,
+                        scalars={"start_count": int(start_count), "n_steps": len(sample)},
+                    )
             return dispatcher.dispatch(carry, sample, start_count)
 
     # rb_lock stays internal: rb_add (below) and the prefetcher's sampler are the

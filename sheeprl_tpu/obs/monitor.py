@@ -71,7 +71,7 @@ class TrainingMonitor:
         # runs that never turned the tracer on.
         from sheeprl_tpu.obs.perf import PerfPlane
 
-        self.perf = PerfPlane(cfg)
+        self.perf = PerfPlane(cfg, log_dir=log_dir)
         # Capture machinery lives in the common path (not behind obs.enabled) so
         # the perf watchdog's anomaly auto-capture can open an XProf window on an
         # otherwise-untraced run.

@@ -30,16 +30,30 @@ and a handful of free functions used from the lowering seams:
    ``perf_regression`` flight-recorder event and exports a ``perf_anomalies``
    fleet gauge.
 
-All state that outlives a run (the registry) is process-global and reset from
-``cli.run_algorithm``'s ``finally`` block so multirun jobs do not bleed cost
-models into each other.
+4. **Scope maps** — :func:`scope` names a part of a jitted hot path
+   (``jax.named_scope`` + the name's declaration); :func:`register_compiled`
+   writes, once per registered program that declares scopes,
+   ``<log_dir>/scopes/<name>.json``: the HLO module's name and instruction name
+   -> ``{"<scope> <fwd|bwd>": share}``, parsed from ``Compiled.as_text()``
+   (:func:`scopes_tag` keeps the compile cache from handing back an executable
+   compiled under other scopes).  A device capture
+   names its op events by HLO instruction only (no ``op_name``), so this file is
+   what groups a capture's device time by the program's layers
+   (``perfbench/readers/spans.py``).
+
+All state that outlives a run (the registry, the declared scopes, the scope
+directory) is process-global and reset from ``cli.run_algorithm``'s ``finally``
+block so multirun jobs do not bleed cost models into each other.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import logging
 import os
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Mapping, MutableMapping, Optional, Tuple
@@ -63,6 +77,9 @@ __all__ = [
     "registered_cost_models",
     "report_path",
     "reset",
+    "scope",
+    "scope_map",
+    "scopes_tag",
 ]
 
 PERF_REPORT_ENV_VAR = "SHEEPRL_TPU_PERF_REPORT"
@@ -263,8 +280,11 @@ def registered_cost_models() -> Dict[str, Dict[str, Any]]:
 
 def reset() -> None:
     """Clear the process-global registry (between multirun jobs / in tests)."""
+    global _scope_dir
     with _lock:
         _registry.clear()
+        _scope_names.clear()
+        _scope_dir = None
 
 
 # ------------------------------------------------------------------- cost analysis
@@ -313,6 +333,168 @@ def register_compiled(name: str, compiled: Any) -> None:
         record_registration_failure(name, ValueError("cost_analysis() reported no flops on this backend"))
         return
     register_cost_model(name, flops, bytes_accessed, **_memory_info(compiled))
+    if _scope_dir is not None:
+        _write_scope_map(name, compiled)
+
+
+# ---------------------------------------------------------------------- scope maps
+
+_scope_names: set = set()
+_scope_dir: Optional[str] = None  # <log_dir>/scopes once a PerfPlane with a log dir exists
+
+# ``  %fusion.12 = bf16[..] fusion(..), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(f)/.."}``
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_HLO_MODULE = re.compile(r"HloModule\s+([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_HLO_REFERENCE = re.compile(r"%([\w.\-]+)")
+_JIT_COMPONENT = re.compile(r"jit\([^()]*\)/?")
+_TRANSFORM_OPEN = re.compile(r"\b\w+\(")
+
+
+def scope(name: str):
+    """``with scope("world_model/rssm"):`` inside a jitted hot path: a
+    ``jax.named_scope`` whose name is also declared here, so that the program's
+    scope map can tell it from the Flax modules and primitives after it in an
+    ``op_name`` path.  Metadata only: the compiled program holds the same ops."""
+    import jax
+
+    _scope_names.add(name)
+    return jax.named_scope(name)
+
+
+def scopes_tag():
+    """``with scopes_tag():`` around a few small ops of a program that declares scopes,
+    traced after them: the ops carry the declared names as a frontend attribute.
+
+    JAX's persistent compile cache keys a program without its metadata, so a cache
+    that a source with other scopes (or none) filled would hand back that source's
+    executable, with its ``op_name`` paths.  A frontend attribute is part of the
+    program and of the key: a program compiled under other scope names is another
+    entry.  (A scope moved under the same names is not; clear the entry then.)  A
+    program that declares none is left as it is."""
+    from jax.experimental.xla_metadata import set_xla_metadata
+
+    if not _scope_names:
+        return contextlib.nullcontext()
+    return set_xla_metadata(scopes=",".join(sorted(_scope_names)))
+
+
+def _scope_of(op_name: str) -> str:
+    """``jit(block)/jit(main)/while/body/transpose(jvp(world_model/rssm))/while/body/mul``
+    -> ``"world_model/rssm bwd"``: the leftmost declared scope of the path (the
+    transformations' wrappers dropped), the declared scopes nested directly under
+    it, and the direction; ``""`` where the path holds no declared scope."""
+    parts = _TRANSFORM_OPEN.sub("", _JIT_COMPONENT.sub("", op_name)).replace(")", "").split("/")
+    found: List[str] = []
+    i = 0
+    while i < len(parts):
+        hits = [n for n in _scope_names if parts[i : i + n.count("/") + 1] == n.split("/")]
+        if hits:
+            found.append(max(hits, key=len))
+            i += found[-1].count("/") + 1
+        elif found:
+            break
+        else:
+            i += 1
+    if not found:
+        return ""
+    return "/".join(found) + (" bwd" if "transpose(" in op_name else " fwd")
+
+
+def scope_map(hlo_text: str) -> Tuple[Dict[str, Dict[str, float]], List[str]]:
+    """``(ops, inherited)``: HLO instruction name -> ``{"<scope> <fwd|bwd>": share}``
+    for every instruction a device capture can show (those of a fused computation
+    run inside their fusion), the shares of one instruction summing to 1 (``{}``:
+    unscoped); and the names of those that took their shares from a neighbour.
+
+    An instruction that calls a computation (a fusion, mostly) is split over the
+    scopes of the instructions inside it, by their count: XLA fuses a ``Health/*``
+    norm's reduction into the Adam update that reads the same tensor and roots the
+    fusion at the reduction, so the fusion's own ``op_name`` (its root's) would book
+    the optimizer's pass under ``health``.  Any other instruction takes its own
+    ``op_name``, or that of the computation it calls (its root's, else the first one
+    inside it that has one).  One whose path holds no declared scope (the compiler's
+    own copies and slices, which carry no metadata; loop-invariant work that JAX
+    hoists out of a scan without the outer name stack) takes the shares of the first
+    instruction that reads it, else of the first it reads: data movement is charged
+    to the scope it serves."""
+    own: Dict[str, str] = {}  # instruction -> op_name
+    calls: Dict[str, str] = {}  # instruction -> the computation it calls
+    home: Dict[str, str] = {}  # instruction -> the computation it is in
+    reads: Dict[str, List[str]] = {}  # instruction -> the instructions it reads
+    stands_for: Dict[str, str] = {}  # computation -> op_name of its root, else of its first named instruction
+    inside: Dict[str, Dict[str, int]] = {}  # computation -> how many instructions of each scope
+    scope_of = functools.lru_cache(maxsize=None)(_scope_of)  # a layer's ops share their path up to the primitive
+    computation = ""
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(2)
+        home[name] = computation
+        reads[name] = _HLO_REFERENCE.findall(line[m.end() :])
+        op = _HLO_OP_NAME.search(line)
+        if op:
+            own[name] = op.group(1)
+            if m.group(1) or computation not in stands_for:
+                stands_for[computation] = op.group(1)
+            found = scope_of(op.group(1))
+            if found:
+                counts = inside.setdefault(computation, {})
+                counts[found] = counts.get(found, 0) + 1
+        called = _HLO_CALLS.search(line)
+        if called:
+            calls[name] = called.group(1)
+    inner = set(calls.values())
+    ops: Dict[str, Dict[str, float]] = {}
+    for name, comp in home.items():
+        if comp in inner:
+            continue
+        counts = inside.get(calls.get(name, ""), {})
+        found = scope_of(own.get(name) or stands_for.get(calls.get(name, ""), ""))
+        total = sum(counts.values())
+        ops[name] = {key: n / total for key, n in counts.items()} if counts else {found: 1.0} if found else {}
+    read_by: Dict[str, List[str]] = {}
+    for name in ops:
+        for other in reads[name]:
+            read_by.setdefault(other, []).append(name)
+    inherited: List[str] = []
+    for _ in range(4):  # copy-start -> copy-done -> tuple -> the loop that reads it
+        for name, shares in ops.items():
+            if not shares:
+                near = [ops[n] for n in read_by.get(name, []) + reads[name] if ops.get(n)]
+                if near:
+                    ops[name] = dict(near[0])
+                    inherited.append(name)
+    return ops, inherited
+
+
+def _write_scope_map(name: str, compiled: Any) -> None:
+    """``<log_dir>/scopes/<name>.json`` for one registered program that declares
+    scopes; best-effort."""
+    if not _scope_names:
+        return
+    try:
+        t0 = time.perf_counter()
+        text = compiled.as_text()
+        module = _HLO_MODULE.match(text)  # the name a capture's module events carry
+        ops, inherited = scope_map(text)
+        if not any(ops.values()):
+            # another program's scopes; or the compile cache handed back an executable that a
+            # source without these scopes compiled (its entry has to go before a map can be had)
+            _log.info("perf: no scope map for %s: its compiled text holds none of the declared scopes", name)
+            return
+        doc = {"program": name, "module": module.group(1) if module else "", "ops": ops, "inherited": inherited}
+        _dump_json(os.path.join(_scope_dir, f"{name}.json"), doc)
+        _log.info("perf: scope map of %s (%d ops) written in %.2fs", name, len(ops), time.perf_counter() - t0)
+    except Exception as exc:  # attribution must not kill the hot path it measures
+        _log.warning("perf: no scope map for %s (%s: %s)", name, type(exc).__name__, exc)
 
 
 def _unwrap_jit(fn: Any) -> Optional[Any]:
@@ -527,9 +709,12 @@ class PerfPlane:
     ``write_report(path)`` emits ``perf_report.json`` at close.
     """
 
-    def __init__(self, cfg: Any = None, role: str = "learner") -> None:
+    def __init__(self, cfg: Any = None, role: str = "learner", log_dir: Optional[str] = None) -> None:
+        global _scope_dir
         perf_cfg = _perf_cfg(cfg)
         self.enabled = perf_enabled(cfg)
+        if self.enabled and log_dir:
+            _scope_dir = os.path.join(str(log_dir), "scopes")
         self.role = role
         self.regress_pct = float(perf_cfg.get("regress_pct", 0.25) or 0.25)
         self.capture_updates = int(perf_cfg.get("capture_updates", 3) or 3)
@@ -673,14 +858,19 @@ class PerfPlane:
         if not registered_cost_models() and not self.watchdog.anomalies:
             return None
         try:
-            os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-            tmp = f"{path}.tmp.{os.getpid()}"
-            with open(tmp, "w") as f:
-                json.dump(self.report(), f, indent=1, sort_keys=True)
-            os.replace(tmp, path)
+            _dump_json(path, self.report(), indent=1, sort_keys=True)
             return path
         except OSError:
             return None
+
+
+def _dump_json(path: str, doc: Any, **kwargs: Any) -> None:
+    """Write ``doc`` to ``path`` whole or not at all (a reader never sees half a file)."""
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    tmp = f"{path}.tmp.{os.getpid()}"
+    with open(tmp, "w") as f:
+        json.dump(doc, f, **kwargs)
+    os.replace(tmp, path)
 
 
 def report_path(log_dir: Optional[str] = None) -> Optional[str]:

@@ -10,9 +10,14 @@ into the existing metric/logger pipeline.
 Design constraints:
 
 * stdlib + numpy only at import time — ``utils.timer`` hooks into this module, and the
-  CLI imports the timer before JAX may touch a backend;
-* a module-level *active* tracer with a ``None`` fast path, so instrumentation left in
-  hot loops costs one global load + ``is None`` check when observability is off;
+  CLI imports the timer before JAX may touch a backend (``jax.profiler`` is imported
+  at the first span, which touches none);
+* ONE begin/end pair (:func:`begin` / :func:`end`) under ``timer``, :func:`span` and
+  :func:`trace_span`: it writes the span into the profiler's trace as a
+  ``jax.profiler.TraceAnnotation`` (recorded by whichever XProf capture is running,
+  on the profiler's own clock; with none running, the annotation's constructor and
+  two calls) and feeds the module-level *active* tracer (``None`` when
+  observability is off: one global load + ``is None`` check);
 * thread-safe — decoupled algorithms run player/trainer phases from worker threads, and
   the Chrome trace keeps per-thread tracks via ``tid``.
 """
@@ -45,36 +50,54 @@ def set_active(tracer: Optional["SpanTracer"]) -> Optional["SpanTracer"]:
     return prev
 
 
-def maybe_begin(name: str) -> None:
-    """Fast-path hook for ``utils.timer``: no-op unless a tracer is active."""
-    if _ACTIVE is not None:
-        _ACTIVE.begin(name)
+_annotation = None  # jax.profiler.TraceAnnotation, bound at the first span
 
 
-def maybe_end(name: str) -> None:
-    if _ACTIVE is not None:
-        _ACTIVE.end(name)
+def begin(name: str, tracer: Optional["SpanTracer"] = None):
+    """Open the span ``name`` on this thread; returns the handle :func:`end` takes.
+
+    The span is a ``TraceAnnotation`` (an event named ``name`` in the host plane of
+    any capture that runs from before its begin to after its end; a span that
+    straddles ``start_trace`` or ``stop_trace`` is dropped by the profiler) and a
+    slice of ``tracer`` (default: the active one, if any)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    annotation = _annotation(name)
+    annotation.__enter__()
+    if tracer is None:
+        tracer = _ACTIVE
+    if tracer is not None:
+        tracer.begin(name)
+    return annotation
+
+
+def end(name: str, annotation, tracer: Optional["SpanTracer"] = None) -> None:
+    if tracer is None:
+        tracer = _ACTIVE
+    if tracer is not None:
+        tracer.end(name)
+    annotation.__exit__(None, None, None)
 
 
 class _SpanContext:
-    """Re-usable context manager handed out by ``SpanTracer.span`` / module ``span``."""
+    """Re-usable context manager handed out by ``TrainingMonitor.span`` / module ``span``."""
 
-    __slots__ = ("_name", "_tracer")
+    __slots__ = ("_name", "_tracer", "_annotation")
 
     def __init__(self, name: str, tracer: Optional["SpanTracer"]):
         self._name = name
         self._tracer = tracer
+        self._annotation = None
 
     def __enter__(self):
-        tracer = self._tracer if self._tracer is not None else _ACTIVE
-        if tracer is not None:
-            tracer.begin(self._name)
+        self._annotation = begin(self._name, self._tracer)
         return self
 
     def __exit__(self, *exc):
-        tracer = self._tracer if self._tracer is not None else _ACTIVE
-        if tracer is not None:
-            tracer.end(self._name)
+        end(self._name, self._annotation, self._tracer)
         return False
 
 
@@ -84,17 +107,15 @@ def span(name: str) -> _SpanContext:
 
 
 def trace_span(name: str) -> Callable:
-    """Decorator form: the wrapped call becomes one span (no-op when tracing is off)."""
+    """Decorator form: the wrapped call becomes one span."""
 
     def deco(fn: Callable) -> Callable:
         def wrapper(*args, **kwargs):
-            if _ACTIVE is None:
-                return fn(*args, **kwargs)
-            _ACTIVE.begin(name)
+            annotation = begin(name)
             try:
                 return fn(*args, **kwargs)
             finally:
-                _ACTIVE.end(name)
+                end(name, annotation)
 
         wrapper.__name__ = getattr(fn, "__name__", "wrapped")
         wrapper.__doc__ = fn.__doc__

@@ -28,6 +28,9 @@ from typing import Any, Callable, List, Sequence
 import jax
 import jax.numpy as jnp
 
+from sheeprl_tpu.obs.perf import scope, scopes_tag
+from sheeprl_tpu.utils.timer import timer
+
 
 def chunk_sizes(n: int, max_chunk: int = 8) -> List[int]:
     """Decompose ``n`` into descending powers of two ≤ ``max_chunk``.
@@ -84,7 +87,8 @@ def make_train_block(step_fn: Callable, target_update_freq: int = 1, count_offse
             return carry, metrics
 
         carry, metrics = jax.lax.scan(step, carry, (stacked, keys, counts))
-        last = jax.tree.map(lambda m: m[-1], metrics)
+        with scopes_tag():  # after the scan, whose trace declares the step's scopes
+            last = jax.tree.map(lambda m: m[-1], metrics)
         return carry, last
 
     return jax.jit(block, static_argnames=())
@@ -198,7 +202,8 @@ class BlockDispatcher:
         for size in chunk_sizes(len(entries), self._max_chunk):
             chunk = tuple(entries[offset : offset + size])
             offset += size
-            carry, metrics = self._block(carry, chunk, self._base_key, start_count)
+            with timer("Time/dispatch_call"):
+                carry, metrics = self._block(carry, chunk, self._base_key, start_count)
             start_count += size
             self._futures.track(metrics, size)
         return carry
@@ -329,12 +334,14 @@ class IndexedBlockDispatcher:
 
             def step(carry, x):
                 e, s, key, count = x
-                batch = gather_fn(mirror, e, s)
+                with scope("replay_gather"):
+                    batch = gather_fn(mirror, e, s)
                 carry, metrics = step_fn(carry, batch, key, (count % freq) == 0)
                 return carry, metrics
 
             carry, metrics = jax.lax.scan(step, carry, (envs, starts, keys, counts))
-            return carry, jax.tree.map(lambda m: m[-1], metrics)
+            with scopes_tag():  # after the scan, whose trace declares the step's scopes
+                return carry, jax.tree.map(lambda m: m[-1], metrics)
 
         self._block = jax.jit(block)
         self._max_chunk = max_chunk
@@ -359,7 +366,8 @@ class IndexedBlockDispatcher:
             offset += size
             if self._globalize is not None:
                 e, s = self._globalize(e, s)
-            carry, metrics = self._block(carry, mirror, e, s, self._base_key, start_count)
+            with timer("Time/dispatch_call"):
+                carry, metrics = self._block(carry, mirror, e, s, self._base_key, start_count)
             start_count += size
             self._futures.track(metrics, size)
         return carry

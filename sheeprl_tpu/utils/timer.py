@@ -3,10 +3,11 @@
 Class-level registry of named accumulating timers usable as context managers; drives the
 ``Time/sps_train`` / ``Time/sps_env_interaction`` throughput metrics.
 
-Every timed block is also a *span*: when a ``sheeprl_tpu.obs`` tracer is active, the
-``with timer(...)`` instrumentation already present in the algorithm loops feeds the
-hierarchical span tracer (Chrome-trace export + latency histograms) for free.  With no
-tracer active the hook is one global load + ``is None`` check.
+Every timed block is also a *span* (``obs/tracer.py``: ``begin`` / ``end``): an
+annotation in the host plane of whichever XProf capture is running, under the
+timer's name and on the profiler's clock, and a slice of the ``sheeprl_tpu.obs``
+tracer when one is active.  With neither, the hook is the annotation's constructor
+and two calls.
 """
 
 from __future__ import annotations
@@ -24,18 +25,20 @@ class timer:
     def __init__(self, name: str):
         self.name = name
         self._start = 0.0
+        self._span = None
 
     def __enter__(self):
         if not timer.disabled:
-            _tracer.maybe_begin(self.name)
+            self._span = _tracer.begin(self.name)
             self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if not timer.disabled:
+        if self._span is not None:
             elapsed = time.perf_counter() - self._start
             timer._registry[self.name] = timer._registry.get(self.name, 0.0) + elapsed
-            _tracer.maybe_end(self.name)
+            _tracer.end(self.name, self._span)
+            self._span = None
         return False
 
     @classmethod

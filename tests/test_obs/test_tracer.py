@@ -73,9 +73,12 @@ def test_decorator_and_percentiles(tracer):
 
 
 def test_threads_get_separate_tracks(tracer):
+    # both workers are alive at once, so the second cannot be handed the first's thread id
+    both_alive = threading.Barrier(2)
+
     def work():
         with span("Time/worker"):
-            time.sleep(0.001)
+            both_alive.wait(timeout=10)
 
     threads = [threading.Thread(target=work) for _ in range(2)]
     for t in threads:

@@ -2,6 +2,7 @@
 
 import pytest
 
+import sheeprl_tpu.algos  # noqa: F401  (fills the registry check_configs reads; no other file is counted on to)
 from sheeprl_tpu.cli import check_configs
 from sheeprl_tpu.config.core import compose
 
