@@ -60,7 +60,8 @@ from sheeprl_tpu.distributions import (
 )
 from sheeprl_tpu.obs import TrainingMonitor, flight_recorder
 from sheeprl_tpu.obs.health import diagnostics, health_enabled, replay_age_metrics
-from sheeprl_tpu.obs.perf import scope
+from sheeprl_tpu.obs.perf import note, scope
+from sheeprl_tpu.ops.scan_wgrad import dense_scan
 from sheeprl_tpu.rollout import PipelinedPlayer, rollout_metrics
 from sheeprl_tpu.utils.env import make_vector_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
@@ -68,6 +69,55 @@ from sheeprl_tpu.utils.metric import MetricAggregator, make_aggregator, record_e
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import Ratio
+
+
+def rssm_unroll(world_model, wm_params, embed, actions, is_first, key):
+    """The RSSM over a ``[T, B]`` batch of embeddings, (shifted) actions and ``is_first``
+    flags: ``(posteriors, recurrent states, posterior logits, prior logits)``.
+
+    The scan's steps apply the RSSM alone, 88 M of XL's kernels to 16 rows each:
+    ``dense_scan`` forms those kernels' gradients once after the backward loop, not in
+    its carry (``ops/scan_wgrad.py``), and what it deferred is noted to the perf plane.
+    unroll: the per-step GRU work is tiny at batch B, so amortising the loop structure
+    over several steps keeps the MXU fed."""
+    T, B = embed.shape[:2]
+    rec_size = world_model.recurrent_state_size
+    rssm_vars = {"params": {"rssm": wm_params["params"]["rssm"]}}
+    if world_model.decoupled_rssm:
+        # DecoupledRSSM (reference agent.py:501-593): q(z|o) has no recurrent
+        # dependency, so the WHOLE posterior batch is one vectorized call and
+        # only the prior chain runs in the scan.
+        k_repr, k_scan = jax.random.split(key)
+        post_logits, post_samples = world_model.apply(
+            wm_params, embed, k_repr, method=WorldModel.representation_from_embed
+        )
+        posts = post_samples.reshape(T, B, -1)
+        prev_posts = jnp.concatenate([jnp.zeros_like(posts[:1]), posts[:-1]], 0)
+
+        def step(rssm_vars, rec, x):
+            prev_post, action, first, k = x
+            rec, _, prior_logits = world_model.apply(
+                rssm_vars, prev_post, rec, action, first, k, method=WorldModel.dynamic
+            )
+            return rec, (rec, prior_logits)
+
+        xs = (prev_posts, actions, is_first, jax.random.split(k_scan, T))
+        _, (recs, prior_logits), deferred = dense_scan(step, rssm_vars, jnp.zeros((B, rec_size)), xs, unroll=8)
+    else:
+
+        def step(rssm_vars, carry, x):
+            post, rec = carry
+            action, emb, first, k = x
+            rec, post, _, post_logits, prior_logits = world_model.apply(
+                rssm_vars, post, rec, action, emb, first, k, method=WorldModel.dynamic
+            )
+            return (post, rec), (rec, post, post_logits, prior_logits)
+
+        init = (jnp.zeros((B, world_model.stochastic_size * world_model.discrete_size)), jnp.zeros((B, rec_size)))
+        xs = (actions, embed, is_first, jax.random.split(key, T))
+        _, (recs, posts, post_logits, prior_logits), deferred = dense_scan(step, rssm_vars, init, xs, unroll=8)
+    note("deferred_wgrad", {"kernels": len(deferred), "parameters": sum(i * o for i, o in deferred.values())})
+    return posts, recs, post_logits, prior_logits
 
 
 def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_shapes):
@@ -106,13 +156,13 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
         batch_actions = jnp.concatenate([jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], 0)
 
         # ------------------------------------------------ world model update
-        decoupled = wm_cfg.get("decoupled_rssm", False)
-
         def wm_loss_fn(wm_params):
             with scope("world_model/encoder"):
                 embed = world_model.apply(wm_params, batch_obs, method=WorldModel.encode)  # [T,B,E]
             with scope("world_model/rssm"):
-                posts, recs, post_logits, prior_logits = rssm_unroll(wm_params, embed)
+                posts, recs, post_logits, prior_logits = rssm_unroll(
+                    world_model, wm_params, embed, batch_actions, is_first, k_wm
+                )
             latents = jnp.concatenate([posts, recs], -1)  # [T,B,L]
             with scope("world_model/heads"):
                 recon = world_model.apply(wm_params, latents, method=WorldModel.decode)
@@ -121,50 +171,6 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys, obs_sha
             with scope("world_model/loss"):
                 rec_loss, metrics = wm_loss(recon, reward_logits, continue_logits, post_logits, prior_logits)
             return rec_loss, (posts, recs, metrics)
-
-        def rssm_unroll(wm_params, embed):
-            if decoupled:
-                # DecoupledRSSM (reference agent.py:501-593): q(z|o) has no recurrent
-                # dependency, so the WHOLE posterior batch is one vectorized call and
-                # only the prior chain runs in the scan.
-                k_repr, k_scan = jax.random.split(k_wm)
-                post_logits, post_samples = world_model.apply(
-                    wm_params, embed, k_repr, method=WorldModel.representation_from_embed
-                )
-                posts = post_samples.reshape(T, B, -1)
-                prev_posts = jnp.concatenate([jnp.zeros_like(posts[:1]), posts[:-1]], 0)
-
-                def step(rec, x):
-                    prev_post, action, first, k = x
-                    rec, _, prior_logits = world_model.apply(
-                        wm_params, prev_post, rec, action, first, k, method=WorldModel.dynamic
-                    )
-                    return rec, (rec, prior_logits)
-
-                keys = jax.random.split(k_scan, T)
-                # unroll: the per-step GRU work is tiny at batch B, so amortising the
-                # loop structure over several steps keeps the MXU fed
-                _, (recs, prior_logits) = jax.lax.scan(
-                    step, jnp.zeros((B, rec_size)), (prev_posts, batch_actions, is_first, keys), unroll=8
-                )
-            else:
-
-                def step(carry, x):
-                    post, rec = carry
-                    action, emb, first, k = x
-                    rec, post, _, post_logits, prior_logits = world_model.apply(
-                        wm_params, post, rec, action, emb, first, k, method=WorldModel.dynamic
-                    )
-                    return (post, rec), (rec, post, post_logits, prior_logits)
-
-                keys = jax.random.split(k_wm, T)
-                init = (jnp.zeros((B, stoch_size)), jnp.zeros((B, rec_size)))
-                # unroll: the per-step GRU work is tiny at batch B, so amortising the
-                # loop structure over several steps keeps the MXU fed
-                _, (recs, posts, post_logits, prior_logits) = jax.lax.scan(
-                    step, init, (batch_actions, embed, is_first, keys), unroll=8
-                )
-            return posts, recs, post_logits, prior_logits
 
         def wm_loss(recon, reward_logits, continue_logits, post_logits, prior_logits):
             obs_lp = 0.0

@@ -69,6 +69,7 @@ __all__ = [
     "analyze_compiled",
     "instrument",
     "mfu_from_flops",
+    "note",
     "peak_flops",
     "peak_hbm_bw",
     "perf_enabled",
@@ -284,6 +285,7 @@ def reset() -> None:
     with _lock:
         _registry.clear()
         _scope_names.clear()
+        _notes.clear()
         _scope_dir = None
 
 
@@ -333,6 +335,8 @@ def register_compiled(name: str, compiled: Any) -> None:
         record_registration_failure(name, ValueError("cost_analysis() reported no flops on this backend"))
         return
     register_cost_model(name, flops, bytes_accessed, **_memory_info(compiled))
+    if _notes:
+        _log.info("perf: %s traced with %s", name, json.dumps(_notes, sort_keys=True))
     if _scope_dir is not None:
         _write_scope_map(name, compiled)
 
@@ -340,6 +344,7 @@ def register_compiled(name: str, compiled: Any) -> None:
 # ---------------------------------------------------------------------- scope maps
 
 _scope_names: set = set()
+_notes: Dict[str, Any] = {}  # what a program's trace said of itself (``note``)
 _scope_dir: Optional[str] = None  # <log_dir>/scopes once a PerfPlane with a log dir exists
 
 # ``  %fusion.12 = bf16[..] fusion(..), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(f)/.."}``
@@ -362,6 +367,13 @@ def scope(name: str):
 
     _scope_names.add(name)
     return jax.named_scope(name)
+
+
+def note(key: str, value: Any) -> None:
+    """Called while a jitted hot path is traced: a static fact about the program
+    (which mechanism its trace engaged, on how much), logged once at the program's
+    registration and written as a top-level key of its scope map."""
+    _notes[key] = value
 
 
 def scopes_tag():
@@ -490,7 +502,7 @@ def _write_scope_map(name: str, compiled: Any) -> None:
             # source without these scopes compiled (its entry has to go before a map can be had)
             _log.info("perf: no scope map for %s: its compiled text holds none of the declared scopes", name)
             return
-        doc = {"program": name, "module": module.group(1) if module else "", "ops": ops, "inherited": inherited}
+        doc = {"program": name, "module": module.group(1) if module else "", "ops": ops, "inherited": inherited, **_notes}
         _dump_json(os.path.join(_scope_dir, f"{name}.json"), doc)
         _log.info("perf: scope map of %s (%d ops) written in %.2fs", name, len(ops), time.perf_counter() - t0)
     except Exception as exc:  # attribution must not kill the hot path it measures

@@ -11,9 +11,11 @@ import pytest
 from sheeprl_tpu.obs import TrainingMonitor, tracer
 from sheeprl_tpu.obs.watchdog import RecompileWarning
 from sheeprl_tpu.utils.logger import TensorBoardLogger
+from sheeprl_tpu.utils.timer import timer
 
 
 def test_disabled_monitor_is_noop(tmp_path):
+    timer.reset()  # the registry is process-wide: a run in an earlier file of this worker may have left a phase in it
     m = TrainingMonitor({"obs": {"enabled": False}}, str(tmp_path))
     assert not m.enabled
     assert tracer.get_active() is None  # no global tracer installed

@@ -224,6 +224,32 @@ def test_instrument_writes_the_map_of_a_scoped_program_only(tmp_path):
     assert perf.registered_cost_models()["toy/step"]["calls"] == 2
 
 
+def test_a_note_made_while_tracing_is_a_top_level_key_of_the_map_and_one_log_line(tmp_path, caplog):
+    """``perf.note`` from inside the traced function: what the program's trace said of
+    itself (which mechanism it engaged, on how much) goes into the scope map beside
+    the keys a reader knows, is logged at the registration, and is gone after a reset."""
+    cfg = {"obs": {"perf": {"enabled": True}}}
+    perf.PerfPlane(cfg, log_dir=str(tmp_path))
+    scoped = _make_scoped()
+
+    def noting(x, w):
+        perf.note("deferred_wgrad", {"kernels": 1, "parameters": int(w.size)})
+        return scoped(x, w)
+
+    x, w = jnp.ones((8, 16)), jnp.ones((16, 16))
+    fn = perf.instrument(cfg, "toy/step", jax.jit(noting))
+    with caplog.at_level("INFO", logger="sheeprl_tpu.obs.perf"):
+        fn(x, w), fn(x, w)
+    doc = json.loads((tmp_path / "scopes" / "toy" / "step.json").read_text())
+    assert doc["deferred_wgrad"] == {"kernels": 1, "parameters": 256} and {"program", "module", "ops", "inherited"} < set(doc)
+    lines = [r.getMessage() for r in caplog.records if "deferred_wgrad" in r.getMessage()]
+    assert len(lines) == 1 and "toy/step" in lines[0] and '"parameters": 256' in lines[0]
+    perf.reset()
+    perf.PerfPlane(cfg, log_dir=str(tmp_path / "again"))
+    perf.register_compiled("toy/step", jax.jit(_make_scoped()).lower(x, w).compile())
+    assert "deferred_wgrad" not in json.loads((tmp_path / "again" / "scopes" / "toy" / "step.json").read_text())
+
+
 def test_hlo_text_forms_the_parser_meets():
     for name in ("rssm", "opt"):
         with perf.scope(name):
