@@ -14,7 +14,16 @@
    spans around the calls into the dispatch/replay layer.
 
 The program itself is the system under test and is not otherwise told that it is
-being measured.
+being measured.  The class satisfies ``adapters/base.py``'s ``Adapter``; the traffic's
+generator is ``envs/pixel_env.py``.
+
+What ``correct`` compares for this family (``compared``): the three trees' losses and
+the batch's mean KL between posterior and prior, by name; and two groups of leaves
+that ``reference.leaf_groups`` names: ``transition`` by its worst leaf (the prior's
+layers, reached by the dynamic KL term only, so a wrong KL weight or a stop-gradient
+on the wrong side shows there and nowhere in a loss) and ``world_model`` pooled (the
+large leaves, whose norms a flipped categorical draw hardly moves and a lower
+precision does, carry it).
 """
 
 from __future__ import annotations
@@ -23,6 +32,9 @@ import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+
+from perfbench import check
+from perfbench.envs import clock, pixel_env
 
 COMPARED_STEPS = 3
 TREES = ("world_model", "actor", "critic")
@@ -60,6 +72,19 @@ def _timed(fn, adapter: "DreamerV3Adapter", label: str):
                 adapter.intervals.append((label, t0, t1))
 
     return wrapper
+
+
+def gather_batch(rows: List[Dict[str, np.ndarray]], envs: np.ndarray, starts: np.ndarray, T: int) -> Dict[str, np.ndarray]:
+    """``[T, B, ...]`` sequences from the environment's own rows: batch element ``b``
+    is rows ``starts[b] .. starts[b]+T-1`` of env ``envs[b]``."""
+    out: Dict[str, list] = {}
+    for e, s in zip(envs.tolist(), starts.tolist()):
+        have = len(rows[e]["rewards"])
+        if s + T > have:
+            raise RuntimeError(f"the program sampled rows {s}..{s + T - 1} of env {e}; the environment kept {have}")
+        for k, v in rows[e].items():
+            out.setdefault(k, []).append(v[s : s + T])
+    return {k: np.stack(v, axis=1) for k, v in out.items()}
 
 
 def _leaf_norms(tree):
@@ -270,6 +295,26 @@ class DreamerV3Adapter:
             "change_norms": np.asarray(recs[COMPARED_STEPS - 1]["change_norms"], np.float64),
         }
 
+    def rows(self) -> List[Dict[str, np.ndarray]]:
+        """Per env, the rows the generator kept, in the layout a replay row has."""
+        return pixel_env.stored_rows(self.S["actions"])
+
+    def compared(self) -> Dict[str, Any]:
+        return {"losses": tuple(LOSS_KEYS), "groups": self.ref.leaf_groups(self.S)}
+
+    def coverage(self, reference: Dict[str, Any]) -> Dict[str, Any]:
+        """The KL of the batch's states against the free nats (under them the KL terms are
+        constants and the transition model gets no gradient), and the gradient floor."""
+        return {
+            "free_nats": self.S["kl_free_nats"],
+            "kl_mean": [step["kl"] for step in reference["loss"]],
+            "kl_min": [step["kl_min"] for step in reference["loss"]],
+            **check.grad_floor_coverage(reference),
+        }
+
+    def facts(self) -> Dict[str, Any]:
+        return {"ring rows": self.ring_rows, "rows kept": sum(len(env.rows) for env in clock.ENVS)}
+
     def reference_readings(self, rows, program: Dict[str, Any], quant: str = "f32", fault: Optional[str] = None):
         """Follow the program's first three steps with the plain reference, on the
         device the run used (the program's state is gone by now): the weights from the
@@ -282,15 +327,13 @@ class DreamerV3Adapter:
         import jax
         import jax.numpy as jnp
 
-        from perfbench import check
-
         ref, S = self.ref, self.S
         T = S["sequence_length"]
         init, step, change = _reference_programs(ref, S, quant)
         state = init(self.seed_array())
         losses, grad_norms = [], None
         for i, rec in enumerate(program["steps"]):
-            batch = check.gather_batch(rows, rec["envs"], rec["starts"], T)
+            batch = gather_batch(rows, rec["envs"], rec["starts"], T)
             batch.pop("truncated", None)
             if fault == "half_batch":
                 half = batch["rewards"].shape[1] // 2

@@ -6,45 +6,24 @@ seed, so the decoder and the reward head have something to fit, and episodes end
 alternately by termination and truncation, so ``is_first`` resets, terminal rows
 and both flags occur in the window.
 
-The environment is also the benchmark's clock and its witness:
-
-* env 0 calls ``HOOK(env)`` at the start of every ``step()``; the harness's
-  controller stamps ``time.perf_counter()`` there, decides when the window opens
-  and closes, and ends the run by raising from it.  Nothing is read from inside
-  the program to time an iteration.
-* while ``LOG_ROWS`` is true every env keeps the rows a DreamerV3 replay has to
-  hold for it (observation, arrival reward and flags, the action then taken; a
-  terminal row with a zero action at an episode's end), in its own memory.  The
-  plain reference gathers its batches from these rows at the indices the program
-  drew, so "what is sampled is what was stored" is part of what ``correct`` checks.
+The environment is also the benchmark's clock and its witness (``envs/clock.py`` says
+what a generator owes the clock; this one pays it in ``__init__`` and ``step``):
+while ``clock.LOG_ROWS`` is true every env keeps the rows a DreamerV3 replay has to
+hold for it (observation, arrival reward and flags, the action then taken; a
+terminal row with a zero action at an episode's end), in its own memory.  The
+plain reference gathers its batches from these rows at the indices the program
+drew, so "what is sampled is what was stored" is part of what ``correct`` checks.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import gymnasium as gym
 import numpy as np
 
-#: every live env of this process, in construction order (SyncVectorEnv: index = env id)
-ENVS: List["PixelEnv"] = []
-#: set by the harness; called by env 0 at the start of each step()
-HOOK: Optional[Callable[["PixelEnv"], None]] = None
-#: rows are kept while this is true (the harness clears it after the compared steps)
-LOG_ROWS = True
-#: while true (a traced span) every step() leaves ("env_step", t0, t1) here, on perf_counter's clock
-KEEP_INTERVALS = False
-INTERVALS: List[Tuple[str, float, float]] = []
-
-
-def reset_registry() -> None:
-    global HOOK, LOG_ROWS, KEEP_INTERVALS
-    ENVS.clear()
-    INTERVALS.clear()
-    HOOK = None
-    LOG_ROWS = True
-    KEEP_INTERVALS = False
+from perfbench.envs import clock
 
 
 class PixelEnv(gym.Env):
@@ -80,7 +59,7 @@ class PixelEnv(gym.Env):
         self.rows: List[Dict[str, object]] = []
         self.steps = 0
         self.seconds = 0.0  # this env's own cost, summed over its steps
-        ENVS.append(self)
+        clock.ENVS.append(self)
 
     # -- generation ---------------------------------------------------------
     def _frame(self) -> np.ndarray:
@@ -93,7 +72,7 @@ class PixelEnv(gym.Env):
         return np.repeat(np.repeat(small, rep, axis=1), rep, axis=2)
 
     def _keep(self, row: Dict[str, object]) -> None:
-        if LOG_ROWS:
+        if clock.LOG_ROWS:
             self.rows.append(row)
 
     # -- gym API ------------------------------------------------------------
@@ -107,8 +86,8 @@ class PixelEnv(gym.Env):
         return {"rgb": frame}, {}
 
     def step(self, action):
-        if self.rank == 0 and HOOK is not None:
-            HOOK(self)
+        if self.rank == 0 and clock.HOOK is not None:
+            clock.HOOK(self)
         t0 = time.perf_counter()
         self.steps += 1
         action = int(action)
@@ -137,8 +116,8 @@ class PixelEnv(gym.Env):
         self._pending = {"rgb": frame, "reward": reward, "terminated": 0.0, "truncated": 0.0, "is_first": 0.0}
         t1 = time.perf_counter()
         self.seconds += t1 - t0
-        if KEEP_INTERVALS:
-            INTERVALS.append(("env_step", t0, t1))
+        if clock.KEEP_INTERVALS:
+            clock.INTERVALS.append(("env_step", t0, t1))
         return {"rgb": frame}, reward, terminated, truncated, {}
 
     def render(self):
@@ -153,7 +132,7 @@ def stored_rows(n_actions: int) -> List[Dict[str, np.ndarray]]:
     replay row has: ``rgb`` uint8 [3,H,W], ``reward`` (the observation key) [1],
     ``actions`` one-hot [A], ``rewards``/``terminated``/``truncated``/``is_first`` [1]."""
     out = []
-    for env in ENVS:
+    for env in clock.ENVS:
         n = len(env.rows)
         acts = np.zeros((n, n_actions), np.float32)
         for i, r in enumerate(env.rows):

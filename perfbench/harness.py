@@ -4,15 +4,19 @@ Everything that belongs to one configuration, one traffic mix or one metric live
 a file of its own that this module finds by the name in ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<traffic>.json``, ``workloads/<cell>.json``,
 ``metrics/<metric>.json`` (+ the reader module the metric's file names).  Adding a
-cell, a configuration or a metric needs no edit here.
+cell, a configuration or a metric needs no edit here, and neither does a cell of
+another algorithm family: what belongs to a family (which overrides a traffic mix
+becomes, which environment generates it, what the first three steps are compared on)
+sits behind the configuration's ``adapter``, ``reference``, ``traffic_overrides`` and
+generator; ``adapters/base.py`` says what this module calls.
 
-A run: compose the cell's overrides, put the benchmark's environment, weights and
-recorder in place (``adapters/``), call the program's normal entry
-``sheeprl_tpu.cli.run`` in this process, and let the loop prefill, compile and warm
-up.  The environment's clock (``envs/pixel_env.py``) opens the window once warm-up is
-over and closes it ``--seconds`` later, each time after draining the device; the run
-ends there.  Then: peak memory, the trace's reduction (``--trace 1``), the plain
-reference's three steps and the comparison that decides ``correct``.
+A run: compose the cell's overrides, put the family's weights and recorder in place
+(the adapter), call the program's normal entry ``sheeprl_tpu.cli.run`` in this
+process, and let the loop prefill, compile and warm up.  The environment's clock
+(``envs/clock.py``) opens the window once warm-up is over and closes it ``--seconds``
+later, each time after draining the device; the run ends there.  Then: peak memory,
+the trace's reduction (``--trace 1``), the plain reference's three steps and the
+comparison that decides ``correct``.
 """
 
 from __future__ import annotations
@@ -24,8 +28,11 @@ import os
 import shutil
 import sys
 import time
+import types
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence
+
+from perfbench.envs import clock
 
 ROOT = Path(__file__).resolve().parents[1]
 #: everything a run writes (compile cache, logs, traces) goes under here, inside the checkout
@@ -71,9 +78,11 @@ class Cell:
         self.entry = entries[name]
         self.chips = int(self.entry["chips"])
         self.workload = load_json(self.bench / "workloads" / f"{name}.json")
-        self.traffic = load_json(self.bench / "traffic" / f"{self.entry['traffic']}.json")
+        self.traffic_file = self.bench / "traffic" / f"{self.entry['traffic']}.json"
+        self.traffic = load_json(self.traffic_file)
         cfg_entry = {c["name"]: c for c in self.benchmark["configs"]}[self.entry["config"]]
-        self.config = load_json(root / cfg_entry["file"])
+        self.config_file = root / cfg_entry["file"]
+        self.config = load_json(self.config_file)
 
     def metrics(self, group: str) -> List[Dict[str, Any]]:
         """The cell's metrics of ``end_to_end`` or ``per_layer``, each with its file."""
@@ -96,18 +105,24 @@ class Cell:
         in that cell, PERF.md), or the configuration's rehearsal limits on the CPU."""
         return self.config["rehearsal"]["limits"] if rehearsal else self.workload["limits"]
 
+    def traffic_overrides(self, rehearsal: bool) -> List[str]:
+        """The configuration's ``traffic_overrides`` filled from the traffic file's keys
+        (``{num_envs}``) and from the sizes as run (``{sizes.<key>}``)."""
+        sizes = types.SimpleNamespace(**self.sizes(rehearsal))
+        out = []
+        for template in self.config["traffic_overrides"]:
+            try:
+                out.append(template.format(sizes=sizes, **self.traffic))
+            except (KeyError, AttributeError) as e:
+                raise SystemExit(
+                    f"perfbench: {self.config_file} asks for {template!r} in traffic_overrides; "
+                    f"neither {self.traffic_file} nor the configuration's sizes fill it ({e!r})"
+                )
+        return out
+
     def overrides(self, seed: int, rehearsal: bool, cache_dir: Path, log_root: Path) -> List[str]:
-        t, sizes = self.traffic, self.sizes(rehearsal)
-        out = list(self.config["overrides"])
+        out = list(self.config["overrides"]) + self.traffic_overrides(rehearsal)
         out += [
-            f"env.num_envs={t['num_envs']}",
-            f"algo.replay_ratio={t['replay_ratio']}",
-            "env.wrapper.seed=0",
-            "env.wrapper.rank=0",
-            f"env.wrapper.n_actions={sizes['actions']}",
-            f"env.wrapper.episode_length={t['episode_length']}",
-            f"env.wrapper.reward_scale={t['reward_scale']}",
-            f"env.wrapper.blocks={t['frame_blocks']}",
             f"mesh.devices={self.chips}",
             f"seed={seed}",
             f"compile_cache.dir={cache_dir}",
@@ -198,8 +213,6 @@ class Controller:
         self.trace = {"state": "off" if trace_dir is None else "armed"}
 
     def _snapshot(self, now: float) -> Dict[str, Any]:
-        from perfbench.envs import pixel_env
-
         return {
             "t": now,
             "grad_steps": self.adapter.grad_steps,
@@ -207,17 +220,15 @@ class Controller:
             "compiles": self.compiles.snapshot(),
             "spans": {k: s.snapshot() for k, s in self.adapter.spans.items()},
             "player_s": self.player.total(),
-            "env_s": sum(e.seconds for e in pixel_env.ENVS),
-            "env_steps": sum(e.steps for e in pixel_env.ENVS),
+            "env_s": sum(e.seconds for e in clock.ENVS),
+            "env_steps": sum(e.steps for e in clock.ENVS),
         }
 
     def on_step(self, env) -> None:
-        from perfbench.envs import pixel_env
-
         self.iteration += 1
         self.player.sample()
         if self._stop_logging:
-            pixel_env.LOG_ROWS = False
+            clock.LOG_ROWS = False
         elif self.adapter.captured():
             self._stop_logging = True  # this vector step still commits rows the third batch may hold
         if self.state == "warmup":
@@ -277,8 +288,6 @@ class Controller:
     def _start_capture(self) -> None:
         import jax
 
-        from perfbench.envs import pixel_env
-
         tr = self.trace
         self.adapter.drain()
         options = jax.profiler.ProfileOptions()
@@ -287,13 +296,11 @@ class Controller:
         with jax.profiler.TraceAnnotation(ANCHOR):
             tr["t_started"] = time.perf_counter()
         tr["grad_steps0"] = self.adapter.grad_steps
-        self.adapter.keep_intervals = pixel_env.KEEP_INTERVALS = True
+        self.adapter.keep_intervals = clock.KEEP_INTERVALS = True
         tr["state"] = "on"
 
     def _stop_capture(self) -> None:
         import jax
-
-        from perfbench.envs import pixel_env
 
         tr = self.trace
         self.adapter.drain()
@@ -301,7 +308,7 @@ class Controller:
         tr["t1"] = time.perf_counter()
         jax.profiler.stop_trace()
         tr["stop_s"] = time.perf_counter() - tr["t1"]
-        self.adapter.keep_intervals = pixel_env.KEEP_INTERVALS = False
+        self.adapter.keep_intervals = clock.KEEP_INTERVALS = False
         tr["state"] = "done"
         log(f"captured {tr['t1'] - tr['t_started']:.2f}s, {tr['grad_steps']} gradient steps; stopping the profiler took {tr['stop_s']:.1f}s")
 
@@ -392,16 +399,15 @@ def drive(
         log(f"needs {cell.chips} TPU chip(s); JAX found {len(jax.devices())} x {platform!r}. No result.")
         raise SystemExit(2)
 
-    from perfbench.envs import pixel_env
     from sheeprl_tpu import cli
 
-    pixel_env.reset_registry()
+    clock.reset_registry()
     compiles, collections = CompileCounter(), FullCollections()
     reference = resolve(cell.config["reference"])
     adapter = (adapter_cls or resolve(cell.config["adapter"]))(sizes, seed, reference)
     adapter.install()
     controller = Controller(adapter, compiles, seconds, trace_dir if trace else None, t_process)
-    pixel_env.HOOK = controller.on_step
+    clock.HOOK = controller.on_step
     overrides = cell.overrides(seed, rehearsal, cache_dir, log_root) + list(extra_overrides)
     log(f"{workload} seed {seed}: " + " ".join(overrides))
     try:
@@ -413,12 +419,12 @@ def drive(
         adapter.uninstall()
         compiles.close()
         collections.close()
-        pixel_env.HOOK = None
+        clock.HOOK = None
         if controller.trace["state"] == "on":
             jax.profiler.stop_trace()
 
     program = adapter.program_readings()
-    rows = pixel_env.stored_rows(sizes["actions"])
+    rows = adapter.rows()
     gc.collect()
     device = device_report(cell.chips)
     window = _window(controller, t_process)
@@ -430,7 +436,6 @@ def drive(
         "window": window,
         "device": device,
         "peaks": load_json(cell.bench / "peaks.json"),
-        "ring_rows": adapter.ring_rows,
         "trace": None,
         "adapter": adapter,
         "program": program,
@@ -444,9 +449,10 @@ def drive(
         f"(misses {window['cache_misses']}); the three longest iterations "
         f"{[round(1e3 * g, 1) for g in sorted(window['gaps_s'])[-3:]]} ms, Python's full collections in the window "
         f"{window['full_collections_ms']} ms; the env's own step cost {window['env_step_ms']:.4f} ms; "
-        f"cache at window open {window['compiles_at_open']}; ring rows {adapter.ring_rows}, of which written "
-        f"{window['rows_written_at_open']} at window open and {window['rows_written_at_close']} at its close (a row a policy step); "
-        f"rows kept {sum(len(r['rewards']) for r in rows)}; memory peak {device['memory_peak_bytes'] / 2**30:.3f} GiB"
+        f"cache at window open {window['compiles_at_open']}; rows written {window['rows_written_at_open']} at window open and "
+        f"{window['rows_written_at_close']} at its close (a row a policy step); "
+        + "".join(f"{k} {v}; " for k, v in adapter.facts().items())
+        + f"memory peak {device['memory_peak_bytes'] / 2**30:.3f} GiB"
     )
     log(
         f"one gradient step by shapes ({cell.config['flops']}): {resolve(cell.config['flops'])(sizes)['total']:.4e} flops; "
@@ -456,7 +462,7 @@ def drive(
         from perfbench.readers import xplane
 
         try:
-            run["trace"] = xplane.summarize(trace_dir, controller.trace, adapter.intervals + pixel_env.INTERVALS)
+            run["trace"] = xplane.summarize(trace_dir, controller.trace, adapter.intervals + clock.INTERVALS)
         except xplane.NoDevicePlane:
             if not rehearsal:
                 raise
@@ -474,17 +480,17 @@ def judge(run: Dict[str, Any]) -> Dict[str, Any]:
     from perfbench import check
 
     t0 = time.perf_counter()
-    program = run["program"]
-    ref_out = run["adapter"].reference_readings(run["rows"], program)
+    adapter, program = run["adapter"], run["program"]
+    ref_out = adapter.reference_readings(run["rows"], program)
     prog_out = {
         "loss": [s["loss"] for s in program["steps"]],
         "grad_norms": program["grad_norms"],
         "change_norms": program["change_norms"],
     }
-    numbers = check.compare(prog_out, ref_out, run["adapter"].ref.leaf_groups(run["sizes"]))
+    numbers = check.compare(prog_out, ref_out, **adapter.compared())
     judged = check.verdict(numbers, run["cell"].limits(run["rehearsal"]))
     log(f"reference followed three steps in {time.perf_counter() - t0:.1f}s; all numbers: {json.dumps(numbers)}")
-    log(f"what the three steps exercised: {json.dumps(check.coverage(ref_out, run['sizes']['kl_free_nats']))}")
+    log(f"what the three steps exercised: {json.dumps(adapter.coverage(ref_out))}")
     for i, (p, r) in enumerate(zip(prog_out["loss"], ref_out["loss"])):
         log(f"step {i + 1} loss: program {json.dumps(p, sort_keys=True)} reference {json.dumps(r, sort_keys=True)}")
     judged["numbers"] = numbers

@@ -54,7 +54,7 @@ def main(argv=None) -> int:
     entry = {"seed": args.seed, "program": {"correct": result["correct"], "compared": result["compared"]}}
     for name, how in VARIANTS.items():
         got = run["adapter"].reference_readings(run["rows"], run["program"], **how)
-        numbers = check.compare(got, reference, run["adapter"].ref.leaf_groups(run["sizes"]))
+        numbers = check.compare(got, reference, **run["adapter"].compared())
         judged = check.verdict(numbers, limits)
         entry[name] = {"correct": judged["correct"], "numbers": numbers, "readings": harness.plain(got)}
         print(f"readings: {args.workload} seed {args.seed} {name} correct={judged['correct']} " + json.dumps(numbers), flush=True)

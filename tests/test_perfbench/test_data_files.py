@@ -12,7 +12,7 @@ BENCH = ROOT / "perfbench"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-LIMITED = ("loss_gap.world_model", "loss_gap.actor", "loss_gap.critic", "loss_gap.kl", "grad_gap", "grad_gap.median", "grad_gap.transition", "grad_gap.world_model", "change_gap")
+DV3_LIMITED = {"loss_gap.world_model", "loss_gap.actor", "loss_gap.critic", "loss_gap.kl", "grad_gap", "grad_gap.median", "grad_gap.transition", "grad_gap.world_model", "change_gap"}
 
 
 def load(path):
@@ -27,6 +27,18 @@ CELLS = {w["name"]: w for w in BENCHMARK["workloads"]}
 
 def files(sub):
     return sorted((BENCH / sub).glob("*.json"))
+
+
+def compared_names(config):
+    """The numbers ``check.compare`` makes for a configuration, by what its family's adapter says is compared."""
+    from perfbench import harness
+
+    what = harness.resolve(config["adapter"])(config["sizes"], 0, harness.resolve(config["reference"])).compared()
+    names = {f"loss_gap.{name}" for name in what["losses"]} | {f"grad_gap.{group}" for group in what["groups"]}
+    return names | {"grad_gap", "grad_gap.median", "change_gap"}
+
+
+DV3_CONFIGS = [c for c in BENCHMARK["configs"] if load(ROOT / c["file"])["adapter"].endswith(":DreamerV3Adapter")]
 
 
 def test_benchmark_has_exactly_the_contract_keys():
@@ -81,7 +93,8 @@ def test_workload_file(path):
     if w["name"] in CELLS:
         assert CELLS[w["name"]] == {k: w[k] for k in ("name", "config", "traffic", "chips", "why")}
     # every limit is of a number the comparison makes, and the state-unchanged fault (a gap of 1) fails
-    assert w["limits"] and set(w["limits"]) <= set(LIMITED) and w["limits"]["change_gap"] < 1.0
+    names = compared_names(load(BENCH / "configs" / f"{w['config']}.json"))
+    assert w["limits"] and set(w["limits"]) <= names and w["limits"]["change_gap"] < 1.0
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
@@ -94,17 +107,30 @@ def test_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell):
 
 @pytest.mark.parametrize("path", files("traffic"), ids=lambda p: p.stem)
 def test_traffic_file(path):
+    """A traffic mix is data: it says why and from where, and fills every placeholder of
+    the ``traffic_overrides`` of each configuration it is paired with in a cell."""
+    from perfbench import harness
+
     t = load(path)
-    assert NAME.match(path.stem)
-    for key in ("num_envs", "replay_ratio", "episode_length", "reward_scale", "frame_blocks", "why", "source"):
-        assert key in t
-    assert t["num_envs"] >= 1 and 0 < t["replay_ratio"] <= 1 and 64 % t["frame_blocks"] == 0
+    assert NAME.match(path.stem) and t["why"] and t["source"] and t["num_envs"] >= 1
+    cells = [w for w in CELLS.values() if w["traffic"] == path.stem]
+    assert cells, "a traffic mix that no cell uses"
+    for w in cells:
+        composed = harness.Cell(w["name"]).traffic_overrides(rehearsal=False)
+        assert f"env.num_envs={t['num_envs']}" in composed and not [o for o in composed if "{" in o]
+    if "replay_ratio" in t:  # a replay family's mix
+        assert 0 < t["replay_ratio"] <= 1
+    if "frame_blocks" in t:  # the pixel generator's
+        assert 64 % t["frame_blocks"] == 0
 
 
 @pytest.mark.parametrize("entry", BENCHMARK["configs"], ids=lambda c: c["name"])
-def test_config_file_states_what_the_program_runs(entry):
-    """The sizes the reference reads are the sizes the composed program config holds."""
-    from sheeprl_tpu.config.core import compose
+def test_config_file_names_what_the_harness_asks_of_a_family(entry):
+    """Of any family: the entry agrees with the file, and the file names an adapter with
+    every member of ``adapters/base.py``'s protocol, a reference, a flops count, the
+    overrides a traffic mix becomes, and rehearsal limits of numbers the comparison makes."""
+    from perfbench import harness
+    from perfbench.adapters.base import Adapter
 
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert any(entry["file"].startswith(p + "/") for p in BENCHMARK["paths"])
@@ -113,9 +139,22 @@ def test_config_file_states_what_the_program_runs(entry):
     for key in entry["reduced"]:
         assert NAME.match(key) and key in c["reduced_why"]
         assert not re.search(r"(_dim$|_rank$|_size$|hidden|units|multiplier)", key), "a width may not be reduced"
-    importlib.import_module(c["reference"])
-    module, _, attr = c["adapter"].partition(":")
-    assert hasattr(importlib.import_module(module), attr)
+    adapter = harness.resolve(c["adapter"])(c["sizes"], 0, harness.resolve(c["reference"]))
+    members = [m for m in vars(Adapter) if not m.startswith("_")] + list(Adapter.__annotations__)
+    assert [m for m in members if not hasattr(adapter, m)] == []
+    assert harness.resolve(c["flops"])(c["sizes"])["total"] > 0
+    assert c["traffic_overrides"] and "env.num_envs={num_envs}" in c["traffic_overrides"]
+    assert any(o.startswith("env.wrapper._target_=") for o in c["overrides"])
+    assert c["rehearsal"]["limits"] and set(c["rehearsal"]["limits"]) <= compared_names(c)
+
+
+@pytest.mark.parametrize("entry", DV3_CONFIGS, ids=lambda c: c["name"])
+def test_dreamer_v3_config_file_states_what_the_program_runs(entry):
+    """The sizes the reference reads are the sizes the composed program config holds."""
+    from sheeprl_tpu.config.core import compose
+
+    c = load(ROOT / entry["file"])
+    assert compared_names(c) == DV3_LIMITED
     cfg = compose(overrides=c["overrides"] + ["env.num_envs=1", "seed=1"])
     S, wm = c["sizes"], cfg.algo.world_model
     assert S["recurrent_state_size"] == wm.recurrent_model.recurrent_state_size
@@ -139,7 +178,7 @@ def test_config_file_states_what_the_program_runs(entry):
         assert (o["lr"], o["eps"], o["clip"]) == (node.optimizer.lr, node.optimizer.eps, node.clip_gradients)
     assert S["precision"] == cfg.mesh.precision and S["ring_rows"] == cfg.buffer.size
     assert S["learning_starts"] == cfg.algo.learning_starts and cfg.buffer.device is True
-    assert set(c["rehearsal"]["limits"]) == set(LIMITED)
+    assert set(c["rehearsal"]["limits"]) == DV3_LIMITED
 
 
 def test_peaks_table_names_its_source():

@@ -16,25 +16,6 @@ NO_CACHE = []  # the runs share a persistent compile cache in a temporary direct
 
 
 @pytest.fixture(scope="module")
-def out_dir(tmp_path_factory):
-    """The harness writes under a temporary directory, and JAX's cache settings, which
-    the program's entry changes for the whole process, are put back afterwards."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    from perfbench import harness
-
-    names = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs", "jax_persistent_cache_min_entry_size_bytes")
-    saved = {n: getattr(jax.config, n) for n in names}
-    old, harness.OUT = harness.OUT, tmp_path_factory.mktemp("perfbench_out")
-    yield harness.OUT
-    harness.OUT = old
-    for n, v in saved.items():
-        jax.config.update(n, v)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
 def sound(out_dir):
     from perfbench import harness
 
@@ -94,10 +75,10 @@ def test_control_in_lower_precision_is_not_correct(sound):
     adapter, program, rows = sound["adapter"], sound["program"], sound["rows"]
     reference = adapter.reference_readings(rows, program)
     control = adapter.reference_readings(rows, program, quant="bf16")
-    limits, groups = sound["cell"].limits(True), adapter.ref.leaf_groups(sound["sizes"])
-    assert not check.verdict(check.compare(control, reference, groups), limits)["correct"]
+    limits, what = sound["cell"].limits(True), adapter.compared()
+    assert not check.verdict(check.compare(control, reference, **what), limits)["correct"]
     again = adapter.reference_readings(rows, program)
-    assert check.verdict(check.compare(again, reference, groups), limits)["correct"]
+    assert check.verdict(check.compare(again, reference, **what), limits)["correct"]
 
 
 def faulty_adapter(fault):
@@ -131,7 +112,7 @@ def test_swapped_kl_weights_are_not_correct(sound):
     S = sound["sizes"]
     swapped = DreamerV3Adapter({**S, "kl_dynamic": S["kl_representation"], "kl_representation": S["kl_dynamic"]}, sound["seed"], sound["adapter"].ref)
     reference = sound["adapter"].reference_readings(sound["rows"], sound["program"])
-    numbers = check.compare(swapped.reference_readings(sound["rows"], sound["program"]), reference, swapped.ref.leaf_groups(S))
+    numbers = check.compare(swapped.reference_readings(sound["rows"], sound["program"]), reference, **swapped.compared())
     assert numbers["loss_gap.world_model"] < 1e-6
     assert numbers["grad_gap.transition"] == pytest.approx(0.8, abs=1e-3)
     assert not check.verdict(numbers, sound["cell"].limits(True))["correct"]
@@ -152,9 +133,9 @@ def test_planted_fault_in_the_timed_path_is_not_correct(out_dir, fault):
 def test_the_compared_steps_exercise_the_kl_above_the_free_nats(sound):
     """The benchmark's weights put every state's KL above the free nats, so both KL terms
     carry gradient and no leaf falls under the gradient floor."""
-    from perfbench import check, harness
+    from perfbench import harness
 
-    seen = check.coverage(harness.judge(sound)["reference"], sound["sizes"]["kl_free_nats"])
+    seen = sound["adapter"].coverage(harness.judge(sound)["reference"])
     assert min(seen["kl_min"]) > seen["free_nats"]
     assert seen["leaves_under_grad_floor"] == 0 and seen["leaves"] > 100
 
