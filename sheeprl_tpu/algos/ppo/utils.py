@@ -65,6 +65,26 @@ def log_prob_and_entropy(actor_out: Sequence[jax.Array], actions: jax.Array, is_
     return logprob, entropy
 
 
+def chunked_log_prob_and_entropy(hidden: jax.Array, head: jax.Array, actions: jax.Array, chunk: int, dtype: Any):
+    """Log-probability of ``actions`` ``[N]`` and entropy under ``Categorical(hidden @ head)``
+    for ``hidden`` ``[N, D]`` and a wide ``head`` ``[D, V]``, with the logits formed
+    ``chunk`` tokens at a time and formed again in the backward pass: a float32 copy of
+    ``[N, V]`` is never held (0.62 GB at 4,096 tokens over 37,984 classes).  The same
+    arithmetic as ``log_prob_and_entropy`` over the whole logits."""
+    n, d = hidden.shape
+    if n % chunk:
+        raise ValueError(f"{n} tokens do not divide into chunks of {chunk}")
+
+    @jax.checkpoint
+    def one(h, a):
+        logits = jnp.dot(h.astype(dtype), head.astype(dtype), preferred_element_type=jnp.float32)
+        dist = Categorical(logits)
+        return dist.log_prob(a), dist.entropy()
+
+    logprob, entropy = jax.lax.map(lambda t: one(*t), (hidden.reshape(n // chunk, chunk, d), actions.reshape(n // chunk, chunk)))
+    return logprob.reshape(n), entropy.reshape(n)
+
+
 def test(agent, params, ctx, cfg, log_dir: str, greedy: bool = True) -> float:
     """Greedy single-env evaluation episode (reference ``utils.py:test``)."""
     from sheeprl_tpu.utils.env import make_env

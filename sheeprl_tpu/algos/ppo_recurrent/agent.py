@@ -20,8 +20,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.algos.ppo.agent import parse_action_space
+from sheeprl_tpu.algos.ppo.utils import chunked_log_prob_and_entropy, log_prob_and_entropy
+from sheeprl_tpu.models import decoder
 from sheeprl_tpu.models.blocks import MLP, MultiEncoder
+from sheeprl_tpu.obs.perf import scope
 from sheeprl_tpu.ops.ring_attention import reference_attention
+
+
+#: tokens whose logits the decoder's update forms at a time: 512 x 37,984 float32 logits are
+#: 78 MB where the whole minibatch's would be 0.62 GB
+HEAD_CHUNK = 512
 
 
 class RecurrentPPOAgent(nn.Module):
@@ -216,11 +224,57 @@ class RecurrentPPOAgent(nn.Module):
         return actor_out, values
 
 
-def make_zero_state(cfg):
-    """Per-env zero carry matching ``algo.sequence_model``: LSTM ``(c, h)`` or the
-    attention variant's ``(window, valid)`` rolling context."""
+class DecoderPPOAgent(decoder.DecoderPolicy):
+    """``sequence_model="decoder"``: the sparse-expert decoder of ``models/decoder.py`` as
+    the policy.  Observations and actions are token ids of one vocabulary (an embedding
+    lookup in place of the encoder and of the one-hot previous action); the critic is a
+    linear head on the final hidden state."""
+
+    is_continuous = False
+
+    @property
+    def action_dims(self) -> Tuple[int, ...]:
+        return (self.cfg.vocab_held,)
+
+
+def evaluate_sequences(agent, params, batch: Dict[str, jax.Array], obs_keys: Sequence[str], state0: Any):
+    """New log-probabilities, entropies and values ``[T, B]`` of a minibatch of rollouts
+    from their carry ``state0``, and the model's counters (a dict, empty for most)."""
+    if isinstance(agent, DecoderPPOAgent):
+        (key,) = obs_keys
+        ids = lambda x: jnp.swapaxes(x[..., 0], 0, 1).astype(jnp.int32)  # noqa: E731  [T, B, 1] -> [B, T]
+        hidden, values, _, _, aux = agent.apply(
+            params, ids(batch[key]), ids(batch["prev_actions"]), jnp.swapaxes(batch["is_first"][..., 0], 0, 1), state0
+        )
+        B, T, D = hidden.shape
+        with scope("policy/head"):
+            logprob, entropy = chunked_log_prob_and_entropy(
+                hidden.reshape(B * T, D),
+                params["params"]["head"],
+                ids(batch["actions"]).reshape(B * T),
+                min(HEAD_CHUNK, B * T),
+                agent.dtype,
+            )
+        return logprob.reshape(B, T).T, entropy.reshape(B, T).T, values.T, aux
+    actor_out, values = agent.apply(
+        params, {k: batch[k] for k in obs_keys}, batch["prev_actions"], batch["is_first"], state0
+    )
+    logprob, entropy = log_prob_and_entropy(actor_out, batch["actions"], agent.is_continuous)
+    return logprob, entropy, values[..., 0], {}
+
+
+def make_zero_state(cfg, dtype: Any = jnp.float32):
+    """Per-env zero carry matching ``algo.sequence_model``: LSTM ``(c, h)``, the
+    attention variant's ``(window, valid)`` rolling context, or the decoder's tree of
+    per-layer caches (``models/decoder.py``; ``dtype``: what the caches hold)."""
     h = cfg.algo.rnn.lstm.hidden_size
-    if cfg.algo.get("sequence_model", "lstm") == "attention":
+    if cfg.algo.get("sequence_model", "lstm") == "decoder":
+        dcfg = decoder.DecoderConfig.from_cfg(cfg.algo.decoder)
+
+        def zero_state(n: int):
+            return decoder.zero_state(dcfg, n, dtype)
+
+    elif cfg.algo.get("sequence_model", "lstm") == "attention":
         w = int(cfg.algo.attention.window)
 
         def zero_state(n: int):
@@ -235,8 +289,10 @@ def make_zero_state(cfg):
 
 
 def build_agent(ctx, action_space, obs_space, cfg) -> Tuple[RecurrentPPOAgent, Any]:
-    is_continuous, dims = parse_action_space(action_space)
     sequence_model = cfg.algo.get("sequence_model", "lstm")
+    if sequence_model == "decoder":
+        return build_decoder_agent(ctx, action_space, obs_space, cfg)
+    is_continuous, dims = parse_action_space(action_space)
     attention_fn = None
     if sequence_model == "attention" and ctx.mesh.shape.get("sequence", 1) > 1:
         from sheeprl_tpu.ops.ring_attention import make_ring_attention
@@ -276,3 +332,17 @@ def build_agent(ctx, action_space, obs_space, cfg) -> Tuple[RecurrentPPOAgent, A
     )
     params = ctx.replicate(params)
     return agent, params
+
+
+def build_decoder_agent(ctx, action_space, obs_space, cfg) -> Tuple[DecoderPPOAgent, Any]:
+    dcfg = decoder.DecoderConfig.from_cfg(cfg.algo.decoder)
+    keys = list(cfg.algo.cnn_keys.encoder) + list(cfg.algo.mlp_keys.encoder)
+    if len(keys) != 1 or not isinstance(action_space, gymnasium.spaces.Discrete):
+        raise ValueError(f"sequence_model=decoder reads one key of token ids and writes one token; got keys {keys}, actions {action_space}")
+    if int(action_space.n) != dcfg.vocab_held or int(obs_space[keys[0]].high.max()) >= dcfg.vocab_held:
+        raise ValueError(f"the environment's ids ({obs_space[keys[0]]}, {action_space}) are not the {dcfg.vocab_held} rows held of the tables")
+    agent = DecoderPPOAgent(dcfg, ctx.compute_dtype)
+    ids = jnp.zeros((1,), jnp.int32)
+    state0 = decoder.zero_state(dcfg, 1, ctx.compute_dtype)
+    params = agent.init(ctx.rng(), ids, ids, jnp.ones((1, 1)), state0, method=DecoderPPOAgent.step)
+    return agent, ctx.replicate(params)

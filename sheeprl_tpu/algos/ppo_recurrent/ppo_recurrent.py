@@ -1,9 +1,16 @@
 """Recurrent PPO training loop (reference: ``algos/ppo_recurrent/ppo_recurrent.py:120-…``).
 
-Rollout carries the LSTM state per env (reset at episode starts); the update runs BPTT
-over the fixed ``[rollout_steps, num_envs]`` sequences from the stored initial state,
-minibatching over the env/sequence axis — ``update_epochs`` × sequence-minibatches in
-one jitted ``lax.scan`` chain, like the feed-forward PPO."""
+Rollout carries the sequence model's state per env (reset at episode starts); the update
+runs BPTT over the fixed ``[rollout_steps, num_envs]`` sequences from the stored initial
+state, minibatching over the env/sequence axis — ``update_epochs`` × sequence-minibatches
+in one jitted ``lax.scan`` chain, like the feed-forward PPO.
+
+The carry is one tree for every ``algo.sequence_model``: the LSTM's ``(c, h)``, the
+attention variant's ``(window, valid)``, the decoder's per-layer caches
+(``models/decoder.py``).  ``act_fn``, the rollout and ``train_fn`` pass it as one
+argument; the update reads the carry of the rollout's start as a constant.  The
+decoder variant (``exp=ppo_recurrent_decoder``) reads and writes token ids, keeps its
+context across rollouts, and forms the head's loss in token chunks."""
 
 from __future__ import annotations
 
@@ -19,8 +26,15 @@ import optax
 
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
 from sheeprl_tpu.algos.ppo.ppo import make_optimizer
-from sheeprl_tpu.algos.ppo.utils import AGGREGATOR_KEYS, log_prob_and_entropy, prepare_obs, sample_actions
-from sheeprl_tpu.algos.ppo_recurrent.agent import RecurrentPPOAgent, build_agent, make_zero_state
+from sheeprl_tpu.algos.ppo.utils import AGGREGATOR_KEYS, prepare_obs, sample_actions
+from sheeprl_tpu.algos.ppo_recurrent.agent import (
+    DecoderPPOAgent,
+    RecurrentPPOAgent,
+    build_agent,
+    evaluate_sequences,
+    make_zero_state,
+)
+from sheeprl_tpu.models.decoder import cast_matmul_weights
 from sheeprl_tpu.analysis.strict import assert_finite, maybe_inject_nonfinite, strict_guard
 from sheeprl_tpu.checkpoint.manager import CheckpointManager
 from sheeprl_tpu.fault.guard import TrainingGuard
@@ -29,6 +43,7 @@ from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.obs import perf as obs_perf
 from sheeprl_tpu.obs import TrainingMonitor, flight_recorder
 from sheeprl_tpu.obs.health import diagnostics, health_enabled
+from sheeprl_tpu.obs.tracer import span
 from sheeprl_tpu.utils.env import make_vector_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, record_episode_stats
@@ -37,10 +52,14 @@ from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import gae, normalize_tensor, polynomial_decay
 
 
-def _onehot_actions(env_act: np.ndarray, actions_dim, is_continuous: bool) -> np.ndarray:
+def _onehot_actions(env_act: np.ndarray, actions_dim, is_continuous: bool, as_ids: bool = False) -> np.ndarray:
+    """The previous action as the sequence model reads it: continuous values, one-hot
+    columns, or (``as_ids``, the decoder) the ids themselves."""
     if is_continuous:
         return env_act.astype(np.float32)
     n = env_act.shape[0]
+    if as_ids:
+        return env_act.reshape(n, -1).astype(np.int32)
     out = []
     acts = env_act.reshape(n, -1)
     for i, d in enumerate(actions_dim):
@@ -50,15 +69,27 @@ def _onehot_actions(env_act: np.ndarray, actions_dim, is_continuous: bool) -> np
     return np.concatenate(out, -1)
 
 
+def agent_step(agent, p, obs, prev_actions, is_first, state):
+    """One env-side step of either agent: ``(actor_out, value [B, 1], new_state)``."""
+    if isinstance(agent, DecoderPPOAgent):
+        (tokens,) = obs.values()
+        return agent.apply(
+            p, tokens[:, 0].astype(jnp.int32), prev_actions[:, 0], is_first, state, method=DecoderPPOAgent.step
+        )
+    return agent.apply(p, obs, prev_actions, is_first, state, method=RecurrentPPOAgent.step)
+
+
 def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
-    """Optimizer + the jitted BPTT sequence-minibatch update.
+    """Optimizer + the jitted BPTT sequence-minibatch update
+    ``train_fn(params, opt_state, seq_data, state0, key, clip_coef, ent_coef)``; ``state0``
+    is the carry at the rollout's start, a tree with the env axis leading every leaf.
 
     Module-level (rather than a closure in ``main``) so the IR audit
     (``sheeprl_tpu.analysis.ir``) can AOT-lower the exact update the entry point
     jits — the same reason ``make_a2c_train_fn`` moved out for the flight
     recorder."""
     opt = make_optimizer(cfg.algo.optimizer, cfg.algo.max_grad_norm)
-    is_continuous = agent.is_continuous
+    is_decoder = isinstance(agent, DecoderPPOAgent)
     health = health_enabled(cfg)  # trace-time constant (obs/health.py)
     num_envs = cfg.env.num_envs
     num_batches = max(int(cfg.algo.per_rank_num_batches), 1)
@@ -69,23 +100,18 @@ def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
         )
     mb_envs = num_envs // num_batches
 
-    def seq_loss_fn(p, batch, clip_coef, ent_coef):
-        actor_out, values = agent.apply(
-            p,
-            {k: batch[k] for k in obs_keys},
-            batch["prev_actions"],
-            batch["is_first"],
-            (batch["c0"], batch["h0"]),
-        )
-        logprob, entropy = log_prob_and_entropy(actor_out, batch["actions"], is_continuous)
+    def seq_loss_fn(p, batch, state0, clip_coef, ent_coef):
+        logprob, entropy, values, counters = evaluate_sequences(agent, p, batch, obs_keys, state0)
         adv = batch["advantages"]
         if cfg.algo.normalize_advantages:
             adv = normalize_tensor(adv)
         pg = policy_loss(logprob, batch["logprobs"], adv, clip_coef, "mean")
-        vf = value_loss(values[..., 0], batch["values"], batch["returns"], clip_coef, cfg.algo.clip_vloss, "mean")
+        vf = value_loss(values, batch["values"], batch["returns"], clip_coef, cfg.algo.clip_vloss, "mean")
         ent = entropy_loss(entropy, cfg.algo.loss_reduction)
         total = pg + cfg.algo.vf_coef * vf + cfg.algo.ent_coef * ent
-        aux = {"Loss/policy_loss": pg, "Loss/value_loss": vf, "Loss/entropy_loss": -ent}
+        aux = {"Loss/policy_loss": pg, "Loss/value_loss": vf, "Loss/entropy_loss": -ent, **counters}
+        if is_decoder:  # new over old probabilities; 1 before the first step of an update
+            aux["Health/ratio_first_epoch"] = jnp.exp(logprob - batch["logprobs"]).mean()
         if health:
             aux["Health/policy_entropy"] = entropy.mean()
             aux["Health/value_mean"] = values.mean()
@@ -96,33 +122,40 @@ def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
     dp_ok = ctx.data_parallel_size > 1 and mb_envs % ctx.data_parallel_size == 0
     mb_sharding = ctx.sharding(None, "data")
 
-    @jax.jit
-    def train_fn(p, o_state, seq_data, c0, h0, key, clip_coef, ent_coef):
+    def train_fn(p, o_state, seq_data, state0, key, clip_coef, ent_coef):
         def mb_step(carry, env_idx):
             p, o_state = carry
             batch = jax.tree.map(lambda x: x[:, env_idx], seq_data)
             if dp_ok:
                 batch = jax.tree.map(lambda x: jax.lax.with_sharding_constraint(x, mb_sharding), batch)
-            batch["c0"] = c0[env_idx]
-            batch["h0"] = h0[env_idx]
-            (_, aux), grads = jax.value_and_grad(seq_loss_fn, has_aux=True)(p, batch, clip_coef, ent_coef)
-            updates, o_state = opt.update(grads, o_state, p)
-            p = optax.apply_updates(p, updates)
+            mb_state = jax.tree.map(lambda x: x[env_idx], state0)
+            (_, aux), grads = jax.value_and_grad(seq_loss_fn, has_aux=True)(p, batch, mb_state, clip_coef, ent_coef)
+            with obs_perf.scope("policy_optimizer"):
+                updates, o_state = opt.update(grads, o_state, p)
+                p = optax.apply_updates(p, updates)
             if health:  # per-module norms/ratios, averaged by the scans below
-                aux = {**aux, **diagnostics(grads=grads, params=p, updates=updates)}
+                with obs_perf.scope("health"):
+                    aux = {**aux, **diagnostics(grads=grads, params=p, updates=updates)}
             return (p, o_state), aux
 
         def epoch_step(carry, ekey):
             perm = jax.random.permutation(ekey, num_envs).reshape(num_batches, mb_envs)
             carry, auxs = jax.lax.scan(mb_step, carry, perm)
-            return carry, jax.tree.map(jnp.mean, auxs)
+            means = jax.tree.map(jnp.mean, auxs)
+            if is_decoder:  # the minibatch before the epoch's first step, not the mean over them
+                means["Health/ratio_first_epoch"] = auxs["Health/ratio_first_epoch"][0]
+            return carry, means
 
         keys = jax.random.split(key, cfg.algo.update_epochs)
-        (p, o_state), metrics = jax.lax.scan(epoch_step, (p, o_state), keys)
-        metrics = jax.tree.map(jnp.mean, metrics)
+        (p, o_state), per_epoch = jax.lax.scan(epoch_step, (p, o_state), keys)
+        metrics = jax.tree.map(jnp.mean, per_epoch)
+        if is_decoder:
+            metrics["Health/ratio_first_epoch"] = per_epoch["Health/ratio_first_epoch"][0]
         return p, o_state, maybe_inject_nonfinite(cfg, metrics)
 
-    return opt, train_fn
+    # The decoder's parameters and Adam's moments are most of the chip's memory: the
+    # update writes them where they lie.
+    return opt, jax.jit(train_fn, donate_argnums=(0, 1) if is_decoder else ())
 
 
 @register_algorithm(name="ppo_recurrent")
@@ -144,8 +177,9 @@ def main(ctx, cfg) -> None:
     agent, params = build_agent(ctx, act_space, obs_space, cfg)
     is_continuous = agent.is_continuous
     actions_dim = agent.action_dims
-    act_sum = int(sum(actions_dim))
-    hidden = cfg.algo.rnn.lstm.hidden_size
+    is_decoder = isinstance(agent, DecoderPPOAgent)
+    # the previous action as the model reads it: one-hot columns, or the decoder's one id
+    prev_shape, prev_dtype = ((1,), np.int32) if is_decoder else ((int(sum(actions_dim)),), np.float32)
 
     opt, train_fn = make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys)
     opt_state = ctx.replicate(opt.init(params))
@@ -172,13 +206,20 @@ def main(ctx, cfg) -> None:
 
     gamma, gae_lambda = cfg.algo.gamma, cfg.algo.gae_lambda
 
-    @jax.jit
-    def act_fn(p, obs, prev_actions, is_first, state, key):
-        actor_out, value, new_state = agent.apply(
-            p, obs, prev_actions, is_first, state, method=RecurrentPPOAgent.step
-        )
+    def act(p, obs, prev_actions, is_first, state, key):
+        actor_out, value, new_state = agent_step(agent, p, obs, prev_actions, is_first, state)
         env_act, stored_act, logprob = sample_actions(key, actor_out, is_continuous)
         return env_act, logprob, value[..., 0], new_state
+
+    # The carry is written where it lies (donated: the decoder's caches are GBs).  A value
+    # that must leave it as it is (a truncated episode's last observation, the rollout's
+    # bootstrap) comes from a call of its own over all the rows: static shapes, so no count
+    # of truncated envs compiles anew.
+    act_fn = obs_perf.instrument(cfg, "ppo_recurrent/act_fn", jax.jit(act, donate_argnums=(4,)))
+    value_fn = jax.jit(lambda p, obs, prev, first, state: agent_step(agent, p, obs, prev, first, state)[1][..., 0])
+    # the decoder's acting steps read its matmul weights in the compute dtype, cast once an update
+    acting_params = jax.jit(lambda p: cast_matmul_weights(p, ctx.compute_dtype)) if is_decoder else (lambda p: p)
+    not_first = np.zeros((num_envs, 1), np.float32)  # a transfer where it is used: jnp.zeros would compile
 
     gae_fn = jax.jit(lambda r, v, d, nv: gae(r, v, d, nv, rollout_steps, gamma, gae_lambda))
 
@@ -203,10 +244,10 @@ def main(ctx, cfg) -> None:
         last_checkpoint = state.get("last_checkpoint", 0)
 
     obs, _ = envs.reset(seed=cfg.seed + rank)
-    zero_state = make_zero_state(cfg)
+    zero_state = make_zero_state(cfg, ctx.compute_dtype)
     is_attention = cfg.algo.get("sequence_model", "lstm") == "attention"
     lstm_state = zero_state(num_envs)
-    prev_stored = np.zeros((num_envs, act_sum), dtype=np.float32)
+    prev_stored = np.zeros((num_envs, *prev_shape), dtype=prev_dtype)
     is_first_np = np.ones((num_envs, 1), dtype=np.float32)
     step_data: Dict[str, np.ndarray] = {}
 
@@ -217,15 +258,20 @@ def main(ctx, cfg) -> None:
             # attends within the rollout only, so acting resets its window here —
             # the policies stay EXACTLY on-policy.
             lstm_state = zero_state(num_envs)
-        c0, h0 = lstm_state
+        # the carry at the rollout's start, which the update reads: a copy, since the acting
+        # steps overwrite theirs
+        state0 = jax.tree.map(jnp.copy, lstm_state)
+        act_params = acting_params(params)
         env_t0 = time.perf_counter()
         with timer("Time/env_interaction_time"):
             for _ in range(rollout_steps):
                 obs_t = prepare_obs(obs, cnn_keys, mlp_keys)
-                env_act, logprob, value, lstm_state = act_fn(
-                    params, obs_t, jnp.asarray(prev_stored), jnp.asarray(is_first_np), lstm_state, ctx.local_rng()
-                )
-                env_act_np = np.asarray(jax.device_get(env_act))
+                with span("Rollout/act_call"):
+                    env_act, logprob, value, lstm_state = act_fn(
+                        act_params, obs_t, jnp.asarray(prev_stored), jnp.asarray(is_first_np), lstm_state, ctx.local_rng()
+                    )
+                with span("Rollout/action_fetch"):  # one fetch for the three small arrays of the step
+                    env_act_np, logprob_np, value_np = (np.asarray(x) for x in jax.device_get((env_act, logprob, value)))
                 if is_continuous:
                     low, high = act_space.low, act_space.high
                     env_actions = np.clip(env_act_np, low, high) if np.isfinite(low).all() else env_act_np
@@ -238,39 +284,36 @@ def main(ctx, cfg) -> None:
                 reward = np.asarray(reward, dtype=np.float32).reshape(num_envs)
 
                 # Bootstrap truncated episodes with V(final_obs) under the current
-                # recurrent state (reference ppo_recurrent.py:309-335).
+                # recurrent state (reference ppo_recurrent.py:309-335).  Every row goes
+                # through the model (nothing is written) and the truncated rows' values are
+                # kept; the previous action is the one just taken.
                 if truncated.any() and "final_obs" in info:
                     trunc_idx = np.nonzero(truncated)[0]
-                    final_obs = {
-                        k: np.stack([np.asarray(info["final_obs"][i][k]) for i in trunc_idx]) for k in obs_keys
-                    }
-                    sub_state = (lstm_state[0][trunc_idx], lstm_state[1][trunc_idx])
-                    # local_rng: acting-side keys are per-process; drawing from the
-                    # process-identical chain here would desynchronize it across
-                    # ranks (truncations happen at different iterations per rank).
-                    _, _, v_final, _ = act_fn(
-                        params,
+                    final_obs = {k: np.array(next_obs[k]) for k in obs_keys}
+                    for k in obs_keys:
+                        final_obs[k][trunc_idx] = np.stack([np.asarray(info["final_obs"][i][k]) for i in trunc_idx])
+                    v_final = value_fn(
+                        act_params,
                         prepare_obs(final_obs, cnn_keys, mlp_keys),
-                        jnp.asarray(prev_stored[trunc_idx]),
-                        jnp.zeros((len(trunc_idx), 1)),
-                        sub_state,
-                        ctx.local_rng(),
+                        jnp.asarray(_onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)),
+                        jnp.asarray(not_first),
+                        lstm_state,
                     )
-                    reward[trunc_idx] += gamma * np.asarray(jax.device_get(v_final))
+                    reward[trunc_idx] += gamma * np.asarray(jax.device_get(v_final))[trunc_idx]
 
                 for k in obs_keys:
                     step_data[k] = np.asarray(obs[k])[None]
                 step_data["actions"] = env_act_np.reshape(num_envs, -1).astype(np.float32)[None]
                 step_data["prev_actions"] = prev_stored[None].copy()
                 step_data["is_first"] = is_first_np[None].copy()
-                step_data["logprobs"] = np.asarray(jax.device_get(logprob)).reshape(num_envs, 1)[None]
-                step_data["values"] = np.asarray(jax.device_get(value)).reshape(num_envs, 1)[None]
+                step_data["logprobs"] = logprob_np.reshape(num_envs, 1)[None]
+                step_data["values"] = value_np.reshape(num_envs, 1)[None]
                 step_data["rewards"] = reward.reshape(num_envs, 1)[None]
                 step_data["dones"] = done.astype(np.float32).reshape(num_envs, 1)[None]
                 rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
-                prev_stored = _onehot_actions(env_act_np, actions_dim, is_continuous)
-                prev_stored[done] = 0.0
+                prev_stored = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
+                prev_stored[done] = 0
                 is_first_np = done.astype(np.float32).reshape(num_envs, 1)
                 obs = next_obs
                 policy_step += num_envs * world
@@ -279,9 +322,8 @@ def main(ctx, cfg) -> None:
 
         local = rb.to_tensor()
         obs_t = prepare_obs(obs, cnn_keys, mlp_keys)
-        _, _, next_value, _ = act_fn(
-            params, obs_t, jnp.asarray(prev_stored), jnp.asarray(is_first_np), lstm_state, ctx.local_rng()
-        )
+        next_value = value_fn(act_params, obs_t, jnp.asarray(prev_stored), jnp.asarray(is_first_np), lstm_state)
+        act_params = None  # the decoder's copy goes before the update needs the room
         returns, advantages = gae_fn(local["rewards"], local["values"], local["dones"], next_value[:, None])
         seq_data = {
             **{k: local[k] for k in obs_keys},
@@ -305,14 +347,15 @@ def main(ctx, cfg) -> None:
         if recorder is not None:  # device-array references only: no host sync
             recorder.stage_step(
                 batch=seq_data,
-                carry={"params": params, "opt_state": opt_state, "c0": c0, "h0": h0},
+                # the decoder's update is given its parameters and moments to overwrite: no reference to them survives it
+                carry={} if is_decoder else {"params": params, "opt_state": opt_state, "state0": state0},
                 key=key,
                 scalars={"clip_coef": float(clip_coef), "ent_coef": float(ent_coef), "update": update},
             )
         with timer("Time/train_time"), monitor.phase("dispatch"):
             t0 = time.perf_counter()
             params, opt_state, train_metrics = train_fn(
-                params, opt_state, seq_data, c0, h0, key, clip_coef, ent_coef
+                params, opt_state, seq_data, state0, key, clip_coef, ent_coef
             )
             train_metrics = jax.device_get(train_metrics)
             train_time = time.perf_counter() - t0
@@ -372,24 +415,24 @@ def test(agent, params, ctx, cfg, log_dir: str, greedy: bool = True) -> float:
     env = make_env(cfg, cfg.seed, 0, log_dir, "test")()
     cnn_keys = list(cfg.algo.cnn_keys.encoder)
     mlp_keys = list(cfg.algo.mlp_keys.encoder)
-    act_sum = int(sum(agent.action_dims))
+    is_decoder = isinstance(agent, DecoderPPOAgent)
 
     @jax.jit
     def policy(p, obs, prev_actions, is_first, state, key):
-        actor_out, _, new_state = agent.apply(p, obs, prev_actions, is_first, state, method=RecurrentPPOAgent.step)
+        actor_out, _, new_state = agent_step(agent, p, obs, prev_actions, is_first, state)
         env_act, _, _ = sample_actions(key, actor_out, agent.is_continuous, greedy=greedy)
         return env_act, new_state
 
     obs, _ = env.reset(seed=cfg.seed)
-    state = make_zero_state(cfg)(1)
-    prev = np.zeros((1, act_sum), dtype=np.float32)
+    state = make_zero_state(cfg, ctx.compute_dtype)(1)
+    prev = np.zeros((1, 1), np.int32) if is_decoder else np.zeros((1, int(sum(agent.action_dims))), np.float32)
     is_first = np.ones((1, 1), dtype=np.float32)
     done, cum_reward = False, 0.0
     while not done:
         obs_t = prepare_obs({k: np.asarray(v)[None] for k, v in obs.items()}, cnn_keys, mlp_keys)
         act, state = policy(params, obs_t, jnp.asarray(prev), jnp.asarray(is_first), state, ctx.rng())
         act_np = np.asarray(jax.device_get(act))
-        prev = _onehot_actions(act_np, agent.action_dims, agent.is_continuous)
+        prev = _onehot_actions(act_np, agent.action_dims, agent.is_continuous, as_ids=is_decoder)
         is_first = np.zeros((1, 1), dtype=np.float32)
         if agent.is_continuous:
             env_action = act_np[0]
@@ -407,6 +450,8 @@ def test(agent, params, ctx, cfg, log_dir: str, greedy: bool = True) -> float:
 def lower_for_audit():
     """IR-audit hook (``python -m sheeprl_tpu.analysis.ir``): the jitted BPTT
     update at tiny synthetic shapes, through ``make_ppo_recurrent_train_fn``."""
+    import gymnasium as gym
+
     from sheeprl_tpu.analysis.ir.synth import (
         compose_tiny,
         discrete_act_space,
@@ -450,12 +495,60 @@ def lower_for_audit():
         "returns": zeros((T, N)),
         "advantages": zeros((T, N)),
     }
-    return [
+    entries = [
         AuditEntry(
             name="ppo_recurrent/train_fn",
             fn=train_fn,
-            args=(params, opt_state, seq_data, zeros((N, hidden)), zeros((N, hidden)), jax.random.PRNGKey(0), 0.2, 0.0),
+            args=(params, opt_state, seq_data, (zeros((N, hidden)), zeros((N, hidden))), jax.random.PRNGKey(0), 0.2, 0.0),
             covers=("ppo_recurrent",),
             precision=str(cfg.mesh.precision),
         )
     ]
+
+    # the decoder variant's update: two layers (one of each kind), two of four experts held
+    cfg = compose_tiny(
+        [
+            "exp=ppo_recurrent_decoder",
+            "algo.rollout_steps=4",
+            "algo.update_epochs=1",
+            "algo.decoder.hidden_size=16",
+            "algo.decoder.head_dim=8",
+            "algo.decoder.heads_held=2",
+            "algo.decoder.kv_heads_held=1",
+            "algo.decoder.moe_num_primary_experts=4",
+            "algo.decoder.experts_held=2",
+            "algo.decoder.moe_ffn_hidden_size=8",
+            "algo.decoder.vocab_held=16",
+            "algo.decoder.layers=2",
+            "algo.decoder.sliding_window_size=4",
+            "algo.decoder.cache_capacity=8",
+            "env.num_envs=2",
+        ]
+    )
+    ctx = tiny_ctx(cfg)
+    V = int(cfg.algo.decoder.vocab_held)
+    agent, params = build_agent(
+        ctx, gym.spaces.Discrete(V), gym.spaces.Dict({"token": gym.spaces.Box(0, V - 1, (1,), np.int32)}), cfg
+    )
+    opt, train_fn = make_ppo_recurrent_train_fn(ctx, agent, cfg, ["token"])
+    T, N = int(cfg.algo.rollout_steps), int(cfg.env.num_envs)
+    seq_data = {
+        "token": zeros((T, N, 1)),
+        "actions": zeros((T, N, 1)),
+        "prev_actions": zeros((T, N, 1), jnp.int32),
+        "is_first": zeros((T, N, 1)),
+        "logprobs": zeros((T, N)),
+        "values": zeros((T, N)),
+        "returns": zeros((T, N)),
+        "advantages": zeros((T, N)),
+    }
+    entries.append(
+        AuditEntry(
+            name="ppo_recurrent/train_fn_decoder",
+            fn=train_fn,
+            args=(params, opt.init(params), seq_data, make_zero_state(cfg)(N), jax.random.PRNGKey(0), 0.2, 0.0),
+            covers=("ppo_recurrent",),
+            precision=str(cfg.mesh.precision),
+        )
+    )
+    return entries

@@ -26,17 +26,21 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def _block_mask(q_pos, kv_pos, causal, q_seg=None, kv_seg=None, window=None):
     """[B?, Tq, Tk] boolean mask combining causality, segment equality (episode
-    boundaries) and a sliding attention window; None when nothing masks."""
+    boundaries) and a sliding attention window; None when nothing masks.
+
+    ``q_pos`` / ``kv_pos``: ``[Tq]`` / ``[Tk]`` positions shared by the batch, or
+    ``[B, Tq]`` / ``[B, Tk]`` absolute positions of their own for every row (a
+    decoder's chunk against its carried cache, ``grouped_attention``)."""
     mask = None
     if causal:
-        mask = kv_pos[None, :] <= q_pos[:, None]  # [Tq, Tk]
+        mask = kv_pos[..., None, :] <= q_pos[..., :, None]  # [B?, Tq, Tk]
     if window is not None:
         # A window always excludes the future too ("the LAST `window` positions"),
         # so window-only attention is causal-windowed by construction.
-        delta = q_pos[:, None] - kv_pos[None, :]
+        delta = q_pos[..., :, None] - kv_pos[..., None, :]
         w = (delta >= 0) & (delta < window)
         mask = w if mask is None else (mask & w)
-    if mask is not None:
+    if mask is not None and mask.ndim == 2:
         mask = mask[None]  # broadcast over batch
     if q_seg is not None:
         seg = q_seg[:, :, None] == kv_seg[:, None, :]  # [B, Tq, Tk]
@@ -156,3 +160,36 @@ def reference_attention(
         p = jnp.where(mask[:, None], p, 0.0)  # fully-masked rows attend to nothing
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def grouped_attention(q, blocks, q_pos, q_seg, window=None) -> jax.Array:
+    """Grouped-query attention of a chunk's queries over several blocks of keys (a
+    carried cache, then the chunk's own), masked by absolute position; plain full
+    materialisation, one softmax over all the blocks.
+
+    ``q``: ``[B, Tq, Hq, D]``; ``blocks``: ``(k, v, kv_pos, kv_seg)`` each, ``k, v``:
+    ``[B, Tk, Hkv, D]`` with ``Hq`` a multiple of ``Hkv`` (query head ``h`` reads key
+    head ``h // (Hq // Hkv)``); ``q_pos`` / ``kv_pos``: ``[B, Tq]`` / ``[B, Tk]``
+    positions inside the episode; ``q_seg`` / ``kv_seg``: int segments (a key of
+    another segment, e.g. an empty cache slot given ``-1``, is never visible).  A key
+    is visible iff it is of the query's segment, not after it, and, with ``window``,
+    fewer than ``window`` positions before it.  The blocks are not concatenated (a
+    cache is read where it lies); scores and softmax are float32.  Returns
+    ``[B, Tq, Hq, D]`` in ``q.dtype``; a query that sees no key returns zeros."""
+    B, Tq, Hq, D = q.shape
+    Hkv = blocks[0][0].shape[2]
+    qg = q.reshape(B, Tq, Hkv, Hq // Hkv, D)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    scores, masks = [], []
+    for k, _, kv_pos, kv_seg in blocks:
+        scores.append(jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32) * scale)
+        masks.append(_block_mask(q_pos, kv_pos, True, q_seg, kv_seg, window)[:, None, None])
+    mask = jnp.concatenate(masks, -1)
+    s = jnp.where(mask, jnp.concatenate(scores, -1), jnp.finfo(jnp.float32).min)
+    p = jnp.where(mask, jax.nn.softmax(s, -1), 0.0)
+    out, at = 0.0, 0
+    for _, v, kv_pos, _ in blocks:
+        n = kv_pos.shape[1]
+        out = out + jnp.einsum("bhgqk,bkhd->bqhgd", p[..., at : at + n].astype(v.dtype), v, preferred_element_type=jnp.float32)
+        at += n
+    return out.reshape(B, Tq, Hq, D).astype(q.dtype)
