@@ -66,6 +66,7 @@ from sheeprl_tpu.rollout import PipelinedPlayer, rollout_metrics
 from sheeprl_tpu.utils.env import make_vector_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, make_aggregator, record_episode_stats
+from sheeprl_tpu.utils.packed import pack
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import Ratio
@@ -424,8 +425,12 @@ def main(ctx, cfg) -> None:
     # path too via per-process local rings + a zero-copy global view
     # (data/device_buffer.py: MultiProcessDeviceReplayMirror).
 
+    # The player takes the loop's packed carry (below) and unpacks inside its own jit:
+    # it reads only the rows of the parameters it uses.
     player_step = make_player_step(world_model, actor, actions_dim, cfg.algo.world_model.discrete_size)
-    player_jit = jax.jit(player_step, static_argnames=("greedy",))
+    player_jit = jax.jit(
+        lambda carry, *args, **kwargs: player_step(carry.unpack()[0], *args, **kwargs), static_argnames=("greedy",)
+    )
     stoch_size = cfg.algo.world_model.stochastic_size * cfg.algo.world_model.discrete_size
     rec_size = cfg.algo.world_model.recurrent_model.recurrent_state_size
 
@@ -504,6 +509,13 @@ def main(ctx, cfg) -> None:
             rb.load_state_dict(state["rb"])
             if mirror is not None:
                 mirror.load_from(rb)
+    # From here on the train state is one ``Packed`` (utils/packed.py): the hundreds of
+    # small leaves stacked into one buffer a shape and dtype, the large ones as they are,
+    # so the block's call hands back 150 buffers at XL and not 552 (each costs the host
+    # ~48 us on a v5e).  The block and the player see the tree inside their jits; the
+    # checkpoint on disk keeps the tree's format.
+    carry = pack((params, opt_states, moments_state))
+    del params, opt_states, moments_state
 
     # Pending-row storage (reference ``dreamer_v3.py:538-651``): row t holds obs_t
     # together with the reward/terminated/truncated received when ARRIVING at obs_t
@@ -531,7 +543,7 @@ def main(ctx, cfg) -> None:
         nonlocal player_state
         obs_t = prepare_obs(cur_obs, cnn_keys, mlp_keys, num_envs)
         actions, stored, player_state = player_jit(
-            params, player_state, obs_t, jnp.asarray(is_first_np), ctx.local_rng()
+            carry, player_state, obs_t, jnp.asarray(is_first_np), ctx.local_rng()
         )
         return (stored, list(actions))
 
@@ -606,11 +618,8 @@ def main(ctx, cfg) -> None:
                 )
                 if grad_steps > 0:
                     with monitor.phase("dispatch"):
-                        params, opt_states, moments_state = _run_block(
-                            (params, opt_states, moments_state),
-                            grad_steps,
-                            cumulative_grad_steps,
-                            stage_next=iter_num < num_iters,
+                        carry = _run_block(
+                            carry, grad_steps, cumulative_grad_steps, stage_next=iter_num < num_iters
                         )
                     cumulative_grad_steps += grad_steps
 
@@ -665,6 +674,10 @@ def main(ctx, cfg) -> None:
             # the breakdown).
             def save_ckpt():
                 nonlocal last_checkpoint
+                # the tree on the host, from one fetch of the packed buffers (process 0 writes it)
+                params, opt_states, moments_state = (
+                    jax.device_get(carry).unpack() if ctx.is_global_zero else (None, None, None)
+                )
                 state = {
                     "params": params,
                     "opt_states": opt_states,
@@ -725,7 +738,7 @@ def main(ctx, cfg) -> None:
         if prefetcher is not None:
             prefetcher.close()
     if cfg.algo.run_test and ctx.is_global_zero:
-        reward = test(player_step, params, player_state_init, ctx, cfg, log_dir)
+        reward = test(player_step, carry[0], player_state_init, ctx, cfg, log_dir)
         if logger is not None:
             logger.log_metrics({"Test/cumulative_reward": reward}, policy_step)
     if not cfg.get("model_manager", {}).get("disabled", True) and ctx.is_global_zero:

@@ -40,6 +40,8 @@ import jax
 import numpy as np
 from flax import serialization
 
+from sheeprl_tpu.utils.packed import Packed
+
 PROTECTED_RESUME_KEYS = ("env", "algo", "buffer", "checkpoint", "distribution", "exp_name", "seed")
 
 #: Manifest format written by this version: 2 = per-file sha256 checksums.
@@ -179,6 +181,8 @@ class CheckpointManager:
                 manifest[name] = "per_rank"
             elif _is_device_tree(value):
                 host_value = jax.device_get(value)
+                if isinstance(host_value, Packed):  # written as the tree it packs: the format on disk is the tree's
+                    host_value = host_value.unpack()
                 fname = f"{name}.msgpack"
                 checksums[fname] = _fsync_write(tmp / fname, serialization.to_bytes(host_value))
                 manifest[name] = "msgpack"

@@ -28,7 +28,8 @@ from typing import Any, Callable, List, Sequence
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.obs.perf import scope, scopes_tag
+from sheeprl_tpu.obs.perf import note, scope, scopes_tag
+from sheeprl_tpu.utils.packed import Packed
 from sheeprl_tpu.utils.timer import timer
 
 
@@ -51,6 +52,17 @@ def chunk_sizes(n: int, max_chunk: int = 8) -> List[int]:
     return out
 
 
+def _open(carry):
+    """A block's carry as the tree its steps work on, and what turns the result back into
+    what came in.  A ``Packed`` carry (``utils/packed.py``) crosses the jit boundary as
+    one buffer a shape and is a tree in between; the trace notes how many leaves came in
+    how many buffers.  Any other carry passes as it is: its block is the program it was."""
+    if not isinstance(carry, Packed):
+        return carry, lambda tree: tree
+    note("packed_carry", {"leaves": len(carry.spec.slots), "buffers": len(carry.buffers)})
+    return carry.unpack(), carry.spec.pack
+
+
 def make_train_block(step_fn: Callable, target_update_freq: int = 1, count_offset: int = 1) -> Callable:
     """Wrap a per-step ``step_fn(carry, batch, key, update_target) -> (carry,
     metrics)`` into a jitted ``block(carry, stacked_batch, base_key, start_count)``
@@ -63,11 +75,13 @@ def make_train_block(step_fn: Callable, target_update_freq: int = 1, count_offse
     count is tested AFTER the increment (DV3), with ``0`` before it (DV2's hard copy
     fires on the very first step).  Returns the final carry and the LAST step's
     metrics (what the loops log).  The carry is not donated: the loops keep live
-    references to params/opt-states between calls (checkpointing, acting).
+    references to params/opt-states between calls (checkpointing, acting).  A carry
+    handed in as a ``Packed`` comes back as one (:func:`_open`).
     """
     freq = max(int(target_update_freq), 1)
 
     def block(carry, step_batches, base_key, start_count):
+        carry, close = _open(carry)
         # Stack the per-step batches INSIDE the jit: an eager jnp.stack per leaf
         # would cost one dispatch each — the exact per-call overhead this block
         # exists to remove.
@@ -89,7 +103,7 @@ def make_train_block(step_fn: Callable, target_update_freq: int = 1, count_offse
         carry, metrics = jax.lax.scan(step, carry, (stacked, keys, counts))
         with scopes_tag():  # after the scan, whose trace declares the step's scopes
             last = jax.tree.map(lambda m: m[-1], metrics)
-        return carry, last
+        return close(carry), last
 
     return jax.jit(block, static_argnames=())
 
@@ -328,6 +342,7 @@ class IndexedBlockDispatcher:
         freq = max(int(target_update_freq), 1)
 
         def block(carry, mirror, envs, starts, base_key, start_count):
+            carry, close = _open(carry)
             G = envs.shape[0]
             keys = jax.random.split(jax.random.fold_in(base_key, start_count), G)
             counts = jnp.asarray(start_count, jnp.int32) + count_offset + jnp.arange(G, dtype=jnp.int32)
@@ -341,7 +356,8 @@ class IndexedBlockDispatcher:
 
             carry, metrics = jax.lax.scan(step, carry, (envs, starts, keys, counts))
             with scopes_tag():  # after the scan, whose trace declares the step's scopes
-                return carry, jax.tree.map(lambda m: m[-1], metrics)
+                last = jax.tree.map(lambda m: m[-1], metrics)
+            return close(carry), last
 
         self._block = jax.jit(block)
         self._max_chunk = max_chunk
