@@ -20,7 +20,8 @@ expose pure single-step methods for the scan bodies, like the DV3 agent.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+import functools
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import gymnasium
@@ -574,6 +575,15 @@ def exploration_amount(expl_amount: float, expl_decay: float, expl_min: float, s
     if expl_decay:
         amount *= 0.5 ** (float(step) / expl_decay)
     return max(amount, expl_min)
+
+
+def exploration_schedule(cfg) -> Callable[[int], float]:
+    """``policy_step -> amount`` by ``algo.actor.expl_{amount,decay,min}``: the extra
+    acting argument of the DV1/DV2 players."""
+    expl = cfg.algo.actor
+    return functools.partial(
+        exploration_amount, expl.get("expl_amount", 0.0), expl.get("expl_decay", 0.0), expl.get("expl_min", 0.0)
+    )
 
 
 def add_exploration_noise(

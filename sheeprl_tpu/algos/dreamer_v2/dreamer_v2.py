@@ -18,9 +18,6 @@ specifics it encodes from the reference:
 from __future__ import annotations
 
 import os
-import time
-from pathlib import Path
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -28,37 +25,16 @@ import numpy as np
 import optax
 
 from sheeprl_tpu.analysis.strict import maybe_inject_nonfinite, nan_scan, strict_enabled
-from sheeprl_tpu.algos.dreamer_v2.agent import (
-    PlayerState,
-    WorldModelV2,
-    build_agent,
-    exploration_amount,
-    make_player_step,
-    parse_actions_dim,
-)
+from sheeprl_tpu.algos.dreamer.loop import Entry, run as run_loop, sequential_buffer
+from sheeprl_tpu.algos.dreamer_v2.agent import WorldModelV2, build_agent, exploration_schedule, make_player_step
 from sheeprl_tpu.algos.dreamer_v2.loss import reconstruction_loss
-from sheeprl_tpu.algos.dreamer_v2.utils import (
-    AGGREGATOR_KEYS,
-    compute_lambda_values,
-    prepare_obs,
-    test,
-)
+from sheeprl_tpu.algos.dreamer_v2.utils import AGGREGATOR_KEYS, compute_lambda_values
 from sheeprl_tpu.algos.ppo.ppo import make_optimizer
-from sheeprl_tpu.checkpoint.manager import CheckpointManager
-from sheeprl_tpu.fault.guard import TrainingGuard
-from sheeprl_tpu.config.core import save_config
-from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, EpisodeBuffer, SequentialReplayBuffer
+from sheeprl_tpu.data.buffers import EpisodeBuffer
 from sheeprl_tpu.data.device_buffer import make_device_replay
 from sheeprl_tpu.distributions import BernoulliSafeMode, Independent, Normal, OneHotCategorical
-from sheeprl_tpu.obs import TrainingMonitor
-from sheeprl_tpu.obs.health import diagnostics, health_enabled, replay_age_metrics
-from sheeprl_tpu.rollout import rollout_metrics
-from sheeprl_tpu.utils.env import make_vector_env
-from sheeprl_tpu.utils.logger import get_log_dir, get_logger
-from sheeprl_tpu.utils.metric import MetricAggregator, record_episode_stats
+from sheeprl_tpu.obs.health import diagnostics, health_enabled
 from sheeprl_tpu.utils.registry import register_algorithm
-from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import Ratio
 
 
 def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys):
@@ -274,308 +250,62 @@ def make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys):
 
 def make_buffer(cfg, num_envs, obs_keys, log_dir, rank, world):
     """sequential | episode buffer switch (reference ``dreamer_v2.py:496-517``)."""
-    buffer_size = max(int(cfg.buffer.size) // max(num_envs * world, 1), 1)
     buffer_type = str(cfg.buffer.get("type", "sequential")).lower()
-    memmap_dir = os.path.join(log_dir, "memmap_buffer", f"rank_{rank}") if cfg.buffer.memmap else None
     if buffer_type == "sequential":
-        return EnvIndependentReplayBuffer(
-            buffer_size,
-            n_envs=num_envs,
-            obs_keys=obs_keys,
-            memmap=cfg.buffer.memmap,
-            memmap_dir=memmap_dir,
-            buffer_cls=SequentialReplayBuffer,
-        )
+        return sequential_buffer(cfg, num_envs, obs_keys, log_dir, rank, world)
     if buffer_type == "episode":
         return EpisodeBuffer(
-            buffer_size,
+            max(int(cfg.buffer.size) // max(num_envs * world, 1), 1),
             minimum_episode_length=1 if cfg.dry_run else cfg.algo.per_rank_sequence_length,
             n_envs=num_envs,
             obs_keys=obs_keys,
             prioritize_ends=cfg.buffer.get("prioritize_ends", False),
             memmap=cfg.buffer.memmap,
-            memmap_dir=memmap_dir,
+            memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{rank}") if cfg.buffer.memmap else None,
         )
     raise ValueError(f"Unrecognized buffer type: must be one of `sequential` or `episode`, received: {buffer_type}")
 
 
-@register_algorithm(name="dreamer_v2")
-def main(ctx, cfg) -> None:
-    rank = ctx.process_index
-    log_dir = get_log_dir(cfg)
-    if ctx.is_global_zero:
-        save_config(cfg, Path(log_dir) / "config.yaml")
-    logger = get_logger(cfg, log_dir)
-    monitor = TrainingMonitor(cfg, log_dir)
+def block_step_of(train_step):
+    """``train_step`` as the dispatcher's per-step closure over the carry
+    ``(params, opt_states)`` (``utils/blocks.py``)."""
 
-    envs = make_vector_env(cfg, cfg.seed, rank, log_dir if cfg.env.capture_video else None)
-    obs_space = envs.single_observation_space
-    act_space = envs.single_action_space
-    is_continuous, actions_dim = parse_actions_dim(act_space)
-    act_dim_sum = int(sum(actions_dim))
-    cnn_keys = list(cfg.algo.cnn_keys.encoder)
-    mlp_keys = list(cfg.algo.mlp_keys.encoder)
-    obs_keys = cnn_keys + mlp_keys
-    num_envs = cfg.env.num_envs
-    world = jax.process_count()
-
-    world_model, actor, critic, params, _ = build_agent(ctx, actions_dim, is_continuous, cfg, obs_space)
-    train_step, init_opt_states = make_train_step(world_model, actor, critic, cfg, cnn_keys, mlp_keys)
-    opt_states = ctx.replicate(init_opt_states(params))
-    target_update_freq = cfg.algo.critic.per_rank_target_network_update_freq
-
-    # One jitted scan per iteration's gradient block (utils/blocks.py); DV2's hard
-    # target copy tests the count BEFORE the increment (fires on the first step).
     def _block_step(carry, batch, key, update_target):
         params, opt_states = carry
         params, opt_states, metrics = train_step(params, opt_states, batch, key, update_target)
         return (params, opt_states), metrics
 
+    return _block_step
 
-    player_step = make_player_step(world_model, actor, actions_dim, is_continuous)
-    player_jit = jax.jit(player_step, static_argnames=("greedy",))
-    stoch_size = cfg.algo.world_model.stochastic_size * cfg.algo.world_model.discrete_size
-    rec_size = cfg.algo.world_model.recurrent_model.recurrent_state_size
 
-    def player_state_init(n: int) -> PlayerState:
-        return PlayerState(
-            recurrent_state=jnp.zeros((n, rec_size)),
-            stochastic_state=jnp.zeros((n, stoch_size)),
-            actions=jnp.zeros((n, act_dim_sum)),
+@register_algorithm(name="dreamer_v2")
+def main(ctx, cfg) -> None:
+    def setup(obs_space, is_continuous, actions_dim) -> Entry:
+        world_model, actor, critic, params, _ = build_agent(ctx, actions_dim, is_continuous, cfg, obs_space)
+        train_step, init_opt_states = make_train_step(
+            world_model, actor, critic, cfg, list(cfg.algo.cnn_keys.encoder), list(cfg.algo.mlp_keys.encoder)
+        )
+        return Entry(
+            carry=(params, ctx.replicate(init_opt_states(params))),
+            ckpt_names=("params", "opt_states"),
+            # DV2's hard target copy tests the count BEFORE the increment (fires on the first step)
+            block_step=block_step_of(train_step),
+            dispatcher_kwargs=dict(
+                target_update_freq=cfg.algo.critic.per_rank_target_network_update_freq, count_offset=0
+            ),
+            player_step=make_player_step(world_model, actor, actions_dim, is_continuous),
+            stochastic_size=cfg.algo.world_model.stochastic_size * cfg.algo.world_model.discrete_size,
+            aggregator_keys=AGGREGATOR_KEYS,
+            place=ctx.replicate,
+            exploration_amount=exploration_schedule(cfg),
+            clip_reward=np.tanh,
+            # DV2's episode buffer stays on host
+            make_buffer=make_buffer,
+            # MineDojo's masked actor plays from the first step
+            random_prefill="minedojo" not in str(cfg.env.get("wrapper", {}).get("_target_", "")).lower(),
         )
 
-    rb = make_buffer(cfg, num_envs, obs_keys, log_dir, rank, world)
-    rb.seed(cfg.seed + rank)
-    # Device-vs-host replay data path, one shared implementation
-    # (data/device_buffer.py); DV2's episode buffer stays on host.
-    dispatcher, mirror, prefetcher, _run_block, rb_add = make_device_replay(
-        ctx,
-        cfg,
-        rb,
-        cnn_keys,
-        mlp_keys,
-        obs_space,
-        act_dim_sum,
-        _block_step,
-        dispatcher_kwargs=dict(target_update_freq=target_update_freq, count_offset=0),
-        require_sequential=True,
-    )
-    is_episode_buffer = isinstance(rb, EpisodeBuffer)
-
-    aggregator = MetricAggregator(cfg.metric.aggregator.get("metrics", {}))
-    aggregator.keep(AGGREGATOR_KEYS | set(cfg.metric.aggregator.get("metrics", {})))
-    ckpt_manager = CheckpointManager(Path(log_dir) / "checkpoints", keep_last=cfg.checkpoint.keep_last)
-    guard = TrainingGuard(cfg, log_dir)
-    ratio = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)
-
-    batch_size = cfg.algo.per_rank_batch_size
-    seq_len = cfg.algo.per_rank_sequence_length
-    policy_steps_per_iter = num_envs * world * cfg.env.action_repeat
-    total_steps = int(cfg.algo.total_steps)
-    num_iters = max(total_steps // policy_steps_per_iter, 1) if not cfg.dry_run else 1
-    learning_starts = cfg.algo.learning_starts // policy_steps_per_iter if not cfg.dry_run else 0
-    expl_cfg = cfg.algo.actor
-
-    start_iter = 1
-    policy_step = 0
-    last_log = 0
-    last_checkpoint = 0
-    cumulative_grad_steps = 0
-    if cfg.checkpoint.get("resume_from"):
-        state = CheckpointManager.load(
-            cfg.checkpoint.resume_from,
-            templates={"params": jax.device_get(params), "opt_states": jax.device_get(opt_states)},
-        )
-        params = ctx.replicate(state["params"])
-        opt_states = ctx.replicate(state["opt_states"])
-        ratio.load_state_dict(state["ratio"])
-        start_iter = state["iter_num"] + 1
-        policy_step = state["policy_step"]
-        last_log = state.get("last_log", 0)
-        last_checkpoint = state.get("last_checkpoint", 0)
-        cumulative_grad_steps = state.get("cumulative_grad_steps", 0)
-        learning_starts += start_iter
-        if cfg.buffer.checkpoint and "rb" in state:
-            rb.load_state_dict(state["rb"])
-            if mirror is not None:
-                mirror.load_from(rb)
-
-    def _obs_row(o, idxs=None):
-        row = {}
-        for k in cnn_keys:
-            v = np.asarray(o[k]) if idxs is None else np.asarray(o[k])[idxs]
-            row[k] = v.reshape(1, v.shape[0], -1, *v.shape[-2:])
-        for k in mlp_keys:
-            v = np.asarray(o[k], dtype=np.float32) if idxs is None else np.asarray(o[k], dtype=np.float32)[idxs]
-            row[k] = v.reshape(1, v.shape[0], -1)
-        return row
-
-    obs, _ = envs.reset(seed=cfg.seed + rank)
-    player_state = player_state_init(num_envs)
-    step_data: Dict[str, np.ndarray] = _obs_row(obs)
-    step_data["rewards"] = np.zeros((1, num_envs, 1), np.float32)
-    step_data["terminated"] = np.zeros((1, num_envs, 1), np.float32)
-    step_data["truncated"] = np.zeros((1, num_envs, 1), np.float32)
-    step_data["is_first"] = np.ones((1, num_envs, 1), np.float32)
-    is_first_np = np.ones((num_envs, 1), dtype=np.float32)
-    prefill_iters = max(learning_starts - 1, 0)
-    is_minedojo = "minedojo" in str(cfg.env.get("wrapper", {}).get("_target_", "")).lower()
-
-    for iter_num in range(start_iter, num_iters + 1):
-        monitor.advance()
-        env_t0 = time.perf_counter()
-        expl_amount = exploration_amount(
-            expl_cfg.get("expl_amount", 0.0), expl_cfg.get("expl_decay", 0.0), expl_cfg.get("expl_min", 0.0), policy_step
-        )
-        with timer("Time/env_interaction_time"):
-            if iter_num <= learning_starts and not cfg.checkpoint.get("resume_from") and not is_minedojo:
-                if is_continuous:
-                    stored_actions = np.stack([act_space.sample() for _ in range(num_envs)]).astype(np.float32)
-                    env_actions = stored_actions
-                else:
-                    sampled = np.stack([act_space.sample() for _ in range(num_envs)]).reshape(num_envs, -1)
-                    onehots = []
-                    for i, d in enumerate(actions_dim):
-                        oh = np.zeros((num_envs, d), dtype=np.float32)
-                        oh[np.arange(num_envs), sampled[:, i]] = 1.0
-                        onehots.append(oh)
-                    stored_actions = np.concatenate(onehots, -1)
-                    env_actions = sampled.squeeze(-1) if len(actions_dim) == 1 else sampled
-                player_state = player_state._replace(actions=jnp.asarray(stored_actions))
-            else:
-                obs_t = prepare_obs(obs, cnn_keys, mlp_keys, num_envs)
-                actions, stored, player_state = player_jit(
-                    params, player_state, obs_t, jnp.asarray(is_first_np), ctx.local_rng(), jnp.asarray(expl_amount)
-                )
-                # ONE device_get for everything the host needs (per-array fetches
-                # would each pay their own dispatch and device→host sync).
-                stored_np, acts_list = jax.device_get((stored, list(actions)))
-                stored_actions = np.asarray(stored_np)
-                acts_np = [np.asarray(a) for a in acts_list]
-                if is_continuous:
-                    env_actions = acts_np[0]
-                elif len(actions_dim) == 1:
-                    env_actions = acts_np[0].argmax(-1)
-                else:
-                    env_actions = np.stack([a.argmax(-1) for a in acts_np], -1)
-
-            step_data["actions"] = stored_actions.reshape(1, num_envs, -1)
-            rb_add(step_data, validate_args=cfg.buffer.validate_args)
-        env_time = time.perf_counter() - env_t0
-
-        # Dispatch this iteration's gradient block BEFORE stepping the envs: the
-        # device trains while the host walks the environments below (acting above
-        # used the previous iteration's params, exactly as the eager ordering did).
-        grad_steps = 0
-        if iter_num >= learning_starts:
-            grad_steps = ratio(
-                (policy_step + policy_steps_per_iter - prefill_iters * policy_steps_per_iter) / world
-            )
-            if grad_steps > 0:
-                params, opt_states = _run_block(
-                    (params, opt_states), grad_steps, cumulative_grad_steps, stage_next=iter_num < num_iters
-                )
-                cumulative_grad_steps += grad_steps
-
-        env_t0 = time.perf_counter()
-        with timer("Time/env_interaction_time"):
-            next_obs, reward, terminated, truncated, info = envs.step(env_actions)
-            if cfg.env.clip_rewards:
-                reward = np.tanh(reward)
-            done = np.logical_or(terminated, truncated)
-            reward = np.asarray(reward, dtype=np.float32).reshape(num_envs, 1)
-
-            real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
-            if done.any() and "final_obs" in info:
-                for i in np.nonzero(done)[0]:
-                    if info["final_obs"][i] is not None:
-                        for k in obs_keys:
-                            real_next_obs[k][i] = np.asarray(info["final_obs"][i][k])
-
-            step_data = _obs_row(next_obs)
-            step_data["rewards"] = reward.reshape(1, num_envs, 1).copy()
-            step_data["terminated"] = terminated.astype(np.float32).reshape(1, num_envs, 1)
-            step_data["truncated"] = truncated.astype(np.float32).reshape(1, num_envs, 1)
-            step_data["is_first"] = np.zeros((1, num_envs, 1), np.float32)
-
-            done_idxs = np.nonzero(done)[0].tolist()
-            if done_idxs:
-                reset_data = _obs_row(real_next_obs, idxs=done_idxs)
-                reset_data["rewards"] = step_data["rewards"][:, done_idxs]
-                reset_data["terminated"] = step_data["terminated"][:, done_idxs]
-                reset_data["truncated"] = step_data["truncated"][:, done_idxs]
-                reset_data["actions"] = np.zeros((1, len(done_idxs), act_dim_sum), np.float32)
-                reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-                rb_add(reset_data, indices=done_idxs, validate_args=cfg.buffer.validate_args)
-                step_data["rewards"][:, done_idxs] = 0.0
-                step_data["terminated"][:, done_idxs] = 0.0
-                step_data["truncated"][:, done_idxs] = 0.0
-                step_data["is_first"][:, done_idxs] = 1.0
-
-            is_first_np = done.astype(np.float32).reshape(num_envs, 1)
-            obs = next_obs
-            policy_step += policy_steps_per_iter
-            record_episode_stats(aggregator, info)
-        env_time += time.perf_counter() - env_t0
-
-        if logger is not None and (
-            policy_step - last_log >= cfg.metric.log_every or iter_num == num_iters or cfg.dry_run
-        ):
-            dispatcher.drain(aggregator)  # the window's only blocking device sync
-            metrics = aggregator.compute()
-            window_sps = dispatcher.pop_window_sps()
-            if window_sps is not None:
-                metrics["Time/sps_train"] = window_sps
-            metrics["Time/sps_env_interaction"] = (
-                policy_steps_per_iter / world / env_time if env_time > 0 else 0.0
-            )
-            metrics["Params/replay_ratio"] = (
-                cumulative_grad_steps * world / policy_step if policy_step > 0 else 0.0
-            )
-            metrics["Params/exploration_amount"] = expl_amount
-            metrics.update(replay_age_metrics(rb))
-            metrics.update(rollout_metrics(envs))
-            monitor.log_metrics(logger, metrics, policy_step)
-            aggregator.reset()
-            last_log = policy_step
-
-        def save_ckpt():
-            nonlocal last_checkpoint
-            state = {
-                "params": params,
-                "opt_states": opt_states,
-                "ratio": ratio.state_dict(),
-                "iter_num": iter_num,
-                "policy_step": policy_step,
-                "last_log": last_log,
-                "last_checkpoint": policy_step,
-                "cumulative_grad_steps": cumulative_grad_steps,
-            }
-            if cfg.buffer.checkpoint:
-                state["rb"] = rb.state_dict()
-            path = ckpt_manager.save(policy_step, state)
-            last_checkpoint = policy_step
-            return path
-
-        if (
-            cfg.checkpoint.every > 0
-            and (policy_step - last_checkpoint) >= cfg.checkpoint.every
-            or iter_num == num_iters
-            and cfg.checkpoint.save_last
-        ):
-            save_ckpt()
-        guard.boundary(policy_step, save_ckpt)
-
-    monitor.close()
-    envs.close()
-    if prefetcher is not None:
-        prefetcher.close()
-    if cfg.algo.run_test and ctx.is_global_zero:
-        reward = test(player_step, params, player_state_init, ctx, cfg, log_dir)
-        if logger is not None:
-            logger.log_metrics({"Test/cumulative_reward": reward}, policy_step)
-    if logger is not None:
-        logger.close()
+    run_loop(ctx, cfg, setup, make_device_replay=make_device_replay)
 
 
 def lower_for_audit():
@@ -604,12 +334,7 @@ def lower_for_audit():
     train_step, init_opt_states = make_train_step(world_model, actor, critic, cfg, [], ["state"])
     carry = (params, init_opt_states(params))
 
-    def _block_step(carry, batch, key, update_target):
-        params, opt_states = carry
-        params, opt_states, metrics = train_step(params, opt_states, batch, key, update_target)
-        return (params, opt_states), metrics
-
-    block = make_train_block(_block_step, cfg.algo.critic.per_rank_target_network_update_freq, 0)
+    block = make_train_block(block_step_of(train_step), cfg.algo.critic.per_rank_target_network_update_freq, 0)
     batch = sequence_batch(
         {"state": obs_space["state"].shape},
         act_dim=int(sum(actions_dim)),

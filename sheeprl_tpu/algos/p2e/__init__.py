@@ -143,3 +143,75 @@ def load_exploration_config(cfg) -> Any:
     if cfg.buffer.get("load_from_exploration") and exploration_cfg.buffer.checkpoint:
         cfg.env.num_envs = exploration_cfg.env.num_envs
     return exploration_cfg
+
+
+def actor_view(actor: str) -> Callable[[Any, Any], Any]:
+    """The Dreamer loop's ``player_params`` (``algos/dreamer/loop.py::Entry``) for a player
+    that acts with the world model and the actor named ``actor`` of the carry's parameters."""
+    return lambda tree, aux: {"world_model": tree[0]["world_model"], "actor": tree[0][actor]}
+
+
+def exploring_actor_view(cfg) -> Callable[[Any, Any], Any]:
+    """The player of an exploration run: the actor ``algo.player.actor_type`` names."""
+    exploring = cfg.algo.player.get("actor_type", "exploration") == "exploration"
+    return actor_view("actor_exploration" if exploring else "actor_task")
+
+
+def finetuning_fields(ctx, cfg, place: Callable[[Any], Any], templates: Any, task_view: Any) -> dict:
+    """What the three finetuning entry points hand the Dreamer loop
+    (``algos/dreamer/loop.py::Entry``) alike, whatever the task's train step is.
+
+    The run starts from the exploration run's checkpoint, or from its own when it resumes:
+    ``templates`` are the exploration-shaped device trees by checkpoint name (``params``,
+    ``opt_states`` and, where the train step has them, ``moments``).  The functional
+    parameter split makes the task's train step the plain Dreamer one over a slice:
+    ``task_view`` maps each name of its trees to its name in the Plan2Explore tree, and
+    that slice alone is the carry the block steps.  The player starts on the actor
+    ``algo.player.actor_type`` names and switches to the TASK actor at the first
+    training iteration (reference p2e finetuning ``:350-352``); there is no random
+    prefill, the agent is pretrained.  A checkpoint is written exploration-shaped again, so
+    that both resume and evaluation reload it with the same templates; what was not
+    trained on keeps the values loaded from the exploration checkpoint."""
+    from sheeprl_tpu.checkpoint.manager import CheckpointManager
+
+    resume_from = cfg.checkpoint.get("resume_from")
+    state = CheckpointManager.load(
+        resume_from or cfg.checkpoint.exploration_ckpt_path, templates=jax.device_get(templates)
+    )
+    loaded, loaded_opts = state["params"], state["opt_states"]
+    if not resume_from and not cfg.buffer.get("load_from_exploration"):
+        state.pop("rb", None)  # the exploration run's ring is handed on only where asked for
+    actor_type = cfg.algo.player.get("actor_type", "exploration")
+    if resume_from:
+        actor_type = state.get("actor_type", actor_type)
+    exploring = actor_type == "exploration"
+    carry = [
+        place({k: loaded[name] for k, name in task_view.items()}),
+        place({k: loaded_opts[task_view[k]] for k in ("world_model", "actor", "critic")}),
+    ]
+    if "moments" in templates:
+        carry.append(ctx.replicate(state["moments"]["task"]))
+
+    def to_ckpt(tree, aux, learning):
+        view, opts, *moments = tree
+        entries = {
+            "params": {**loaded, **{name: view[k] for k, name in task_view.items()}},
+            "opt_states": {**loaded_opts, **{task_view[k]: v for k, v in opts.items()}},
+            "actor_type": "task" if learning else actor_type,
+        }
+        if moments:
+            entries["moments"] = {"task": moments[0], "expl": templates["moments"]["expl"]}
+        return entries
+
+    return dict(
+        carry=tuple(carry),
+        aux=place(loaded["actor_exploration"]) if exploring else None,
+        ckpt_names=tuple(templates),
+        to_ckpt=to_ckpt,
+        restored=state,
+        player_params=actor_view("actor"),
+        starting_player_params=(
+            (lambda tree, aux: {"world_model": tree[0]["world_model"], "actor": aux}) if exploring else None
+        ),
+        random_prefill=False,
+    )
