@@ -8,13 +8,18 @@ in one jitted ``lax.scan`` chain, like the feed-forward PPO.
 The carry is one tree for every ``algo.sequence_model``: the LSTM's ``(c, h)``, the
 attention variant's ``(window, valid)``, the decoder's per-layer caches
 (``models/decoder.py``).  ``act_fn``, the rollout and ``train_fn`` pass it as one
-argument; the update reads the carry of the rollout's start as a constant.  The
+argument; the update reads the carry of the rollout's start as a constant.  An acting
+step crosses the host-device boundary once each way: host arrays in (``StepInputs``),
+the sampling key beside the state in ``act_fn``'s donated argument, one buffer of
+per-env results out.  The
 decoder variant (``exp=ppo_recurrent_decoder``) reads and writes token ids, keeps its
 context across rollouts, and forms the head's loss in token chunks."""
 
 from __future__ import annotations
 
+import math
 import os
+import re
 import time
 from pathlib import Path
 from typing import Any, Dict
@@ -77,6 +82,87 @@ def agent_step(agent, p, obs, prev_actions, is_first, state):
             p, tokens[:, 0].astype(jnp.int32), prev_actions[:, 0], is_first, state, method=DecoderPPOAgent.step
         )
     return agent.apply(p, obs, prev_actions, is_first, state, method=RecurrentPPOAgent.step)
+
+
+class StepInputs:
+    """What a jitted step of the rollout takes of the host, and how it reads it.
+
+    Every host array that goes into a jitted call is a transfer of its own (~0.1 ms of host
+    time each on a TPU v5e's host, PERF.md section 6, PR 31), so the per-env vectors of a step
+    (the vector observation keys, the previous action, ``is_first``) go in as one array a
+    dtype, ``[envs, -1]`` each and side by side; image keys stay buffers of their own.
+    ``is_first``, a 0/1 flag, travels in the dtype of the previous action it gates (the
+    decoder's three vectors are one int32 ``[envs, 3]``).  The layout is read off the first
+    arrays; nothing here asks which sequence model runs."""
+
+    def __init__(self, cnn_keys, mlp_keys, obs, prev_actions):
+        self.cnn_keys = tuple(cnn_keys)
+        vectors = {**{k: obs[k] for k in mlp_keys}, "prev_actions": prev_actions, "is_first": prev_actions[:, :1]}
+        self.groups: Dict[str, list] = {}  # dtype -> [(name, shape an env), ...], side by side in that order
+        for name, v in vectors.items():
+            self.groups.setdefault(v.dtype.name, []).append((name, v.shape[1:]))
+
+    def host(self, obs, prev_actions, is_first):
+        """``(images, vectors)`` for a jitted step: host arrays, the vectors one a dtype."""
+        vectors = {**obs, "prev_actions": prev_actions, "is_first": is_first.astype(prev_actions.dtype)}
+        n = len(is_first)
+        packed = {
+            dtype: np.concatenate([vectors[name].reshape(n, -1) for name, _ in members], -1)
+            for dtype, members in self.groups.items()
+        }
+        return {k: obs[k] for k in self.cnn_keys}, packed
+
+    def device(self, images, packed):
+        """Inside the jitted step: ``(obs, prev_actions, is_first)`` as the agent reads them
+        (vector keys cast to float32 here, as ``prepare_obs`` does on the host elsewhere)."""
+        rows = {}
+        for dtype, members in self.groups.items():
+            start = 0
+            for name, shape in members:
+                stop = start + math.prod(shape)
+                rows[name] = packed[dtype][:, start:stop].reshape(-1, *shape)
+                start = stop
+        prev_actions, is_first = rows.pop("prev_actions"), rows.pop("is_first").astype(jnp.float32)
+        return {**images, **{k: v.astype(jnp.float32) for k, v in rows.items()}}, prev_actions, is_first
+
+
+def make_acting_fns(agent, inputs: StepInputs):
+    """The two jitted calls of the rollout, over ``inputs.host(...)``'s two arguments:
+    ``act(p, images, vectors, (state, key))`` -> ``(results, (state, key))`` and
+    ``value(p, images, vectors, state)`` -> values ``[envs]``, which writes nothing.
+
+    ``act``'s last argument is donated: the state is written where it lies (the decoder's
+    caches are GBs), and the sampling key lives beside it, split here a step, so no key is
+    drawn on the host.  ``results`` is the one buffer that comes back, float32
+    ``[envs, A + 2]``: the environment's actions (ids are exact in float32), the
+    log-probability and the value."""
+    is_continuous = agent.is_continuous
+
+    def act(p, images, vectors, carry):
+        state, key = carry
+        key, sub = jax.random.split(key)
+        actor_out, value, state = agent_step(agent, p, *inputs.device(images, vectors), state)
+        env_act, _, logprob = sample_actions(sub, actor_out, is_continuous)
+        results = [env_act.astype(jnp.float32).reshape(len(logprob), -1), logprob[:, None], value]
+        return jnp.concatenate(results, -1), (state, key)
+
+    def value(p, images, vectors, state):
+        return agent_step(agent, p, *inputs.device(images, vectors), state)[1][..., 0]
+
+    return jax.jit(act, donate_argnums=(3,)), jax.jit(value)
+
+
+def acting_boundary(compiled, args) -> Dict[str, int]:
+    """What crosses the jit boundary a call of ``compiled`` with ``args``: the arguments
+    that are host arrays, the leaves of donated arguments whose place an output takes,
+    and the outputs that get a buffer of their own."""
+    head = compiled.as_text().split("\n", 1)[0]
+    aliased = len(re.findall(r"(?:may|must)-alias", head))
+    return {
+        "host_arrays_in": sum(isinstance(x, np.ndarray) for x in jax.tree.leaves(args)),
+        "donated_aliased": aliased,
+        "fresh_out": len(jax.tree.leaves(compiled.out_info)) - aliased,
+    }
 
 
 def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
@@ -206,20 +292,9 @@ def main(ctx, cfg) -> None:
 
     gamma, gae_lambda = cfg.algo.gamma, cfg.algo.gae_lambda
 
-    def act(p, obs, prev_actions, is_first, state, key):
-        actor_out, value, new_state = agent_step(agent, p, obs, prev_actions, is_first, state)
-        env_act, stored_act, logprob = sample_actions(key, actor_out, is_continuous)
-        return env_act, logprob, value[..., 0], new_state
-
-    # The carry is written where it lies (donated: the decoder's caches are GBs).  A value
-    # that must leave it as it is (a truncated episode's last observation, the rollout's
-    # bootstrap) comes from a call of its own over all the rows: static shapes, so no count
-    # of truncated envs compiles anew.
-    act_fn = obs_perf.instrument(cfg, "ppo_recurrent/act_fn", jax.jit(act, donate_argnums=(4,)))
-    value_fn = jax.jit(lambda p, obs, prev, first, state: agent_step(agent, p, obs, prev, first, state)[1][..., 0])
     # the decoder's acting steps read its matmul weights in the compute dtype, cast once an update
     acting_params = jax.jit(lambda p: cast_matmul_weights(p, ctx.compute_dtype)) if is_decoder else (lambda p: p)
-    not_first = np.zeros((num_envs, 1), np.float32)  # a transfer where it is used: jnp.zeros would compile
+    not_first = np.zeros((num_envs, 1), np.float32)
 
     gae_fn = jax.jit(lambda r, v, d, nv: gae(r, v, d, nv, rollout_steps, gamma, gae_lambda))
 
@@ -243,12 +318,31 @@ def main(ctx, cfg) -> None:
         last_log = state.get("last_log", 0)
         last_checkpoint = state.get("last_checkpoint", 0)
 
-    obs, _ = envs.reset(seed=cfg.seed + rank)
-    zero_state = make_zero_state(cfg, ctx.compute_dtype)
-    is_attention = cfg.algo.get("sequence_model", "lstm") == "attention"
-    lstm_state = zero_state(num_envs)
+    def host_obs(o):  # the keys the policy reads, as the host arrays the jitted calls take
+        return {k: np.asarray(o[k]) for k in obs_keys}
+
+    obs = host_obs(envs.reset(seed=cfg.seed + rank)[0])
     prev_stored = np.zeros((num_envs, *prev_shape), dtype=prev_dtype)
     is_first_np = np.ones((num_envs, 1), dtype=np.float32)
+    # The carry is written where it lies (donated), the sampling key inside it.  A value
+    # that must leave it as it is (a truncated episode's last observation, the rollout's
+    # bootstrap) comes from a call of its own over all the rows: static shapes, so no count
+    # of truncated envs compiles anew.
+    inputs = StepInputs(cnn_keys, mlp_keys, obs, prev_stored)
+    act_jit, value_fn = make_acting_fns(agent, inputs)
+    act_fn = obs_perf.instrument(cfg, "ppo_recurrent/act_fn", act_jit)
+
+    def note_boundary(*args):
+        """Before the first acting call: what crosses its boundary, counted from the compiled
+        call (which that call finds in jit's cache).  No reference to ``args`` outlives this:
+        the acting parameters are a copy that must go before the update needs the room."""
+        obs_perf.note("acting_boundary", acting_boundary(act_jit.lower(*args).compile(), args))
+
+    zero_state = make_zero_state(cfg, ctx.compute_dtype)
+    is_attention = cfg.algo.get("sequence_model", "lstm") == "attention"
+    # what act_fn overwrites a step: the sequence model's state and the sampling key, one
+    # draw off this process's chain for the whole run
+    carry = (zero_state(num_envs), ctx.local_rng())
     step_data: Dict[str, np.ndarray] = {}
 
     for update in range(start_update, num_updates + 1):
@@ -257,28 +351,27 @@ def main(ctx, cfg) -> None:
             # The attention context never crosses a rollout boundary: training
             # attends within the rollout only, so acting resets its window here —
             # the policies stay EXACTLY on-policy.
-            lstm_state = zero_state(num_envs)
-        # the carry at the rollout's start, which the update reads: a copy, since the acting
+            carry = (zero_state(num_envs), carry[1])
+        # the state at the rollout's start, which the update reads: a copy, since the acting
         # steps overwrite theirs
-        state0 = jax.tree.map(jnp.copy, lstm_state)
+        state0 = jax.tree.map(jnp.copy, carry[0])
         act_params = acting_params(params)
+        if update == start_update and obs_perf.perf_enabled(cfg):
+            note_boundary(act_params, *inputs.host(obs, prev_stored, is_first_np), carry)
         env_t0 = time.perf_counter()
         with timer("Time/env_interaction_time"):
             for _ in range(rollout_steps):
-                obs_t = prepare_obs(obs, cnn_keys, mlp_keys)
-                with span("Rollout/act_call"):
-                    env_act, logprob, value, lstm_state = act_fn(
-                        act_params, obs_t, jnp.asarray(prev_stored), jnp.asarray(is_first_np), lstm_state, ctx.local_rng()
-                    )
-                with span("Rollout/action_fetch"):  # one fetch for the three small arrays of the step
-                    env_act_np, logprob_np, value_np = (np.asarray(x) for x in jax.device_get((env_act, logprob, value)))
+                with span("Rollout/act_call"):  # host arrays in (the call's own transfer), one buffer out
+                    results, carry = act_fn(act_params, *inputs.host(obs, prev_stored, is_first_np), carry)
+                with span("Rollout/action_fetch"):  # the one fetch of the step: it waits for the step
+                    results_np = np.asarray(jax.device_get(results))
+                env_act_np, logprob_np, value_np = results_np[:, :-2], results_np[:, -2], results_np[:, -1]
                 if is_continuous:
                     low, high = act_space.low, act_space.high
                     env_actions = np.clip(env_act_np, low, high) if np.isfinite(low).all() else env_act_np
-                elif len(actions_dim) == 1:
-                    env_actions = env_act_np[..., 0]
                 else:
-                    env_actions = env_act_np
+                    env_act_np = env_act_np.astype(np.int32)  # ids come back in the float32 buffer, exact
+                    env_actions = env_act_np[..., 0] if len(actions_dim) == 1 else env_act_np
                 next_obs, reward, terminated, truncated, info = envs.step(env_actions)
                 done = np.logical_or(terminated, truncated)
                 reward = np.asarray(reward, dtype=np.float32).reshape(num_envs)
@@ -292,17 +385,12 @@ def main(ctx, cfg) -> None:
                     final_obs = {k: np.array(next_obs[k]) for k in obs_keys}
                     for k in obs_keys:
                         final_obs[k][trunc_idx] = np.stack([np.asarray(info["final_obs"][i][k]) for i in trunc_idx])
-                    v_final = value_fn(
-                        act_params,
-                        prepare_obs(final_obs, cnn_keys, mlp_keys),
-                        jnp.asarray(_onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)),
-                        jnp.asarray(not_first),
-                        lstm_state,
-                    )
+                    taken = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
+                    v_final = value_fn(act_params, *inputs.host(final_obs, taken, not_first), carry[0])
                     reward[trunc_idx] += gamma * np.asarray(jax.device_get(v_final))[trunc_idx]
 
                 for k in obs_keys:
-                    step_data[k] = np.asarray(obs[k])[None]
+                    step_data[k] = obs[k][None]
                 step_data["actions"] = env_act_np.reshape(num_envs, -1).astype(np.float32)[None]
                 step_data["prev_actions"] = prev_stored[None].copy()
                 step_data["is_first"] = is_first_np[None].copy()
@@ -315,14 +403,13 @@ def main(ctx, cfg) -> None:
                 prev_stored = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
                 prev_stored[done] = 0
                 is_first_np = done.astype(np.float32).reshape(num_envs, 1)
-                obs = next_obs
+                obs = host_obs(next_obs)
                 policy_step += num_envs * world
                 record_episode_stats(aggregator, info)
         env_time = time.perf_counter() - env_t0
 
         local = rb.to_tensor()
-        obs_t = prepare_obs(obs, cnn_keys, mlp_keys)
-        next_value = value_fn(act_params, obs_t, jnp.asarray(prev_stored), jnp.asarray(is_first_np), lstm_state)
+        next_value = value_fn(act_params, *inputs.host(obs, prev_stored, is_first_np), carry[0])
         act_params = None  # the decoder's copy goes before the update needs the room
         returns, advantages = gae_fn(local["rewards"], local["values"], local["dones"], next_value[:, None])
         seq_data = {

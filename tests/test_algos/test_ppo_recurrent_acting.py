@@ -1,0 +1,190 @@
+"""What crosses the host-device boundary an acting step of recurrent PPO
+(``sheeprl_tpu/algos/ppo_recurrent/ppo_recurrent.py``), for its three sequence models at
+tiny sizes on the CPU (and for the LSTM over an image key and two action heads): the step's
+inputs go into the jitted call as host arrays, one a dtype, the sampling key lives in the
+donated carry, and nothing but the one buffer of per-env results comes back.
+
+Each model is run through the CLI for two rollouts with the module's ``jax`` / ``jnp`` seen
+through counting proxies and the update wrapped (as the benchmark's adapter wraps it), so the
+tests read what the program itself did: the calls it made in order, the rows it stored and
+the carry it handed to the update."""
+
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sheeprl_tpu.algos.ppo_recurrent import ppo_recurrent as program
+from sheeprl_tpu.cli import run
+from sheeprl_tpu.obs import perf as obs_perf
+from sheeprl_tpu.parallel.mesh import MeshContext
+
+ENVS, STEPS, UPDATES = 4, 8, 2
+SMALL = ["algo.mlp_keys.encoder=[state]", "algo.dense_units=8", "algo.rnn.lstm.hidden_size=8", "algo.mlp_layers=1", "algo.per_rank_num_batches=2"]
+MODELS = {
+    "lstm": ["exp=ppo_recurrent", "env=discrete_dummy", *SMALL],
+    "attention": ["exp=ppo_recurrent", "env=discrete_dummy", "algo.sequence_model=attention", "algo.attention.num_heads=2", "algo.attention.window=8", *SMALL],
+    # episodes of 3-10 tokens: terminations and truncations (the bootstrap's call) fall inside the rollouts
+    "decoder": [
+        "exp=ppo_recurrent_decoder", "env.wrapper.min_length=3", "env.wrapper.max_length=10", "algo.decoder.hidden_size=16", "algo.decoder.head_dim=8",
+        "algo.decoder.heads_held=2", "algo.decoder.kv_heads_held=1", "algo.decoder.moe_num_primary_experts=4", "algo.decoder.experts_held=4",
+        "algo.decoder.moe_ffn_hidden_size=8", "algo.decoder.vocab_held=16", "algo.decoder.layers=2", "algo.decoder.sliding_window_size=4",
+        "algo.decoder.cache_capacity=16",
+    ],
+    # an image key beside the vector key, two action heads
+    "pixels": ["exp=ppo_recurrent", "env=multidiscrete_dummy", "algo.cnn_keys.encoder=[rgb]", "algo.encoder.cnn_features_dim=16", *SMALL],
+}  # fmt: skip
+#: host arrays into the acting call: the vectors of one dtype as one array, an image key a buffer of its own
+HOST_ARRAYS_IN = {"lstm": 1, "attention": 1, "decoder": 1, "pixels": 2}
+
+
+class Counted:
+    """A module seen through a proxy: calls of ``names`` are logged, ``jit`` hands back
+    functions that log each of their calls under the jitted function's name."""
+
+    def __init__(self, module, names, log):
+        self._module, self._names, self._log = module, names, log
+
+    def __getattr__(self, name):
+        attr = getattr(self._module, name)
+        if name == "jit":
+            return lambda fn, **kwargs: CountedJit(attr(fn, **kwargs), self._log)
+        if name in self._names:
+            return lambda *args, **kwargs: (self._log.append(name), attr(*args, **kwargs))[1]
+        return attr
+
+
+class CountedJit:
+    def __init__(self, jitted, log):
+        self.__wrapped__, self._log = jitted, log
+
+    def __call__(self, *args, **kwargs):
+        self._log.append(self.__wrapped__.__name__)
+        return self.__wrapped__(*args, **kwargs)
+
+    def __getattr__(self, name):  # ``lower`` and the rest of the jitted function
+        return getattr(self.__wrapped__, name)
+
+
+def rollouts(model, tmp_path, rank=0, extra=()):
+    """Two rollouts and updates of ``model`` from seed 5 through ``cli.run``: the module's calls
+    in order, the rows each update was given, and the acting call's note."""
+    log, updates, notes = [], [], {}
+    real_make, real_local_rng = program.make_ppo_recurrent_train_fn, MeshContext.local_rng
+
+    def make_train_fn(ctx, agent, cfg, obs_keys):
+        opt, train_fn = real_make(ctx, agent, cfg, obs_keys)
+
+        def recording(params, opt_state, seq_data, state0, *rest):
+            notes.update(obs_perf._notes)
+            # the update's own view of the rollout, from the carry of its start, before the update overwrites anything
+            logprob, _, values, _ = jax.jit(lambda p, b, s: program.evaluate_sequences(agent, p, b, obs_keys, s))(params, seq_data, state0)
+            updates.append(
+                {
+                    # copies: on the CPU the update's rows can be views of the rollout buffer, which the next rollout overwrites
+                    "rows": {k: np.array(seq_data[k]) for k in ("actions", "logprobs", "values")},
+                    "evaluated": {"logprobs": np.array(logprob), "values": np.array(values)},
+                    "state_leaves": len(jax.tree.leaves(state0)),
+                    "parameter_shapes": {x.shape for x in jax.tree.leaves(params)},
+                    "live": [(x.shape, str(x.dtype)) for x in jax.live_arrays()],
+                }
+            )
+            log.append("train_fn")
+            return train_fn(params, opt_state, seq_data, state0, *rest)
+
+        recording.__wrapped__ = train_fn
+        return opt, recording
+
+    def local_rng(self):  # this process's chain as process ``rank`` would seed it; nothing else sees the rank
+        log.append("local_rng")
+        with mock.patch.object(jax, "process_index", lambda: rank):
+            return real_local_rng(self)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(program, "jax", Counted(jax, ("device_put",), log))
+        patch.setattr(program, "jnp", Counted(jnp, ("asarray", "array"), log))
+        patch.setattr(program, "prepare_obs", lambda *args: log.append("prepare_obs"))
+        patch.setattr(program, "make_ppo_recurrent_train_fn", make_train_fn)
+        patch.setattr(MeshContext, "local_rng", local_rng)
+        run(
+            MODELS[model]
+            + [
+                f"env.num_envs={ENVS}", f"algo.rollout_steps={STEPS}", f"algo.total_steps={ENVS * STEPS * UPDATES}", "algo.update_epochs=1",
+                "algo.run_test=False", "seed=5", "env.sync_env=True", "env.capture_video=False", "checkpoint.every=0", "checkpoint.save_last=False",
+                "metric.log_every=100000", "buffer.memmap=False", f"log_root={tmp_path}", *extra,
+            ]  # fmt: skip
+        )
+    assert len(updates) == UPDATES
+    return {"log": log, "updates": updates, "notes": notes}
+
+
+@pytest.fixture(scope="module")
+def first_run(tmp_path_factory):
+    """One run a model, shared by the cases below."""
+    runs = {}
+
+    def of(model):
+        if model not in runs:
+            runs[model] = rollouts(model, tmp_path_factory.mktemp(model))
+        return runs[model]
+
+    return of
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_the_compiled_call_aliases_the_whole_carry_and_hands_back_the_results_only(first_run, model):
+    ran = first_run(model)
+    update = ran["updates"][0]
+    assert ran["notes"]["acting_boundary"] == {
+        "host_arrays_in": HOST_ARRAYS_IN[model],  # observation, previous action and is_first; the decoder's three are one int32 array
+        "donated_aliased": update["state_leaves"] + 1,  # every leaf of the state, and the key
+        "fresh_out": 1,  # actions, log-probability and value in one buffer
+    }
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_nothing_but_the_acting_step_is_dispatched_between_two_acting_steps(first_run, model):
+    log = first_run(model)["log"]
+    assert not {"asarray", "array", "device_put", "prepare_obs"} & set(log), log
+    assert log.count("local_rng") == 1  # the carry's key, once a run
+    assert log.count("act") == STEPS * UPDATES
+    acts = [i for i, name in enumerate(log) if name == "act"]
+    between = [set(log[a + 1 : b]) for a, b in zip(acts, acts[1:])]
+    boundaries = [names for names in between if "train_fn" in names]
+    assert len(boundaries) == UPDATES - 1
+    # inside a rollout: the truncation bootstrap's call, and no other
+    assert all(names <= {"value"} for names in between if "train_fn" not in names), log
+    if model == "decoder":
+        assert {"value"} in between  # an episode was cut inside the rollouts, so the bootstrap's call is covered
+
+
+@pytest.mark.parametrize("model", ["lstm", "attention", "decoder"])
+def test_one_seed_stores_the_same_rows_and_another_rank_does_not(first_run, model, tmp_path):
+    first = first_run(model)["updates"]
+    again = rollouts(model, tmp_path / "again")["updates"]
+    other = rollouts(model, tmp_path / "other", rank=1)["updates"]
+    for a, b in zip(first, again):
+        for name in ("actions", "logprobs", "values"):
+            np.testing.assert_array_equal(a["rows"][name], b["rows"][name], err_msg=name)
+    assert any((a["rows"]["actions"] != b["rows"]["actions"]).any() for a, b in zip(first, other))
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_the_stored_rows_are_what_the_update_evaluates_from_the_carry_of_the_rollouts_start(first_run, model):
+    """On-policy before the first step: the log-probabilities and values the acting steps wrote,
+    one step at a time through the carry, are those of ``evaluate_sequences`` over the whole
+    rollout from ``state0`` (for the decoder: one chunk through the carried caches)."""
+    for update in first_run(model)["updates"]:
+        np.testing.assert_allclose(update["rows"]["logprobs"], update["evaluated"]["logprobs"], atol=1e-5)
+        np.testing.assert_allclose(update["rows"]["values"], update["evaluated"]["values"], atol=1e-5)
+
+
+def test_the_acting_copy_of_the_weights_is_gone_when_the_update_runs(tmp_path):
+    """The decoder's acting steps read a copy of the matmul weights in the compute dtype, which
+    must go before the update needs the room: on the chip a reference kept for the boundary's
+    note held 1.3 GB through every update (PERF.md section 6, PR 31)."""
+    for update in rollouts("decoder", tmp_path, extra=["mesh.precision=bf16-mixed"])["updates"]:
+        copies = [shape for shape, dtype in update["live"] if dtype == "bfloat16" and shape in update["parameter_shapes"]]
+        assert not copies, copies
