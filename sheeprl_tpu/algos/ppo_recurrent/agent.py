@@ -28,7 +28,7 @@ from sheeprl_tpu.ops.ring_attention import reference_attention
 
 
 #: tokens whose logits the decoder's update forms at a time: 512 x 37,984 float32 logits are
-#: 78 MB where the whole minibatch's would be 0.62 GB
+#: 78 MB where the whole minibatch's would be 0.62 GB (SmallThinker's rows held; LFM2's 16,384: 34 MB)
 HEAD_CHUNK = 512
 
 
@@ -228,7 +228,8 @@ class DecoderPPOAgent(decoder.DecoderPolicy):
     """``sequence_model="decoder"``: the sparse-expert decoder of ``models/decoder.py`` as
     the policy.  Observations and actions are token ids of one vocabulary (an embedding
     lookup in place of the encoder and of the one-hot previous action); the critic is a
-    linear head on the final hidden state."""
+    linear head on the final hidden state; the policy's head is a table of its own or
+    the embedding's (``decoder.head_of``)."""
 
     is_continuous = False
 
@@ -250,7 +251,7 @@ def evaluate_sequences(agent, params, batch: Dict[str, jax.Array], obs_keys: Seq
         with scope("policy/head"):
             logprob, entropy = chunked_log_prob_and_entropy(
                 hidden.reshape(B * T, D),
-                params["params"]["head"],
+                decoder.head_of(params["params"]),
                 ids(batch["actions"]).reshape(B * T),
                 min(HEAD_CHUNK, B * T),
                 agent.dtype,

@@ -6,8 +6,8 @@ state, minibatching over the env/sequence axis — ``update_epochs`` × sequence
 in one jitted ``lax.scan`` chain, like the feed-forward PPO.
 
 The carry is one tree for every ``algo.sequence_model``: the LSTM's ``(c, h)``, the
-attention variant's ``(window, valid)``, the decoder's per-layer caches
-(``models/decoder.py``).  ``act_fn``, the rollout and ``train_fn`` pass it as one
+attention variant's ``(window, valid)``, the decoder's per-layer caches and convolution
+tails (``models/decoder.py``).  ``act_fn``, the rollout and ``train_fn`` pass it as one
 argument; the update reads the carry of the rollout's start as a constant.  An acting
 step crosses the host-device boundary once each way: host arrays in (``StepInputs``),
 the sampling key beside the state in ``act_fn``'s donated argument, one buffer of
@@ -39,7 +39,7 @@ from sheeprl_tpu.algos.ppo_recurrent.agent import (
     evaluate_sequences,
     make_zero_state,
 )
-from sheeprl_tpu.models.decoder import cast_matmul_weights
+from sheeprl_tpu.models.decoder import carry_kinds, cast_matmul_weights, hold_buffers
 from sheeprl_tpu.analysis.strict import assert_finite, maybe_inject_nonfinite, strict_guard
 from sheeprl_tpu.checkpoint.manager import CheckpointManager
 from sheeprl_tpu.fault.guard import TrainingGuard
@@ -218,6 +218,8 @@ def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
             (_, aux), grads = jax.value_and_grad(seq_loss_fn, has_aux=True)(p, batch, mb_state, clip_coef, ent_coef)
             with obs_perf.scope("policy_optimizer"):
                 updates, o_state = opt.update(grads, o_state, p)
+                if is_decoder:  # a router's selection bias is no trained weight: Adam leaves it as it is
+                    updates = hold_buffers(updates)
                 p = optax.apply_updates(p, updates)
             if health:  # per-module norms/ratios, averaged by the scans below
                 with obs_perf.scope("health"):
@@ -337,6 +339,8 @@ def main(ctx, cfg) -> None:
         call (which that call finds in jit's cache).  No reference to ``args`` outlives this:
         the acting parameters are a copy that must go before the update needs the room."""
         obs_perf.note("acting_boundary", acting_boundary(act_jit.lower(*args).compile(), args))
+        if is_decoder:  # how many layers carry a cache and how many a convolution tail, with their bytes
+            obs_perf.note("carry_kinds", carry_kinds(args[-1][0]))
 
     zero_state = make_zero_state(cfg, ctx.compute_dtype)
     is_attention = cfg.algo.get("sequence_model", "lstm") == "attention"

@@ -1,31 +1,45 @@
 """A sparse-expert decoder as a sequence policy: RMSNorm, rotary embedding, grouped-query
-attention of a chunk against a carried cache, and an expert layer that is told which
-experts it holds.
+attention of a chunk against a carried cache, a gated short convolution with a carried
+tail, a dense or an expert feed-forward, and an expert layer that is told which experts
+it holds.
 
-The layer (SmallThinker's, ``howto/decoder_policy.md`` gives the equations): the router
-reads the layer's input (before the attention's norm) and keeps the ``experts_per_token``
-largest of a softmax over all ``num_experts``, renormalised; attention is per layer
-either full without positional encoding or windowed with RoPE; the feed-forward is a
-ReGLU expert mixture.  A chip holds ``heads_held`` query heads, ``kv_heads_held`` key
-heads, ``experts_held`` experts (``expert_offset`` onward) and ``vocab_held`` rows of
-the tables: it routes over all the experts and computes its own experts' part of the
-result, and what the absent heads and experts would add is left out.  No exchange
-between chips is written here, and nothing stands in for the absent ones.
+``DecoderConfig`` describes layer kinds, not one model (``howto/decoder_policy.md`` gives
+the equations of the two published models that run on it).  A layer is ``h = x +
+Mixer(norm(x))``, ``out = h + FFN(norm(h))``.  Its *mixer* is per layer (``mixers``) full
+attention, sliding-window attention, or a gated short convolution; attention may norm
+each head's queries and keys (``qk_norm``) and rotates them where ``rope_layout`` says.
+Its *feed-forward* is dense in the ``dense_layers`` leading layers and an expert mixture
+after them, gated by ``activation``.  The *router* keeps the ``experts_per_token``
+largest of a softmax over all ``num_experts``, or (``router="sigmoid"``) of sigmoid scores
+plus a selection bias that the weights do not see; it reads the layer's input or the
+normed state the experts read (``router_reads``).  The head is a table of its own or the
+embedding's (``tie_embeddings``).  A chip holds ``heads_held`` query heads,
+``kv_heads_held`` key heads, ``experts_held`` experts (``expert_offset`` onward) and
+``vocab_held`` rows of the tables: it routes over all the experts and computes its own
+experts' part of the result, and what the absent heads and experts would add is left
+out.  No exchange between chips is written here, and nothing stands in for the absent
+ones.
 
-The carry is a tree: ``{"pos": [B], "layers": ({"k", "v", "pos"}, ...)}``.  ``pos`` is
-the row's next position inside its episode; a layer's cache holds keys (rotated
-already) and values in ``capacity`` slots (full layers) or ``window`` slots (a ring),
-each with the position it holds (``-1``: empty), written at ``position % slots``.  The
-shapes are static, so a step's cost does not depend on the fill.  A chunk of ``T``
-tokens attends to the cache and to itself by position (``ops.ring_attention.
-grouped_attention``): acting is the chunk of one token, whose keys are then written;
-training reads the cache as it stood when the rollout began and writes nothing.
+The carry is a tree: ``{"pos": [B], "layers": (state of layer 0, ...)}`` with a layer's
+state by its mixer's kind: ``{"k", "v", "pos"}`` or ``{"conv": [B, taps - 1, D]}``.
+``pos`` is the row's next position inside its episode; an attention layer's cache holds
+keys (rotated already) and values in ``capacity`` slots (full layers) or ``window`` slots
+(a ring), each with the position it holds (``-1``: empty), written at ``position %
+slots`` (``k`` and ``v`` are one array ``[B, slots, Hkv, hd]``, or, for heads narrower than
+the chip's ``LANES``, a tuple of ``[B, slots, 1, LANES]``: ``lane_grouped_attention``); a
+convolution layer's tail holds the last ``taps - 1`` gated inputs of the row's
+episode, oldest first (zeros before its first token).  The shapes are static, so a
+step's cost does not depend on the fill.  A chunk of ``T`` tokens attends to the cache
+and to itself by position (``ops.ring_attention.grouped_attention``) and convolves over
+the tail and itself by segment: acting is the chunk of one token, whose keys and gated
+input are then written; training reads the carry as it stood when the rollout began and
+writes nothing.  An episode that starts empties its row of every layer's state.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -48,18 +62,30 @@ class DecoderConfig:
     vocab_held: int
     layers: int
     window: int
-    window_layout: Tuple[int, ...]  # per layer: 1 = sliding window, 0 = full attention
-    rope_layout: Tuple[int, ...]  # per layer: 1 = rotary embedding, 0 = no positional encoding
+    mixers: Tuple[str, ...]  # per layer: "full" | "window" (attention) | "conv" (gated short convolution)
+    rope_layout: Tuple[int, ...]  # per attention layer: 1 = rotary embedding, 0 = no positional encoding
     rope_theta: float = 1.5e6
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
     expert_offset: int = 0
     capacity: int = 8192  # slots of a full-attention layer's cache
+    conv_taps: int = 3  # a convolution layer's kernel; it carries ``conv_taps - 1`` gated inputs
+    qk_norm: bool = False  # RMSNorm of each head's queries and keys before the rotation
+    dense_layers: int = 0  # leading layers whose feed-forward is dense, ``dense_width`` wide
+    dense_width: int = 0
+    router: str = "softmax"  # "sigmoid": chosen by score + ``expert_bias``, weighted by the score alone
+    router_reads: str = "input"  # "input": the layer's input | "ffn_norm": the normed state the experts read
+    activation: str = "relu"  # the gate of the feed-forward: "relu" (ReGLU) | "silu" (SwiGLU)
+    tie_embeddings: bool = False  # the head is the embedding table
 
     @classmethod
     def from_cfg(cls, d: Any) -> "DecoderConfig":
         layers = int(d["layers"])
-        cyc = lambda xs: tuple(int(xs[i % len(xs)]) for i in range(layers))  # noqa: E731
+        cyc = lambda xs: tuple(xs[i % len(xs)] for i in range(layers))  # noqa: E731
+        if d.get("layer_types") is not None:
+            mixers = tuple(LAYER_TYPES[t] for t in cyc(d["layer_types"]))
+        else:
+            mixers = tuple("window" if w else "full" for w in cyc(d["sliding_window_layout"]))
         return cls(
             hidden_size=int(d["hidden_size"]),
             head_dim=int(d["head_dim"]),
@@ -72,17 +98,45 @@ class DecoderConfig:
             vocab_held=int(d["vocab_held"]),
             layers=layers,
             window=int(d["sliding_window_size"]),
-            window_layout=cyc(d["sliding_window_layout"]),
-            rope_layout=cyc(d["rope_layout"]),
+            mixers=mixers,
+            rope_layout=tuple(int(r) for r in cyc(d["rope_layout"])),
             rope_theta=float(d["rope_theta"]),
             rms_norm_eps=float(d["rms_norm_eps"]),
             norm_topk_prob=bool(d["norm_topk_prob"]),
             expert_offset=int(d.get("expert_offset", 0)),
             capacity=int(d["cache_capacity"]),
+            conv_taps=int(d["conv_L_cache"]),
+            qk_norm=bool(d["qk_norm"]),
+            dense_layers=int(d["num_dense_layers"]),
+            dense_width=int(d["intermediate_size"]),
+            router=str(d["router"]),
+            router_reads=str(d["router_reads"]),
+            activation=str(d["activation"]),
+            tie_embeddings=bool(d["tie_embeddings"]),
         )
 
     def slots(self, layer: int) -> int:
-        return self.window if self.window_layout[layer] else self.capacity
+        return self.window if self.mixers[layer] == "window" else self.capacity
+
+    @property
+    def expert_layers(self) -> int:
+        return self.layers - min(self.dense_layers, self.layers)
+
+    @property
+    def lane_groups(self) -> int:
+        """How many arrays a cache's keys (and values) are kept in: one, or, where a head is
+        narrower than ``LANES`` and the held key heads fill whole lanes, one a lane-full."""
+        width = self.kv_heads_held * self.head_dim
+        return width // LANES if self.head_dim < LANES and LANES % self.head_dim == 0 and width % LANES == 0 else 1
+
+
+#: the published configs' names for a layer's mixer -> ``DecoderConfig.mixers``
+LAYER_TYPES = {"conv": "conv", "full_attention": "full", "sliding_attention": "window"}
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+ROUTER_EPS = 1e-6  # in the denominator of the sigmoid router's renormalisation
+#: the minor axis of the chip's memory tiles: a cache whose rows are narrower is given another
+#: layout on the device, and the acting step's one-row write then copies the cache whole, twice
+LANES = 128
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -101,14 +155,40 @@ def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
 
 
-def route(x: jax.Array, w_router: jax.Array, k: int, renormalise: bool) -> Tuple[jax.Array, jax.Array]:
-    """``x``: ``[N, D]`` -> weights and ids ``[N, k]`` of the ``k`` most probable of all the
-    experts, in float32 (a tie flipped by rounding sends a token elsewhere)."""
+def route(
+    x: jax.Array, w_router: jax.Array, k: int, renormalise: bool, expert_bias: Optional[jax.Array] = None
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """``x``: ``[N, D]`` -> weights and ids ``[N, k]`` of the ``k`` chosen of all the
+    experts, in float32 (a tie flipped by rounding sends a token elsewhere).  Without
+    ``expert_bias`` the most probable of a softmax; with it (``[E]``, no trained weight)
+    sigmoid scores, the ``k`` largest of score + bias chosen and weighted by the score
+    alone.  Third: ``[N]``, whether the bias changed a token's chosen set (``None``
+    without a bias)."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
-    top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+    if expert_bias is None:
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+        if renormalise:
+            top_p = top_p / top_p.sum(-1, keepdims=True)
+        return top_p, top_i, None
+    score = jax.nn.sigmoid(logits)
+    _, top_i = jax.lax.top_k(score + jax.lax.stop_gradient(expert_bias.astype(jnp.float32)), k)
+    top_p = jnp.take_along_axis(score, top_i, -1)
     if renormalise:
-        top_p = top_p / top_p.sum(-1, keepdims=True)
-    return top_p, top_i
+        top_p = top_p / (top_p.sum(-1, keepdims=True) + ROUTER_EPS)
+    _, unbiased = jax.lax.top_k(score, k)
+    moved = jnp.any(jnp.sort(top_i, -1) != jnp.sort(unbiased, -1), -1)
+    return top_p, top_i, moved
+
+
+def _precision(x: jax.Array):
+    """Stated where a product runs: the process-wide ``jax_default_matmul_precision`` that
+    ``cli.run`` sets (``high``) is not left to decide (PERF.md, PR 28).  Operands already
+    in a narrow compute dtype multiply as they are; float32 ones in full."""
+    return jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+
+
+def _dot(a: jax.Array, w: jax.Array, **kwargs) -> jax.Array:
+    return jnp.dot(a, w, precision=_precision(a), **kwargs)
 
 
 def _grouped(rows: jax.Array, w: jax.Array, group_sizes: jax.Array, valid: jax.Array) -> jax.Array:
@@ -117,13 +197,13 @@ def _grouped(rows: jax.Array, w: jax.Array, group_sizes: jax.Array, valid: jax.A
     stated: the operands are in the compute dtype already, and under the process-wide
     ``jax_default_matmul_precision`` that ``cli.run`` sets (``high``) the chip's grouped
     product gave the expert branch a quarter of its gradient (PERF.md, PR 28)."""
-    precision = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
-    return jnp.where(valid, jax.lax.ragged_dot(rows, w, group_sizes, precision=precision), 0)
+    return jnp.where(valid, jax.lax.ragged_dot(rows, w, group_sizes, precision=_precision(rows)), 0)
 
 
 def expert_layer(
-    m: jax.Array, top_w: jax.Array, top_i: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, offset: int, dtype: Any
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    m: jax.Array, top_w: jax.Array, top_i: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, offset: int, dtype: Any,
+    activation: Callable[[jax.Array], jax.Array] = jax.nn.relu,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:  # fmt: skip
     """The part of the mixture that the experts held here give: ``m``: ``[N, D]`` (normed),
     ``top_w`` / ``top_i``: ``[N, K]`` over all the experts, ``w_*``: the held experts'
     weights ``[E_held, ...]``, which are experts ``offset .. offset + E_held - 1``.
@@ -144,7 +224,7 @@ def expert_layer(
     valid = (jnp.arange(N * K) < n_held)[:, None]
     rows = jnp.where(valid, m.astype(dtype)[token], 0)
     w_gate, w_up, w_down = w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype)
-    h = jax.nn.relu(_grouped(rows, w_gate, group_sizes, valid)) * _grouped(rows, w_up, group_sizes, valid)
+    h = activation(_grouped(rows, w_gate, group_sizes, valid)) * _grouped(rows, w_up, group_sizes, valid)
     y = _grouped(h, w_down, group_sizes, valid).astype(jnp.float32)
     weight = jnp.where(held, top_w, 0.0).reshape(-1)[order]
     out = jnp.zeros((N, m.shape[-1]), jnp.float32).at[token].add(y * weight[:, None])
@@ -159,12 +239,49 @@ def expert_layer(
 def positions(is_first: jax.Array, pos0: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """``is_first``: ``[B, T]`` (1 where an episode starts), ``pos0``: ``[B]`` the next
     position of the carried episode -> each token's position inside its episode and its
-    segment (0: the carried episode, which alone may read the cache)."""
+    segment (0: the carried episode, which alone may read the carried state)."""
     idx = jnp.arange(is_first.shape[1])[None]
     first = is_first > 0
     last_reset = jax.lax.cummax(jnp.where(first, idx, -1), axis=1)
     pos = jnp.where(last_reset >= 0, idx - last_reset, pos0[:, None] + idx)
     return pos.astype(jnp.int32), jnp.cumsum(first, 1, dtype=jnp.int32)
+
+
+def causal_taps(z: jax.Array, tail: jax.Array, kernel: jax.Array, q_seg: jax.Array) -> jax.Array:
+    """``c_t = sum_j kernel[j] * z_{t-j}`` per channel, in float32.  ``z``: ``[B, T, D]``,
+    ``tail``: ``[B, J - 1, D]`` the carried episode's last gated inputs (oldest first),
+    ``kernel``: ``[J, D]``, ``q_seg``: ``[B, T]``.  A tap reaches a token of its own
+    segment only: no tap crosses an episode's start, and the tail is segment 0's."""
+    T, back = z.shape[1], tail.shape[1]
+    zs = jnp.concatenate([tail.astype(z.dtype), z], 1)
+    segs = jnp.concatenate([jnp.zeros((z.shape[0], back), q_seg.dtype), q_seg], 1)
+    out = jnp.zeros(z.shape, jnp.float32)
+    for j in range(back + 1):
+        same = (segs[:, back - j : back - j + T] == q_seg)[..., None]
+        out = out + kernel[j].astype(jnp.float32) * jnp.where(same, zs[:, back - j : back - j + T], 0).astype(jnp.float32)
+    return out
+
+
+def lane_grouped_attention(q, k, v, cache, cache_seg, q_pos, q_seg, window) -> jax.Array:
+    """``grouped_attention`` over a cache kept a lane-full of key heads an array
+    (``cache["k"]``, ``cache["v"]``: tuples of ``[B, slots, 1, LANES]``, each holding
+    ``LANES // hd`` heads side by side).  Each array is attended as one wide key head:
+    a query head is padded with zeros to the lane's width, its own dimensions where its
+    key head lies, so its scores are its own head's (the zeros add nothing) and of the
+    weighted values it keeps its own head's part.  ``q``: ``[B, T, Hq, hd]``, ``k`` /
+    ``v``: the chunk's own ``[B, T, Hkv, hd]`` -> ``[B, T, Hq, hd]``."""
+    B, T, Hq, hd = q.shape
+    Hkv, P = k.shape[2], len(cache["k"])
+    per, G = Hkv // P, Hq // Hkv  # key heads a lane-full, query heads a key head
+    eye = jnp.eye(per, dtype=q.dtype)
+    wide = jnp.einsum("btpagd,aj->btpagjd", q.reshape(B, T, P, per, G, hd), eye).reshape(B, T, P, per * G, per * hd)
+    k, v = k.reshape(B, T, P, 1, per * hd), v.reshape(B, T, P, 1, per * hd)
+    outs = []
+    for j in range(P):
+        blocks = [(cache["k"][j].astype(q.dtype), cache["v"][j].astype(q.dtype), cache["pos"], cache_seg), (k[:, :, j], v[:, :, j], q_pos, q_seg)]
+        o = grouped_attention(wide[:, :, j], blocks, q_pos, q_seg, window, head_dim=hd)
+        outs.append(jnp.einsum("btagjd,aj->btagd", o.reshape(B, T, per, G, per, hd), eye))
+    return jnp.stack(outs, 2).reshape(B, T, Hq, hd)
 
 
 class DecoderLayer(nn.Module):
@@ -173,48 +290,94 @@ class DecoderLayer(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, cache_k, cache_v, cache_pos, q_pos, q_seg):
-        """``x``: ``[B, T, D]`` float32 -> the layer's output, the chunk's keys and values
-        ``[B, T, Hkv, hd]`` (for the cache) and the expert layer's counters."""
+    def __call__(self, x, state, q_pos, q_seg):
+        """``x``: ``[B, T, D]`` float32, ``state``: the layer's carried state -> the layer's
+        output, what the chunk made for that state (keys and values ``[B, T, Hkv, hd]``,
+        or the gated inputs ``[B, T, D]``) and the expert layer's counters (``None`` for
+        a dense feed-forward)."""
         c, dt = self.cfg, self.dtype
         D, hd, Hq, Hkv = c.hidden_size, c.head_dim, c.heads_held, c.kv_heads_held
         init = nn.initializers.normal(0.02)
-        w_router = self.param("router", init, (D, c.num_experts))
-        attn_norm = self.param("attn_norm", nn.initializers.ones, (D,))
-        wq = self.param("wq", init, (D, Hq * hd))
-        wk = self.param("wk", init, (D, Hkv * hd))
-        wv = self.param("wv", init, (D, Hkv * hd))
-        wo = self.param("wo", init, (Hq * hd, D))
+        kind = c.mixers[self.layer]
+        dense = self.layer < c.dense_layers
+        act = ACTIVATIONS[c.activation]
+        B, T, _ = x.shape
+
+        def routed(r):  # [B * T, D], as the router reads it
+            w_router = self.param("router", init, (D, c.num_experts))
+            bias = self.param("expert_bias", nn.initializers.zeros, (c.num_experts,)) if c.router == "sigmoid" else None
+            with scope("policy/router"):
+                return route(r, w_router, c.experts_per_token, c.norm_topk_prob, bias)
+
+        if not dense and c.router_reads == "input":
+            top_w, top_i, moved = routed(x.reshape(B * T, D))
+        if kind == "conv":
+            conv_norm = self.param("conv_norm", nn.initializers.ones, (D,))
+            conv_in = self.param("conv_in", init, (D, 3 * D))
+            conv_kernel = self.param("conv_kernel", init, (c.conv_taps, D))
+            conv_out = self.param("conv_out", init, (D, D))
+            with scope("policy/conv"):
+                a = rms_norm(x, conv_norm, c.rms_norm_eps).astype(dt)
+                gate_in, gate_out, u = jnp.split(_dot(a, conv_in.astype(dt)), 3, -1)
+                z = gate_in * u  # in the compute dtype: what the tail carries is what the chunk reads
+                y = (gate_out.astype(jnp.float32) * causal_taps(z, state["conv"], conv_kernel, q_seg)).astype(dt)
+                h = x + _dot(y, conv_out.astype(dt), preferred_element_type=jnp.float32)
+            made = {"conv": z}
+        else:
+            attn_norm = self.param("attn_norm", nn.initializers.ones, (D,))
+            wq = self.param("wq", init, (D, Hq * hd))
+            wk = self.param("wk", init, (D, Hkv * hd))
+            wv = self.param("wv", init, (D, Hkv * hd))
+            wo = self.param("wo", init, (Hq * hd, D))
+            if c.qk_norm:
+                q_norm = self.param("q_norm", nn.initializers.ones, (hd,))
+                k_norm = self.param("k_norm", nn.initializers.ones, (hd,))
+            with scope("policy/attention_window" if kind == "window" else "policy/attention_full"):
+                a = rms_norm(x, attn_norm, c.rms_norm_eps).astype(dt)
+                q = jnp.dot(a, wq.astype(dt)).reshape(B, T, Hq, hd)
+                k = jnp.dot(a, wk.astype(dt)).reshape(B, T, Hkv, hd)
+                v = jnp.dot(a, wv.astype(dt)).reshape(B, T, Hkv, hd)
+                if c.qk_norm:
+                    q, k = rms_norm(q, q_norm, c.rms_norm_eps).astype(dt), rms_norm(k, k_norm, c.rms_norm_eps).astype(dt)
+                if c.rope_layout[self.layer]:
+                    q, k = rope(q, q_pos, c.rope_theta), rope(k, q_pos, c.rope_theta)
+                cache_seg = jnp.where(state["pos"] >= 0, 0, -1)
+                window = c.window if kind == "window" else None
+                if c.lane_groups > 1:
+                    o = lane_grouped_attention(q, k, v, state, cache_seg, q_pos, q_seg, window)
+                else:
+                    blocks = [(state["k"].astype(dt), state["v"].astype(dt), state["pos"], cache_seg), (k, v, q_pos, q_seg)]
+                    o = grouped_attention(q, blocks, q_pos, q_seg, window)
+                h = x + jnp.dot(o.reshape(B, T, Hq * hd), wo.astype(dt), preferred_element_type=jnp.float32)
+            made = {"k": k, "v": v}
         ffn_norm = self.param("ffn_norm", nn.initializers.ones, (D,))
+        if dense:
+            F = c.dense_width
+            dense_gate = self.param("dense_gate", init, (D, F))
+            dense_up = self.param("dense_up", init, (D, F))
+            dense_down = self.param("dense_down", init, (F, D))
+            with scope("policy/dense_ffn"):
+                m = rms_norm(h, ffn_norm, c.rms_norm_eps).astype(dt)
+                g = act(_dot(m, dense_gate.astype(dt))) * _dot(m, dense_up.astype(dt))
+                return h + _dot(g, dense_down.astype(dt), preferred_element_type=jnp.float32), made, None
         w_gate = self.param("w_gate", init, (c.experts_held, D, c.expert_width))
         w_up = self.param("w_up", init, (c.experts_held, D, c.expert_width))
         w_down = self.param("w_down", init, (c.experts_held, c.expert_width, D))
-
-        B, T, _ = x.shape
-        windowed = bool(c.window_layout[self.layer])
-        with scope("policy/router"):
-            top_w, top_i = route(x.reshape(B * T, D), w_router, c.experts_per_token, c.norm_topk_prob)
-        with scope("policy/attention_window" if windowed else "policy/attention_full"):
-            a = rms_norm(x, attn_norm, c.rms_norm_eps).astype(dt)
-            q = jnp.dot(a, wq.astype(dt)).reshape(B, T, Hq, hd)
-            k = jnp.dot(a, wk.astype(dt)).reshape(B, T, Hkv, hd)
-            v = jnp.dot(a, wv.astype(dt)).reshape(B, T, Hkv, hd)
-            if c.rope_layout[self.layer]:
-                q, k = rope(q, q_pos, c.rope_theta), rope(k, q_pos, c.rope_theta)
-            cache_seg = jnp.where(cache_pos >= 0, 0, -1)
-            blocks = [(cache_k.astype(dt), cache_v.astype(dt), cache_pos, cache_seg), (k, v, q_pos, q_seg)]
-            o = grouped_attention(q, blocks, q_pos, q_seg, c.window if windowed else None)
-            h = x + jnp.dot(o.reshape(B, T, Hq * hd), wo.astype(dt), preferred_element_type=jnp.float32)
         with scope("policy/experts"):
             m = rms_norm(h, ffn_norm, c.rms_norm_eps).reshape(B * T, D)
-            y, counters = expert_layer(m, top_w, top_i, w_gate, w_up, w_down, c.expert_offset, dt)
-        return h + y.reshape(B, T, D), k, v, counters
+        if c.router_reads != "input":
+            top_w, top_i, moved = routed(m)
+        with scope("policy/experts"):
+            y, counters = expert_layer(m, top_w, top_i, w_gate, w_up, w_down, c.expert_offset, dt, act)
+        if moved is not None:
+            counters["bias_moved"] = moved.sum().astype(jnp.float32)
+        return h + y.reshape(B, T, D), made, counters
 
 
 class DecoderPolicy(nn.Module):
     """Token ids in, the final normed hidden state and a value out; the head's logits
     are formed by the caller (whole for one acting step, in token chunks for the
-    update: ``algos/ppo/utils.py::chunked_log_prob_and_entropy``)."""
+    update: ``algos/ppo/utils.py::chunked_log_prob_and_entropy`` over ``head_of``)."""
 
     cfg: DecoderConfig
     dtype: Any = jnp.float32
@@ -226,14 +389,15 @@ class DecoderPolicy(nn.Module):
         # each layer is recomputed in the backward pass: its scores over the cache are not kept
         self.blocks = [nn.remat(DecoderLayer)(c, i, self.dtype, name=f"layers_{i}") for i in range(c.layers)]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (c.hidden_size,))
-        self.head = self.param("head", init, (c.hidden_size, c.vocab_held))
+        if not c.tie_embeddings:
+            self.head = self.param("head", init, (c.hidden_size, c.vocab_held))
         self.value_w = self.param("value_w", nn.initializers.zeros, (c.hidden_size, 1))
         self.value_b = self.param("value_b", nn.initializers.zeros, (1,))
 
     def __call__(self, tokens, prev_actions, is_first, state):
         """``tokens`` / ``prev_actions``: ``[B, T]`` ids, ``is_first``: ``[B, T]``, ``state``:
-        the carry -> ``(hidden [B, T, D] float32, values [B, T], (k, v) a layer, q_pos,
-        counters)``.  Writes nothing."""
+        the carry -> ``(hidden [B, T, D] float32, values [B, T], what each layer made for
+        its state, q_pos, counters)``.  Writes nothing."""
         c = self.cfg
         q_pos, q_seg = positions(is_first, state["pos"])
         with scope("policy/embed"):
@@ -241,65 +405,115 @@ class DecoderPolicy(nn.Module):
             keep = (1.0 - is_first.astype(jnp.float32))[..., None]
             x = emb[tokens] + keep * emb[prev_actions]
         written, totals = [], None
-        for block, cache in zip(self.blocks, state["layers"]):
-            x, k, v, counters = block(x, cache["k"], cache["v"], cache["pos"], q_pos, q_seg)
-            written.append((k, v))
-            totals = counters if totals is None else jax.tree.map(jnp.add, totals, counters)
+        for block, layer_state in zip(self.blocks, state["layers"]):
+            x, made, counters = block(x, layer_state, q_pos, q_seg)
+            written.append(made)
+            if counters is not None:
+                totals = counters if totals is None else jax.tree.map(jnp.add, totals, counters)
         with scope("policy/head"):
             hidden = rms_norm(x, self.final_norm, c.rms_norm_eps)
             values = (jnp.dot(hidden, self.value_w.astype(jnp.float32)) + self.value_b)[..., 0]
-        assigned = float(tokens.size * c.experts_per_token * c.layers)
-        aux = {
-            "MoE/held_share": totals["held"] / assigned,
-            "MoE/load_max_over_mean": totals["load_max"] * c.experts_held / jnp.maximum(totals["held"], 1.0),
-            "MoE/dropped": totals["dropped"],
-        }
+        aux = {}
+        if totals is not None:
+            routed = float(tokens.size * c.expert_layers)  # token-layers that met a router
+            aux = {
+                "MoE/held_share": totals["held"] / (routed * c.experts_per_token),
+                "MoE/load_max_over_mean": totals["load_max"] * c.experts_held / jnp.maximum(totals["held"], 1.0),
+                "MoE/dropped": totals["dropped"],
+            }
+            if "bias_moved" in totals:
+                aux["MoE/bias_moved_share"] = totals["bias_moved"] / routed
         return hidden, values, written, q_pos, aux
 
     def logits(self, hidden):
         with scope("policy/head"):
+            if self.cfg.tie_embeddings:
+                return _dot(hidden.astype(self.dtype), self.embed.astype(self.dtype).T, preferred_element_type=jnp.float32)
             return jnp.dot(hidden.astype(self.dtype), self.head.astype(self.dtype), preferred_element_type=jnp.float32)
 
     def step(self, tokens, prev_actions, is_first, state):
         """One acting step over all rows: ``tokens`` / ``prev_actions``: ``[B]`` ids,
         ``is_first``: ``[B, 1]`` -> ``([logits [B, V]], value [B, 1], new state)``."""
         first = is_first[:, 0] > 0
-        # an episode that starts forgets the one before it: its slots are empty from here on
-        state = {
-            "pos": jnp.where(first, 0, state["pos"]),
-            "layers": tuple({**cache, "pos": jnp.where(first[:, None], -1, cache["pos"])} for cache in state["layers"]),
-        }
+        state = {"pos": jnp.where(first, 0, state["pos"]), "layers": tuple(emptied(s, first) for s in state["layers"])}
         hidden, values, written, q_pos, _ = self(tokens[:, None], prev_actions[:, None], is_first, state)
         rows, pos = jnp.arange(tokens.shape[0]), q_pos[:, 0]
         layers = []
-        for cache, (k, v) in zip(state["layers"], written):
-            slot = pos % cache["pos"].shape[1]
-            layers.append(
-                {
-                    "k": cache["k"].at[rows, slot].set(k[:, 0].astype(cache["k"].dtype)),
-                    "v": cache["v"].at[rows, slot].set(v[:, 0].astype(cache["v"].dtype)),
-                    "pos": cache["pos"].at[rows, slot].set(pos),
-                }
-            )
+        for old, made in zip(state["layers"], written):
+            if "conv" in old:
+                layers.append({"conv": jnp.concatenate([old["conv"][:, 1:], made["conv"].astype(old["conv"].dtype)], 1)})
+                continue
+            slot = pos % old["pos"].shape[1]
+            k, v = (_into_slot(old[name], made[name][:, 0], rows, slot) for name in ("k", "v"))
+            layers.append({"k": k, "v": v, "pos": old["pos"].at[rows, slot].set(pos)})
         return [self.logits(hidden[:, 0])], values, {"pos": pos + 1, "layers": tuple(layers)}
 
 
+def _into_slot(cache, new: jax.Array, rows: jax.Array, slot: jax.Array):
+    """A step's keys (or values) ``[B, Hkv, hd]`` written into their rows' slots of a cache
+    kept as one array or a lane-full of heads an array."""
+    if isinstance(cache, tuple):
+        new = new.reshape(len(rows), len(cache), 1, -1)
+        return tuple(c.at[rows, slot].set(new[:, j].astype(c.dtype)) for j, c in enumerate(cache))
+    return cache.at[rows, slot].set(new.astype(cache.dtype))
+
+
+def emptied(layer_state: Dict[str, jax.Array], first: jax.Array) -> Dict[str, jax.Array]:
+    """A layer's carried state with the rows of ``first`` (``[B]``) empty: an episode that
+    starts forgets the one before it, whichever kind of state the layer carries."""
+    if "conv" in layer_state:
+        return {"conv": jnp.where(first[:, None, None], 0, layer_state["conv"])}
+    return {**layer_state, "pos": jnp.where(first[:, None], -1, layer_state["pos"])}
+
+
+def head_of(params: Dict[str, Any]) -> jax.Array:
+    """The head ``[D, V]`` of a policy's ``params["params"]``: its own table, or the
+    embedding's rows (tied: that one leaf then takes the lookups' scatter-add and the
+    head's dense gradient)."""
+    return params["head"] if "head" in params else params["embed"].T
+
+
 def zero_state(sizes: DecoderConfig, n: int, dtype: Any) -> Dict[str, Any]:
-    """The carry of ``n`` rows before their first token: every slot empty."""
+    """The carry of ``n`` rows before their first token: every slot and every tail empty."""
     layers = []
     for i in range(sizes.layers):
+        if sizes.mixers[i] == "conv":
+            layers.append({"conv": jnp.zeros((n, sizes.conv_taps - 1, sizes.hidden_size), dtype)})
+            continue
         shape = (n, sizes.slots(i), sizes.kv_heads_held, sizes.head_dim)  # a buffer each: the acting step is given them to overwrite
-        layers.append({"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype), "pos": jnp.full(shape[:2], -1, jnp.int32)})
+        if sizes.lane_groups > 1:
+            kv = lambda: tuple(jnp.zeros((*shape[:2], 1, LANES), dtype) for _ in range(sizes.lane_groups))  # noqa: E731
+        else:
+            kv = lambda: jnp.zeros(shape, dtype)  # noqa: E731
+        layers.append({"k": kv(), "v": kv(), "pos": jnp.full(shape[:2], -1, jnp.int32)})
     return {"pos": jnp.zeros((n,), jnp.int32), "layers": tuple(layers)}
 
 
-MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head")
+def carry_kinds(state: Dict[str, Any]) -> Dict[str, Dict[str, int]]:
+    """How many layers of a carry hold a cache and how many a convolution tail, with their bytes."""
+    out = {"cache": {"layers": 0, "bytes": 0}, "conv": {"layers": 0, "bytes": 0}}
+    for layer_state in state["layers"]:
+        kind = out["conv" if "conv" in layer_state else "cache"]
+        kind["layers"] += 1
+        kind["bytes"] += sum(x.nbytes for x in jax.tree.leaves(layer_state))
+    return out
+
+
+MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head", "conv_in", "conv_out", "dense_gate", "dense_up", "dense_down")
+#: leaves that are no trained weight: no gradient reaches them and the optimizer leaves them as they are
+BUFFERS = ("expert_bias",)
+
+
+def _named(path: Sequence[Any], names: Sequence[str]) -> bool:
+    return getattr(path[-1], "key", None) in names
 
 
 def cast_matmul_weights(params: Any, dtype: Any) -> Any:
     """The weights that the policy multiplies in ``dtype``, cast once (the acting steps of
-    a rollout then read half the bytes); the tables, norms, router and value head stay."""
-    def cast(path: Sequence[Any], x: jax.Array) -> jax.Array:
-        return x.astype(dtype) if getattr(path[-1], "key", None) in MATMUL_WEIGHTS else x
+    a rollout then read half the bytes); the tables, norms, router, taps and value head stay."""
+    return jax.tree_util.tree_map_with_path(lambda path, x: x.astype(dtype) if _named(path, MATMUL_WEIGHTS) else x, params)
 
-    return jax.tree_util.tree_map_with_path(cast, params)
+
+def hold_buffers(updates: Any) -> Any:
+    """An optimizer's updates with those of ``BUFFERS`` set to zero, whatever the optimizer made of them."""
+    return jax.tree_util.tree_map_with_path(lambda path, u: jnp.zeros_like(u) if _named(path, BUFFERS) else u, updates)

@@ -162,7 +162,7 @@ def reference_attention(
     return out.astype(q.dtype)
 
 
-def grouped_attention(q, blocks, q_pos, q_seg, window=None) -> jax.Array:
+def grouped_attention(q, blocks, q_pos, q_seg, window=None, head_dim=None) -> jax.Array:
     """Grouped-query attention of a chunk's queries over several blocks of keys (a
     carried cache, then the chunk's own), masked by absolute position; plain full
     materialisation, one softmax over all the blocks.
@@ -174,12 +174,37 @@ def grouped_attention(q, blocks, q_pos, q_seg, window=None) -> jax.Array:
     another segment, e.g. an empty cache slot given ``-1``, is never visible).  A key
     is visible iff it is of the query's segment, not after it, and, with ``window``,
     fewer than ``window`` positions before it.  The blocks are not concatenated (a
-    cache is read where it lies); scores and softmax are float32.  Returns
-    ``[B, Tq, Hq, D]`` in ``q.dtype``; a query that sees no key returns zeros."""
+    cache is read where it lies); scores and softmax are float32, the scores over
+    ``sqrt(head_dim)`` (``D`` unless given: queries padded with zeros to a wider ``D`` keep
+    their own).  Returns
+    ``[B, Tq, Hq, D]`` in ``q.dtype``; a query that sees no key returns zeros.
+
+    The float32 scores ``[B, Hq, Tq, sum of Tk]`` are held whole while they are at most
+    ``SCORE_BYTES``; beyond that (32 query heads of a 64 x 64-token chunk over an 8,192-slot
+    cache are 4.3 GB, and the backward pass holds several such) the rows go through in
+    the fewest equal groups that keep a group's scores under it, one after another, each
+    recomputed in the backward pass: the same arithmetic a row, a group's scores alive at
+    a time."""
+    B, Tq, Hq, _ = q.shape
+    score_bytes = 4 * B * Hq * Tq * sum(kv_pos.shape[1] for _, _, kv_pos, _ in blocks)
+    groups = next(n for n in range(1, B + 1) if B % n == 0 and score_bytes <= n * SCORE_BYTES)
+    if groups == 1:
+        return _grouped_attention(q, blocks, q_pos, q_seg, window, head_dim)
+    split = lambda x: x.reshape(groups, B // groups, *x.shape[1:])  # noqa: E731
+    rows = jax.checkpoint(lambda args: _grouped_attention(*args, window, head_dim))
+    out = jax.lax.map(rows, jax.tree.map(split, (q, list(blocks), q_pos, q_seg)))
+    return out.reshape(q.shape)
+
+
+#: the most float32 scores ``grouped_attention`` holds at a time
+SCORE_BYTES = 1 << 30
+
+
+def _grouped_attention(q, blocks, q_pos, q_seg, window, head_dim) -> jax.Array:
     B, Tq, Hq, D = q.shape
     Hkv = blocks[0][0].shape[2]
     qg = q.reshape(B, Tq, Hkv, Hq // Hkv, D)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim or D, jnp.float32))
     scores, masks = [], []
     for k, _, kv_pos, kv_seg in blocks:
         scores.append(jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32) * scale)

@@ -1,13 +1,14 @@
 """The sparse-expert decoder policy (``sheeprl_tpu/models/decoder.py``) against the
-benchmark's plain reference (``perfbench/configs/smallthinker21b_1of4_reference.py``,
-which shares no code with it) on seeded weights, at a small size on the CPU: window 8,
-8 experts, float32."""
+benchmark's plain references (``perfbench/configs/smallthinker21b_1of4_reference.py`` and
+``perfbench/configs/lfm2_8b_a1b_1of4_reference.py``, which share no code with it or with
+each other) on seeded weights, at a small size on the CPU: window 8, 8 experts, float32."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from perfbench.configs import lfm2_8b_a1b_1of4_reference as lfm2
 from perfbench.configs import smallthinker21b_1of4_reference as ref
 from sheeprl_tpu.algos.ppo.utils import chunked_log_prob_and_entropy, log_prob_and_entropy
 from sheeprl_tpu.models import decoder
@@ -21,14 +22,40 @@ SIZES = {
 }  # fmt: skip
 
 
+#: LFM2's five layers as the cell holds them (conv + dense, attention + experts, three conv + experts), small
+LFM2 = {
+    "hidden_size": 32, "head_dim": 8, "heads_held": 4, "kv_heads_held": 2, "num_experts": 8, "experts_held": 8, "expert_offset": 0,
+    "experts_per_token": 2, "expert_width": 16, "dense_width": 48, "dense_layers": 1, "vocab_held": 48, "layers": 5,
+    "layer_types": ["conv", "full_attention", "conv", "conv", "conv"], "conv_taps": 3, "rope_theta": 1000000.0, "norm_eps": 1e-5,
+    "router_eps": 1e-6, "norm_topk_prob": True, "cache_capacity": 48, "router_scale": 2.0, "branch_scale": 0.25, "bias_scale": 0.05,
+}  # fmt: skip
+
+
 def config_of(S) -> decoder.DecoderConfig:
-    return decoder.DecoderConfig(
+    common = dict(
         hidden_size=S["hidden_size"], head_dim=S["head_dim"], heads_held=S["heads_held"], kv_heads_held=S["kv_heads_held"],
         num_experts=S["num_experts"], experts_held=S["experts_held"], experts_per_token=S["experts_per_token"],
-        expert_width=S["expert_width"], vocab_held=S["vocab_held"], layers=S["layers"], window=S["window"],
-        window_layout=tuple(S["window_layout"]), rope_layout=tuple(S["rope_layout"]), rope_theta=S["rope_theta"],
+        expert_width=S["expert_width"], vocab_held=S["vocab_held"], layers=S["layers"], rope_theta=S["rope_theta"],
         expert_offset=S["expert_offset"], capacity=S["cache_capacity"],
     )  # fmt: skip
+    if "layer_types" in S:  # LFM2's layer kinds
+        return decoder.DecoderConfig(
+            **common, window=0, mixers=tuple(decoder.LAYER_TYPES[t] for t in S["layer_types"][: S["layers"]]), rope_layout=(1,) * S["layers"],
+            rms_norm_eps=S["norm_eps"], conv_taps=S["conv_taps"], qk_norm=True, dense_layers=S["dense_layers"], dense_width=S["dense_width"],
+            router="sigmoid", router_reads="ffn_norm", activation="silu", tie_embeddings=True,
+        )  # fmt: skip
+    mixers = tuple("window" if w else "full" for w in S["window_layout"])
+    return decoder.DecoderConfig(**common, window=S["window"], mixers=mixers, rope_layout=tuple(S["rope_layout"]))
+
+
+def model_of(name, monkeypatch=None):
+    """``(reference module, sizes)`` of one of the two published layers at the small size;
+    ``lfm2_lanes``: with four key heads of 8 on a chip whose lanes are 16 wide, so that the
+    cache is kept two heads an array (``decoder.lane_grouped_attention``)."""
+    if name == "lfm2_lanes":
+        monkeypatch.setattr(decoder, "LANES", 16)
+        return lfm2, {**LFM2, "heads_held": 8, "kv_heads_held": 4}
+    return (lfm2, LFM2) if name == "lfm2" else (ref, SIZES)
 
 
 def sequences(rng, n, t, vocab, firsts):
@@ -41,37 +68,53 @@ def sequences(rng, n, t, vocab, firsts):
     return tokens, prev, is_first
 
 
-def reference_forward(S, weights, n, tokens, prev, is_first, pos, ep):
+def reference_forward(S, weights, n, tokens, prev, is_first, pos, ep, ref=ref):
     """One pass of the plain reference over whole sequences, nothing carried."""
     run = jax.jit(lambda w, *arrays: ref.forward(S, w, ref.empty_context(S, n, 0), *arrays))
     return run(weights, tokens, prev, jnp.asarray(is_first), jnp.asarray(pos), jnp.asarray(ep))
 
 
-@pytest.mark.parametrize("kind", ["full", "window"])
+#: one layer of each kind: SmallThinker's two, and LFM2's three (mixer + feed-forward)
+ONE_LAYER = {
+    "full": (ref, {**SIZES, "layers": 1, "window_layout": [0], "rope_layout": [0]}),
+    "window": (ref, {**SIZES, "layers": 1, "window_layout": [1], "rope_layout": [1]}),
+    "conv_dense": (lfm2, {**LFM2, "layers": 1, "layer_types": ["conv"], "dense_layers": 1}),
+    "conv_experts": (lfm2, {**LFM2, "layers": 1, "layer_types": ["conv"], "dense_layers": 0}),
+    "attention_qk_norm_experts": (lfm2, {**LFM2, "layers": 1, "layer_types": ["full_attention"], "dense_layers": 0}),
+}
+
+
+@pytest.mark.parametrize("kind", list(ONE_LAYER))
 def test_one_layer_of_each_kind_matches_the_reference(kind):
-    S = {**SIZES, "layers": 1, "window_layout": [int(kind == "window")], "rope_layout": [int(kind == "window")]}
+    ref, S = ONE_LAYER[kind]
     weights = ref.make_weights(S, 7)
     tokens, prev, is_first = sequences(np.random.default_rng(0), 3, 20, S["vocab_held"], [(0,), (0, 11), (0, 5, 6)])
     ep, pos, _, _ = ref.episodes_and_positions(is_first, np.zeros(3, np.int32), np.zeros(3, np.int32))
-    want, want_v, _, _ = reference_forward(S, weights, 3, tokens, prev, is_first, pos, ep)
+    want, want_v, _, _ = reference_forward(S, weights, 3, tokens, prev, is_first, pos, ep, ref)
     cfg = config_of(S)
     got, got_v, _, q_pos, aux = jax.jit(decoder.DecoderPolicy(cfg).apply)(weights, tokens, prev, is_first, decoder.zero_state(cfg, 3, jnp.float32))
     np.testing.assert_array_equal(np.asarray(q_pos), pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v), atol=2e-5)
-    assert float(aux["MoE/dropped"]) == 0.0 and float(aux["MoE/held_share"]) == 1.0
+    if kind == "conv_dense":
+        assert aux == {}  # no router, no counters
+    else:
+        assert float(aux["MoE/dropped"]) == 0.0 and float(aux["MoE/held_share"]) == 1.0
+        assert ("MoE/bias_moved_share" in aux) == (ref is lfm2)
 
 
-def test_acting_through_the_caches_matches_the_full_forward_pass():
-    """40 steps a row, one token at a time through the caches (the 8-slot rings wrap four
-    times over; episodes end inside), against one pass over each whole sequence."""
-    S = SIZES
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes"])
+def test_acting_through_the_caches_matches_the_full_forward_pass(model, monkeypatch):
+    """40 steps a row, one token at a time through the carry (the 8-slot rings wrap four
+    times over; episodes end inside, so caches and convolution tails are both emptied),
+    against one pass over each whole sequence."""
+    ref, S = model_of(model, monkeypatch)
     weights = ref.make_weights(S, 11)
     n, t = 3, 40
     tokens, prev, is_first = sequences(np.random.default_rng(1), n, t, S["vocab_held"], [(0, 25), (0, 13, 30), (0, 9, 10, 31)])
     ep, pos, _, _ = ref.episodes_and_positions(is_first, np.zeros(n, np.int32), np.zeros(n, np.int32))
-    hidden, want_v, _, _ = reference_forward(S, weights, n, tokens, prev, is_first, pos, ep)
-    want = np.asarray(hidden @ weights["params"]["head"])
+    hidden, want_v, _, _ = reference_forward(S, weights, n, tokens, prev, is_first, pos, ep, ref)
+    want = np.asarray(hidden @ decoder.head_of(weights["params"]))
     cfg = config_of(S)
     policy = decoder.DecoderPolicy(cfg)
     step = jax.jit(lambda state, tok, prv, first: policy.apply(weights, tok, prv, first, state, method=decoder.DecoderPolicy.step))
@@ -81,17 +124,21 @@ def test_acting_through_the_caches_matches_the_full_forward_pass():
         np.testing.assert_allclose(np.asarray(logits), want[:, i], atol=5e-5, err_msg=f"step {i}")
         np.testing.assert_allclose(np.asarray(value)[:, 0], np.asarray(want_v[:, i]), atol=5e-5)
     assert np.asarray(state["pos"]).tolist() == (pos[:, -1] + 1).tolist()
+    if model == "lfm2_lanes":  # two arrays of two key heads each, a lane-full wide
+        assert [x.shape for x in state["layers"][1]["k"]] == [(n, S["cache_capacity"], 1, 16)] * 2 and cfg.lane_groups == 2
 
 
-def test_a_chunk_after_carried_steps_matches_the_full_forward_pass():
-    """The update's view: 12 steps through the caches, then a chunk of 10 tokens in one piece
-    that reads the cache as a constant, with an episode start inside the chunk."""
-    S = SIZES
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes"])
+def test_a_chunk_after_carried_steps_matches_the_full_forward_pass(model, monkeypatch):
+    """The update's view: 12 steps through the carry, then a chunk of 10 tokens in one piece
+    that reads the carry as a constant, with an episode start inside the chunk (row 0: at
+    its sixth token, so no tap of the convolution may cross it; row 1 carries its episode on)."""
+    ref, S = model_of(model, monkeypatch)
     weights = ref.make_weights(S, 13)
     n, carried, t = 2, 12, 22
     tokens, prev, is_first = sequences(np.random.default_rng(2), n, t, S["vocab_held"], [(0, 17), (0, 4)])
     ep, pos, _, _ = ref.episodes_and_positions(is_first, np.zeros(n, np.int32), np.zeros(n, np.int32))
-    want, _, _, _ = reference_forward(S, weights, n, tokens, prev, is_first, pos, ep)
+    want, _, _, _ = reference_forward(S, weights, n, tokens, prev, is_first, pos, ep, ref)
     cfg = config_of(S)
     policy = decoder.DecoderPolicy(cfg)
     step = jax.jit(lambda state, tok, prv, first: policy.apply(weights, tok, prv, first, state, method=decoder.DecoderPolicy.step))
@@ -135,12 +182,167 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference(what):
         cfg = config_of(cut)
         cache = decoder.zero_state(cfg, n, jnp.float32)["layers"][0]
         q_pos, q_seg = decoder.positions(jnp.asarray(is_first), jnp.zeros(n, jnp.int32))
-        out, _, _, counters = jax.jit(decoder.DecoderLayer(cfg, 0).apply)({"params": part}, x, cache["k"], cache["v"], cache["pos"], q_pos, q_seg)
+        out, _, counters = jax.jit(decoder.DecoderLayer(cfg, 0).apply)({"params": part}, x, cache, q_pos, q_seg)
         total = total + (out - x)
         assert float(counters["dropped"]) == 0.0
     assert float(jnp.abs(whole - x).max()) > 1e-2
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole - x), atol=2e-5)
     assert x.shape[-1] == D
+
+
+def test_the_four_expert_shares_add_up_under_a_bias_that_changes_the_choice():
+    """LFM2's expert layer shared by four chips (offsets 0, 2, 4, 6 of 8 experts; 0, 8, 16, 24
+    of 32 at the published size): every chip holds the mixer whole, so it is counted once,
+    and the four expert parts add up to the uncut reference's layer; the selection bias is
+    wide enough here to change the chosen set of some tokens."""
+    S = {**LFM2, "layers": 1, "layer_types": ["conv"], "dense_layers": 0, "bias_scale": 0.1}
+    L = dict(lfm2.make_weights(S, 3)["params"]["layers_0"])
+    rng = np.random.default_rng(4)
+    n, t = 2, 12
+    x = jnp.asarray(rng.standard_normal((n, t, S["hidden_size"])), jnp.float32)
+    is_first = np.zeros((n, t), np.float32)
+    is_first[:, 0] = 1
+    is_first[1, 7] = 1
+    ep, pos, _, _ = lfm2.episodes_and_positions(is_first, np.zeros(n, np.int32), np.zeros(n, np.int32))
+    whole, _, _ = jax.jit(lambda L, x: lfm2.layer(S, 0, L, x, lfm2.empty_context(S, n, 0), jnp.asarray(pos), jnp.asarray(ep)))(L, x)
+
+    q_pos, q_seg = decoder.positions(jnp.asarray(is_first), jnp.zeros(n, jnp.int32))
+
+    def share(cut, part):
+        cfg = config_of(cut)
+        tail = decoder.zero_state(cfg, n, jnp.float32)["layers"][0]
+        return jax.jit(decoder.DecoderLayer(cfg, 0).apply)({"params": part}, x, tail, q_pos, q_seg)
+
+    mixed, _, _ = share(S, {**L, "w_down": jnp.zeros_like(L["w_down"])})  # x + the mixer: what every chip holds whole
+    total, moved = mixed - x, 0.0
+    for i in range(4):
+        e = slice(2 * i, 2 * i + 2)
+        out, _, counters = share({**S, "experts_held": 2, "expert_offset": 2 * i}, {**L, "w_gate": L["w_gate"][e], "w_up": L["w_up"][e], "w_down": L["w_down"][e]})
+        total = total + (out - mixed)
+        moved = float(counters["bias_moved"])
+        assert float(counters["dropped"]) == 0.0
+    assert 0 < moved < n * t  # the bias changed some tokens' experts, not all
+    assert float(jnp.abs(whole - mixed).max()) > 1e-2
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole - x), atol=2e-5)
+
+
+def test_the_choice_follows_score_plus_bias_and_the_weights_follow_the_score():
+    """Two tokens, four experts, two a token.  The scores alone would choose experts 0 and 1;
+    the bias lifts expert 3 over expert 1.  The weights are the chosen experts' scores
+    without the bias, over their sum (+ 1e-6); no gradient reaches the bias."""
+    logits = jnp.asarray([[2.0, 1.0, -3.0, 0.5], [0.1, 3.0, 2.0, -1.0]], jnp.float32)
+    x, w = jnp.eye(2, dtype=jnp.float32), logits  # x @ w = logits
+    bias = jnp.asarray([0.0, 0.0, 0.0, 0.2], jnp.float32)
+    score = np.asarray(jax.nn.sigmoid(logits))
+    top_w, top_i, moved = decoder.route(x, w, 2, True, bias)
+    assert np.asarray(top_i).tolist() == [[0, 3], [1, 2]] and np.asarray(moved).tolist() == [True, False]
+    want = np.stack([score[0, [0, 3]], score[1, [1, 2]]])
+    np.testing.assert_allclose(np.asarray(top_w), want / (want.sum(-1, keepdims=True) + 1e-6), rtol=1e-6)
+    assert not np.allclose(np.asarray(top_w)[0], (score[0, [0, 3]] + [0.0, 0.2]) / (score[0, [0, 3]].sum() + 0.2), atol=1e-3)  # the bias is in no weight
+    plain_w, plain_i, none = decoder.route(x, w, 2, True)
+    assert np.asarray(plain_i).tolist() == [[0, 1], [1, 2]] and none is None
+    np.testing.assert_allclose(np.asarray(plain_w).sum(-1), 1.0, rtol=1e-6)
+    g = jax.grad(lambda b: decoder.route(x, w, 2, True, b)[0].sum())(bias)
+    assert np.asarray(g).tolist() == [0.0] * 4
+
+
+def test_the_tied_tables_gradient_is_the_sum_of_the_lookups_and_the_heads():
+    """One leaf is the embedding and the head: its gradient is what the lookups scatter
+    into their rows plus the head's dense product, which the same loss gives two separate
+    tables (the program's own pass, its head formed from ``head_of``)."""
+    S = {**LFM2, "layers": 2, "layer_types": ["conv", "full_attention"]}
+    cfg = config_of(S)
+    weights = lfm2.make_weights(S, 5)
+    n, t = 2, 9
+    tokens, prev, is_first = sequences(np.random.default_rng(8), n, t, S["vocab_held"], [(0,), (0, 4)])
+    actions = jnp.asarray(np.random.default_rng(9).integers(0, S["vocab_held"], (n * t,)), jnp.int32)
+    policy, state = decoder.DecoderPolicy(cfg), decoder.zero_state(cfg, n, jnp.float32)
+
+    def loss(lookup_table, head_table):
+        params = {"params": {**weights["params"], "embed": lookup_table}}
+        hidden, values, _, _, _ = policy.apply(params, tokens, prev, is_first, state)
+        lp, ent = chunked_log_prob_and_entropy(hidden.reshape(n * t, -1), decoder.head_of({"embed": head_table}), actions, 6, jnp.float32)
+        return (lp * 0.3 + ent).sum() + values.sum()
+
+    table = weights["params"]["embed"]
+    tied = jax.grad(lambda e: loss(e, e))(table)
+    by_lookup, by_head = jax.grad(loss, argnums=(0, 1))(table, table)
+    used = np.unique(np.concatenate([tokens.reshape(-1), prev.reshape(-1)]))
+    assert np.abs(np.asarray(by_lookup)[used]).max() > 0 and not np.asarray(by_lookup)[np.setdiff1d(np.arange(S["vocab_held"]), used)].any()
+    assert np.abs(np.asarray(by_head)).min() > 0  # dense: every row of the head has a gradient
+    np.testing.assert_allclose(np.asarray(tied), np.asarray(by_lookup + by_head), rtol=1e-5, atol=1e-6)
+    assert "head" not in jax.eval_shape(policy.init, jax.random.PRNGKey(0), tokens, prev, is_first, state)["params"]
+
+
+def test_the_selection_bias_is_unchanged_by_an_update_and_by_adam():
+    """Through the jitted update of ``ppo_recurrent`` itself at a tiny size, with a weight
+    decay that would move any leaf the optimizer is handed: every trained leaf changes,
+    ``expert_bias`` is bit for bit what it was (no gradient reaches it: the test of ``route``;
+    what the decay made of it in Adam's moments is held back)."""
+    import gymnasium as gym
+
+    from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent, make_zero_state
+    from sheeprl_tpu.algos.ppo_recurrent.ppo_recurrent import make_ppo_recurrent_train_fn
+    from sheeprl_tpu.analysis.ir.synth import compose_tiny, tiny_ctx
+
+    cfg = compose_tiny(
+        [
+            "exp=ppo_recurrent_decoder", "algo=ppo_recurrent_lfm2_8b_a1b", "algo.rollout_steps=6", "algo.update_epochs=2", "env.num_envs=2",
+            "algo.decoder.hidden_size=16", "algo.decoder.head_dim=8", "algo.decoder.heads_held=2", "algo.decoder.kv_heads_held=1",
+            "algo.decoder.moe_num_primary_experts=4", "algo.decoder.experts_held=2", "algo.decoder.moe_num_active_primary_experts=2",
+            "algo.decoder.moe_ffn_hidden_size=8", "algo.decoder.intermediate_size=24", "algo.decoder.vocab_held=16", "algo.decoder.cache_capacity=8",
+            "algo.optimizer.weight_decay=0.1", "algo.optimizer.lr=1e-2",
+        ]
+    )  # fmt: skip
+    ctx = tiny_ctx(cfg)
+    V, T, N = 16, 6, 2
+    agent, params = build_agent(ctx, gym.spaces.Discrete(V), gym.spaces.Dict({"token": gym.spaces.Box(0, V - 1, (1,), np.int32)}), cfg)
+    bias = lambda tree, layer: np.asarray(tree["params"][f"layers_{layer}"]["expert_bias"])  # noqa: E731
+    params = jax.tree_util.tree_map_with_path(lambda path, x: x + 0.3 if path[-1].key == "expert_bias" else x, params)
+    before = jax.tree.map(np.asarray, params)
+    opt, train_fn = make_ppo_recurrent_train_fn(ctx, agent, cfg, ["token"])
+    rng = np.random.default_rng(3)
+    ids = lambda: jnp.asarray(rng.integers(0, V, (T, N, 1)), jnp.float32)  # noqa: E731
+    is_first = jnp.zeros((T, N, 1)).at[0].set(1.0).at[3, 1].set(1.0)
+    vec = lambda: jnp.asarray(rng.standard_normal((T, N)), jnp.float32)  # noqa: E731
+    seq = {"token": ids(), "actions": ids(), "prev_actions": ids().astype(jnp.int32), "is_first": is_first, "logprobs": vec() - 3.0, "values": vec(), "returns": vec(), "advantages": vec()}
+    new, _, metrics = train_fn(params, opt.init(params), seq, make_zero_state(cfg)(N), jax.random.PRNGKey(0), 0.2, 0.0)
+    changed = jax.tree_util.tree_map_with_path(lambda path, a, b: (path[-1].key, bool(np.any(np.asarray(a) != b))), new, before)
+    for name, moved in jax.tree.leaves(changed, is_leaf=lambda x: isinstance(x, tuple)):
+        assert moved == (name != "expert_bias"), name
+    for layer in range(1, 5):
+        np.testing.assert_array_equal(bias(new, layer), bias(before, layer))
+    assert 0.0 <= float(metrics["MoE/bias_moved_share"]) <= 1.0
+
+
+def test_smallthinkers_parameter_tree_and_carry_are_what_they_were():
+    """The tree ``DecoderPolicy`` initialises for SmallThinker's layer is the one its
+    reference lays out (names and shapes; no leaf of the new kinds), the carry holds a
+    cache a layer and nothing else, and the cast touches the same leaves."""
+    cfg = config_of(SIZES)
+    ids = jnp.zeros((1,), jnp.int32)
+    state = decoder.zero_state(cfg, 1, jnp.float32)
+    tree = jax.eval_shape(lambda k: decoder.DecoderPolicy(cfg).init(k, ids, ids, jnp.ones((1, 1)), state, method=decoder.DecoderPolicy.step), jax.random.PRNGKey(0))
+    have = {"/".join(str(k.key) for k in path): tuple(x.shape) for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    assert have == ref.flat_shapes(SIZES)
+    assert sorted(tree["params"]["layers_0"]) == ["attn_norm", "ffn_norm", "router", "w_down", "w_gate", "w_up", "wk", "wo", "wq", "wv"]
+    assert all(sorted(layer) == ["k", "pos", "v"] for layer in state["layers"]) and sorted(state) == ["layers", "pos"]
+    assert [layer["k"].shape[1] for layer in state["layers"]] == [32, 8, 32, 8]
+    assert decoder.carry_kinds(state) == {"cache": {"layers": 4, "bytes": sum(x.nbytes for x in jax.tree.leaves(state["layers"]))}, "conv": {"layers": 0, "bytes": 0}}
+
+
+def test_lfm2s_carry_holds_two_kinds_of_state_and_its_tree_is_the_references():
+    cfg = config_of(LFM2)
+    ids = jnp.zeros((1,), jnp.int32)
+    state = decoder.zero_state(cfg, 3, jnp.bfloat16)
+    assert [sorted(layer) for layer in state["layers"]] == [["conv"], ["k", "pos", "v"], ["conv"], ["conv"], ["conv"]]
+    assert state["layers"][0]["conv"].shape == (3, 2, 32) and state["layers"][0]["conv"].dtype == jnp.bfloat16
+    kinds = decoder.carry_kinds(state)
+    assert kinds["conv"] == {"layers": 4, "bytes": 4 * 3 * 2 * 32 * 2} and kinds["cache"]["layers"] == 1
+    one = decoder.zero_state(cfg, 1, jnp.float32)
+    tree = jax.eval_shape(lambda k: decoder.DecoderPolicy(cfg).init(k, ids, ids, jnp.ones((1, 1)), one, method=decoder.DecoderPolicy.step), jax.random.PRNGKey(0))
+    have = {"/".join(str(k.key) for k in path): tuple(x.shape) for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    assert have == lfm2.flat_shapes(LFM2)
 
 
 def test_no_assignment_is_dropped_when_every_token_picks_the_same_expert():
@@ -197,3 +399,42 @@ def test_matmul_weights_are_cast_once_and_the_rest_stay():
     cast = decoder.cast_matmul_weights(ref.make_weights(S, 1), jnp.bfloat16)["params"]
     assert {k for k, v in cast["layers_0"].items() if v.dtype == jnp.bfloat16} == {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
     assert cast["head"].dtype == jnp.bfloat16 and cast["embed"].dtype == jnp.float32 and cast["layers_0"]["router"].dtype == jnp.float32
+
+
+def test_the_new_layers_matmul_weights_are_cast_and_the_tied_table_taps_and_bias_stay():
+    cast = decoder.cast_matmul_weights(lfm2.make_weights(LFM2, 1), jnp.bfloat16)["params"]
+    narrow = lambda layer: {k for k, v in cast[layer].items() if v.dtype == jnp.bfloat16}  # noqa: E731
+    assert narrow("layers_0") == {"conv_in", "conv_out", "dense_gate", "dense_up", "dense_down"}
+    assert narrow("layers_1") == {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"} and narrow("layers_2") == {"conv_in", "conv_out", "w_gate", "w_up", "w_down"}
+    assert cast["embed"].dtype == jnp.float32  # the lookups read it exactly; the tied head casts it where it multiplies
+    held = decoder.hold_buffers(jax.tree.map(jnp.ones_like, cast))
+    assert not held["layers_1"]["expert_bias"].any() and held["layers_1"]["router"].all() and held["embed"].all()
+
+
+def test_attention_in_row_groups_is_the_whole_one(monkeypatch):
+    """Past ``SCORE_BYTES`` of float32 scores the rows go through ``grouped_attention`` in
+    equal groups, one after another and recomputed in the backward pass: outputs and
+    gradients are the whole pass's (here 8 rows in 4 groups of 2)."""
+    from sheeprl_tpu.ops import ring_attention
+
+    rng = np.random.default_rng(0)
+    B, T, Hq, Hkv, D, C = 8, 6, 4, 2, 8, 16
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+    q, ck, cv, k, v = normal(B, T, Hq, D), normal(B, C, Hkv, D), normal(B, C, Hkv, D), normal(B, T, Hkv, D), normal(B, T, Hkv, D)
+    c_pos = jnp.asarray(np.where(rng.random((B, C)) < 0.7, np.arange(C)[None], -1), jnp.int32)
+    q_pos, q_seg = jnp.broadcast_to(jnp.arange(C, C + T)[None], (B, T)), jnp.zeros((B, T), jnp.int32)
+
+    def both():  # traced anew at each call: the threshold is read while tracing
+        def attend(q, ck, cv, k, v):
+            return ring_attention.grouped_attention(q, [(ck, cv, c_pos, jnp.where(c_pos >= 0, 0, -1)), (k, v, q_pos, q_seg)], q_pos, q_seg, 5)
+
+        loops = "scan" in str(jax.make_jaxpr(attend)(q, ck, cv, k, v))
+        return loops, (attend(q, ck, cv, k, v), jax.grad(lambda *a: (attend(*a) ** 2).sum(), argnums=(0, 1, 2, 3, 4))(q, ck, cv, k, v))
+
+    loops, whole = both()
+    assert not loops
+    monkeypatch.setattr(ring_attention, "SCORE_BYTES", 4 * B * Hq * T * (C + T) // 3)  # three groups are not equal: four
+    loops, grouped = both()
+    assert loops
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(grouped)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
