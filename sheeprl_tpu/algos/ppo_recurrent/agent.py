@@ -342,7 +342,8 @@ def build_decoder_agent(ctx, action_space, obs_space, cfg) -> Tuple[DecoderPPOAg
         raise ValueError(f"sequence_model=decoder reads one key of token ids and writes one token; got keys {keys}, actions {action_space}")
     if int(action_space.n) != dcfg.vocab_held or int(obs_space[keys[0]].high.max()) >= dcfg.vocab_held:
         raise ValueError(f"the environment's ids ({obs_space[keys[0]]}, {action_space}) are not the {dcfg.vocab_held} rows held of the tables")
-    agent = DecoderPPOAgent(dcfg, ctx.compute_dtype)
+    # the update's attention kernel is a custom call, which the partitioner does not split: it is told the mesh
+    agent = DecoderPPOAgent(dcfg, ctx.compute_dtype, ctx.mesh if ctx.mesh.size > 1 else None)
     ids = jnp.zeros((1,), jnp.int32)
     state0 = decoder.zero_state(dcfg, 1, ctx.compute_dtype)
     params = agent.init(ctx.rng(), ids, ids, jnp.ones((1, 1)), state0, method=DecoderPPOAgent.step)

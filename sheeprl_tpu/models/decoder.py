@@ -34,6 +34,15 @@ and to itself by position (``ops.ring_attention.grouped_attention``) and convolv
 the tail and itself by segment: acting is the chunk of one token, whose keys and gated
 input are then written; training reads the carry as it stood when the rollout began and
 writes nothing.  An episode that starts empties its row of every layer's state.
+
+Attention takes one of two programs by the shape of its call.  One token a row (acting,
+the bootstrap value) forms its float32 scores over every slot whole: they are small, and
+the step's time is the cache's read.  A chunk (the update) goes blockwise through the
+cache, a key block at a time with an online softmax, the scores never leaving the chip
+and a block that the row has not filled (or that lies out of a window's reach) not
+visited at all (``ops/blockwise_attention.py``); the chunk's own keys are merged in
+afterwards.  The update reports the share of key blocks it visited
+(``Attn/key_blocks_visited_share``), and its trace notes ``blockwise_attention``.
 """
 
 from __future__ import annotations
@@ -45,7 +54,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.obs.perf import scope
+from sheeprl_tpu.obs.perf import note, scope
 from sheeprl_tpu.ops.ring_attention import grouped_attention
 
 
@@ -262,14 +271,15 @@ def causal_taps(z: jax.Array, tail: jax.Array, kernel: jax.Array, q_seg: jax.Arr
     return out
 
 
-def lane_grouped_attention(q, k, v, cache, cache_seg, q_pos, q_seg, window) -> jax.Array:
+def lane_grouped_attention(q, k, v, cache, cache_seg, q_pos, q_seg, window, mesh=None):
     """``grouped_attention`` over a cache kept a lane-full of key heads an array
     (``cache["k"]``, ``cache["v"]``: tuples of ``[B, slots, 1, LANES]``, each holding
     ``LANES // hd`` heads side by side).  Each array is attended as one wide key head:
     a query head is padded with zeros to the lane's width, its own dimensions where its
     key head lies, so its scores are its own head's (the zeros add nothing) and of the
     weighted values it keeps its own head's part.  ``q``: ``[B, T, Hq, hd]``, ``k`` /
-    ``v``: the chunk's own ``[B, T, Hkv, hd]`` -> ``[B, T, Hq, hd]``."""
+    ``v``: the chunk's own ``[B, T, Hkv, hd]`` -> ``[B, T, Hq, hd]`` and what a blockwise call
+    visited (every array's is the same: the flags are the positions', the tile the shapes')."""
     B, T, Hq, hd = q.shape
     Hkv, P = k.shape[2], len(cache["k"])
     per, G = Hkv // P, Hq // Hkv  # key heads a lane-full, query heads a key head
@@ -278,23 +288,24 @@ def lane_grouped_attention(q, k, v, cache, cache_seg, q_pos, q_seg, window) -> j
     k, v = k.reshape(B, T, P, 1, per * hd), v.reshape(B, T, P, 1, per * hd)
     outs = []
     for j in range(P):
-        blocks = [(cache["k"][j].astype(q.dtype), cache["v"][j].astype(q.dtype), cache["pos"], cache_seg), (k[:, :, j], v[:, :, j], q_pos, q_seg)]
-        o = grouped_attention(wide[:, :, j], blocks, q_pos, q_seg, window, head_dim=hd)
+        held = (cache["k"][j].astype(q.dtype), cache["v"][j].astype(q.dtype), cache["pos"], cache_seg)
+        o, visited = grouped_attention(wide[:, :, j], k[:, :, j], v[:, :, j], held, q_pos, q_seg, window, hd, mesh)
         outs.append(jnp.einsum("btagjd,aj->btagd", o.reshape(B, T, per, G, per, hd), eye))
-    return jnp.stack(outs, 2).reshape(B, T, Hq, hd)
+    return jnp.stack(outs, 2).reshape(B, T, Hq, hd), visited
 
 
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     layer: int
     dtype: Any = jnp.float32
+    mesh: Any = None  # the devices the rows are spread over, if several (``grouped_attention``)
 
     @nn.compact
     def __call__(self, x, state, q_pos, q_seg):
         """``x``: ``[B, T, D]`` float32, ``state``: the layer's carried state -> the layer's
         output, what the chunk made for that state (keys and values ``[B, T, Hkv, hd]``,
-        or the gated inputs ``[B, T, D]``) and the expert layer's counters (``None`` for
-        a dense feed-forward)."""
+        or the gated inputs ``[B, T, D]``) and the layer's counters: the expert layer's, and
+        the key blocks of its cache that a chunk's attention had and visited."""
         c, dt = self.cfg, self.dtype
         D, hd, Hq, Hkv = c.hidden_size, c.head_dim, c.heads_held, c.kv_heads_held
         init = nn.initializers.normal(0.02)
@@ -302,6 +313,7 @@ class DecoderLayer(nn.Module):
         dense = self.layer < c.dense_layers
         act = ACTIVATIONS[c.activation]
         B, T, _ = x.shape
+        counters = {}
 
         def routed(r):  # [B * T, D], as the router reads it
             w_router = self.param("router", init, (D, c.num_experts))
@@ -344,10 +356,14 @@ class DecoderLayer(nn.Module):
                 cache_seg = jnp.where(state["pos"] >= 0, 0, -1)
                 window = c.window if kind == "window" else None
                 if c.lane_groups > 1:
-                    o = lane_grouped_attention(q, k, v, state, cache_seg, q_pos, q_seg, window)
+                    o, visited = lane_grouped_attention(q, k, v, state, cache_seg, q_pos, q_seg, window, self.mesh)
                 else:
-                    blocks = [(state["k"].astype(dt), state["v"].astype(dt), state["pos"], cache_seg), (k, v, q_pos, q_seg)]
-                    o = grouped_attention(q, blocks, q_pos, q_seg, window)
+                    held = (state["k"].astype(dt), state["v"].astype(dt), state["pos"], cache_seg)
+                    o, visited = grouped_attention(q, k, v, held, q_pos, q_seg, window, mesh=self.mesh)
+                if visited is not None:  # the chunk went blockwise through the cache
+                    flags, how = visited
+                    counters = {"key_blocks": jnp.float32(flags.size), "key_blocks_visited": flags.sum().astype(jnp.float32)}
+                    note("blockwise_attention", {f"layer_{self.layer}": how})
                 h = x + jnp.dot(o.reshape(B, T, Hq * hd), wo.astype(dt), preferred_element_type=jnp.float32)
             made = {"k": k, "v": v}
         ffn_norm = self.param("ffn_norm", nn.initializers.ones, (D,))
@@ -359,7 +375,7 @@ class DecoderLayer(nn.Module):
             with scope("policy/dense_ffn"):
                 m = rms_norm(h, ffn_norm, c.rms_norm_eps).astype(dt)
                 g = act(_dot(m, dense_gate.astype(dt))) * _dot(m, dense_up.astype(dt))
-                return h + _dot(g, dense_down.astype(dt), preferred_element_type=jnp.float32), made, None
+                return h + _dot(g, dense_down.astype(dt), preferred_element_type=jnp.float32), made, counters
         w_gate = self.param("w_gate", init, (c.experts_held, D, c.expert_width))
         w_up = self.param("w_up", init, (c.experts_held, D, c.expert_width))
         w_down = self.param("w_down", init, (c.experts_held, c.expert_width, D))
@@ -368,7 +384,8 @@ class DecoderLayer(nn.Module):
         if c.router_reads != "input":
             top_w, top_i, moved = routed(m)
         with scope("policy/experts"):
-            y, counters = expert_layer(m, top_w, top_i, w_gate, w_up, w_down, c.expert_offset, dt, act)
+            y, routing = expert_layer(m, top_w, top_i, w_gate, w_up, w_down, c.expert_offset, dt, act)
+        counters = {**counters, **routing}
         if moved is not None:
             counters["bias_moved"] = moved.sum().astype(jnp.float32)
         return h + y.reshape(B, T, D), made, counters
@@ -381,13 +398,14 @@ class DecoderPolicy(nn.Module):
 
     cfg: DecoderConfig
     dtype: Any = jnp.float32
+    mesh: Any = None  # the devices the update's rows are spread over, if several
 
     def setup(self):
         c = self.cfg
         init = nn.initializers.normal(0.02)
         self.embed = self.param("embed", init, (c.vocab_held, c.hidden_size))
-        # each layer is recomputed in the backward pass: its scores over the cache are not kept
-        self.blocks = [nn.remat(DecoderLayer)(c, i, self.dtype, name=f"layers_{i}") for i in range(c.layers)]
+        # each layer is recomputed in the backward pass: what it computes between its input and output is not kept
+        self.blocks = [nn.remat(DecoderLayer)(c, i, self.dtype, self.mesh, name=f"layers_{i}") for i in range(c.layers)]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (c.hidden_size,))
         if not c.tie_embeddings:
             self.head = self.param("head", init, (c.hidden_size, c.vocab_held))
@@ -404,19 +422,21 @@ class DecoderPolicy(nn.Module):
             emb = self.embed.astype(jnp.float32)
             keep = (1.0 - is_first.astype(jnp.float32))[..., None]
             x = emb[tokens] + keep * emb[prev_actions]
-        written, totals = [], None
+        written, totals = [], {}
         for block, layer_state in zip(self.blocks, state["layers"]):
             x, made, counters = block(x, layer_state, q_pos, q_seg)
             written.append(made)
-            if counters is not None:
-                totals = counters if totals is None else jax.tree.map(jnp.add, totals, counters)
+            totals = {**totals, **{name: totals.get(name, 0.0) + n for name, n in counters.items()}}
         with scope("policy/head"):
             hidden = rms_norm(x, self.final_norm, c.rms_norm_eps)
             values = (jnp.dot(hidden, self.value_w.astype(jnp.float32)) + self.value_b)[..., 0]
         aux = {}
-        if totals is not None:
+        if "key_blocks" in totals:  # a chunk's attention went blockwise through the caches
+            aux["Attn/key_blocks_visited_share"] = totals["key_blocks_visited"] / totals["key_blocks"]
+        if "held" in totals:
             routed = float(tokens.size * c.expert_layers)  # token-layers that met a router
             aux = {
+                **aux,
                 "MoE/held_share": totals["held"] / (routed * c.experts_per_token),
                 "MoE/load_max_over_mean": totals["load_max"] * c.experts_held / jnp.maximum(totals["held"], 1.0),
                 "MoE/dropped": totals["dropped"],

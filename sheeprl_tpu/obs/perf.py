@@ -372,8 +372,10 @@ def scope(name: str):
 def note(key: str, value: Any) -> None:
     """Called while a jitted hot path is traced: a static fact about the program
     (which mechanism its trace engaged, on how much), logged once at the program's
-    registration and written as a top-level key of its scope map."""
-    _notes[key] = value
+    registration and written as a top-level key of its scope map.  A dict joins the dict
+    noted under the key before (each layer of a model its own entry)."""
+    before = _notes.get(key)
+    _notes[key] = {**before, **value} if isinstance(before, dict) and isinstance(value, dict) else value
 
 
 def scopes_tag():

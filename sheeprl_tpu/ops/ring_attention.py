@@ -18,10 +18,15 @@ semantics match full causal attention regardless of the ring size.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from sheeprl_tpu.ops import blockwise_attention
+
+_log = logging.getLogger(__name__)
 
 
 def _block_mask(q_pos, kv_pos, causal, q_seg=None, kv_seg=None, window=None):
@@ -162,45 +167,50 @@ def reference_attention(
     return out.astype(q.dtype)
 
 
-def grouped_attention(q, blocks, q_pos, q_seg, window=None, head_dim=None) -> jax.Array:
-    """Grouped-query attention of a chunk's queries over several blocks of keys (a
-    carried cache, then the chunk's own), masked by absolute position; plain full
-    materialisation, one softmax over all the blocks.
+def grouped_attention(q, k, v, cache, q_pos, q_seg, window=None, head_dim=None, mesh=None):
+    """Grouped-query attention of a chunk's queries over a carried cache, then the chunk's
+    own keys, masked by absolute position, one softmax over both.
 
-    ``q``: ``[B, Tq, Hq, D]``; ``blocks``: ``(k, v, kv_pos, kv_seg)`` each, ``k, v``:
-    ``[B, Tk, Hkv, D]`` with ``Hq`` a multiple of ``Hkv`` (query head ``h`` reads key
-    head ``h // (Hq // Hkv)``); ``q_pos`` / ``kv_pos``: ``[B, Tq]`` / ``[B, Tk]``
-    positions inside the episode; ``q_seg`` / ``kv_seg``: int segments (a key of
-    another segment, e.g. an empty cache slot given ``-1``, is never visible).  A key
-    is visible iff it is of the query's segment, not after it, and, with ``window``,
-    fewer than ``window`` positions before it.  The blocks are not concatenated (a
-    cache is read where it lies); scores and softmax are float32, the scores over
-    ``sqrt(head_dim)`` (``D`` unless given: queries padded with zeros to a wider ``D`` keep
-    their own).  Returns
-    ``[B, Tq, Hq, D]`` in ``q.dtype``; a query that sees no key returns zeros.
+    ``q``: ``[B, Tq, Hq, D]``; ``k``, ``v``: the chunk's own ``[B, Tq, Hkv, D]`` with ``Hq`` a
+    multiple of ``Hkv`` (query head ``h`` reads key head ``h // (Hq // Hkv)``); ``cache``:
+    ``(k, v, kv_pos, kv_seg)`` with ``k, v``: ``[B, slots, Hkv, D]``; ``q_pos`` / ``kv_pos``:
+    ``[B, Tq]`` / ``[B, slots]`` positions inside the episode; ``q_seg`` / ``kv_seg``: int
+    segments (a key of another segment, e.g. an empty cache slot given ``-1``, is never
+    visible).  A key is visible iff it is of the query's segment, not after it, and, with
+    ``window``, fewer than ``window`` positions before it.  The cache is read where it lies;
+    scores and softmax are float32, the scores over ``sqrt(head_dim)`` (``D`` unless given:
+    queries padded with zeros to a wider ``D`` keep their own).  Returns ``[B, Tq, Hq, D]`` in
+    ``q.dtype`` (a query that sees no key returns zeros) and what a blockwise call visited.
+    The cache is an input, the carry as it stood: it takes no gradient, whichever way the
+    call goes (``q`` and the chunk's own ``k``, ``v`` do).
 
-    The float32 scores ``[B, Hq, Tq, sum of Tk]`` are held whole while they are at most
-    ``SCORE_BYTES``; beyond that (32 query heads of a 64 x 64-token chunk over an 8,192-slot
-    cache are 4.3 GB, and the backward pass holds several such) the rows go through in
-    the fewest equal groups that keep a group's scores under it, one after another, each
-    recomputed in the backward pass: the same arithmetic a row, a group's scores alive at
-    a time."""
-    B, Tq, Hq, _ = q.shape
-    score_bytes = 4 * B * Hq * Tq * sum(kv_pos.shape[1] for _, _, kv_pos, _ in blocks)
-    groups = next(n for n in range(1, B + 1) if B % n == 0 and score_bytes <= n * SCORE_BYTES)
-    if groups == 1:
-        return _grouped_attention(q, blocks, q_pos, q_seg, window, head_dim)
-    split = lambda x: x.reshape(groups, B // groups, *x.shape[1:])  # noqa: E731
-    rows = jax.checkpoint(lambda args: _grouped_attention(*args, window, head_dim))
-    out = jax.lax.map(rows, jax.tree.map(split, (q, list(blocks), q_pos, q_seg)))
-    return out.reshape(q.shape)
-
-
-#: the most float32 scores ``grouped_attention`` holds at a time
-SCORE_BYTES = 1 << 30
+    By the shape of the call: one query a row (an acting step, whose scores are small and
+    whose time is the cache's read) forms the scores whole, ``_grouped_attention``, and
+    nothing is said of blocks (``None``); a chunk of queries goes blockwise through the
+    cache with the scores kept on the chip and the key blocks that the row has not filled
+    skipped (``ops/blockwise_attention.py``; its ``Visited``: the ``[B, blocks]`` flags and
+    the tile taken), where that kernel takes the shapes.  A chunk whose shapes it does not
+    take forms its scores whole too, ``B * Hq * Tq * slots`` of them in float32 at once, and
+    on the chip says so in the log.  ``mesh``: the devices the rows are spread over, if
+    several."""
+    B, Tq, Hq, D = q.shape
+    ck, cv, kv_pos, kv_seg = cache
+    tile = blockwise_attention.tiles(Tq * Hq // k.shape[2], kv_pos.shape[1], D) if Tq > 1 else None
+    if tile is None:
+        if Tq > 1 and jax.default_backend() == "tpu":
+            _log.warning("grouped_attention: a chunk %s forms its float32 scores whole: the blockwise kernel does not take %d slots of width %d", q.shape[:3], kv_pos.shape[1], D)  # fmt: skip
+        held = (jax.lax.stop_gradient(ck), jax.lax.stop_gradient(cv), kv_pos, kv_seg)
+        return _grouped_attention(q, [held, (k, v, q_pos, q_seg)], q_pos, q_seg, window, head_dim), None
+    flags = blockwise_attention.key_block_flags(q_pos, q_seg, kv_pos, kv_seg, window, tile[1])
+    scale = float((head_dim or D) ** -0.5)
+    out = blockwise_attention.cache_and_own_attention(q, k, v, ck, cv, flags, q_pos, q_seg, kv_pos, kv_seg, scale, window, tile, mesh)
+    return out, blockwise_attention.Visited(flags, {"query_tile": tile[0], "key_block": tile[1], "own_keys": "merged outside the kernel"})
 
 
 def _grouped_attention(q, blocks, q_pos, q_seg, window, head_dim) -> jax.Array:
+    """``grouped_attention`` with the scores of every block of keys (``(k, v, kv_pos, kv_seg)``
+    each) formed whole in float32 and one softmax over them all: the acting path, and
+    the oracle of the blockwise one."""
     B, Tq, Hq, D = q.shape
     Hkv = blocks[0][0].shape[2]
     qg = q.reshape(B, Tq, Hkv, Hq // Hkv, D)

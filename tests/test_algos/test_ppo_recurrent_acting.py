@@ -171,14 +171,88 @@ def test_one_seed_stores_the_same_rows_and_another_rank_does_not(first_run, mode
     assert any((a["rows"]["actions"] != b["rows"]["actions"]).any() for a, b in zip(first, other))
 
 
+#: The most the decoder's stored rows may lie from the update's evaluation in bfloat16 (log-probabilities
+#: and values, absolute).  Between two readings on the CPU at this file's sizes (PERF.md section 6, PR 33):
+#: as the program is, seeds 5-20 read 0.5e-4 to 4.6e-4 in the update that has a filled cache behind it
+#: (exactly 0 in the first, whose caches are empty); with a fault planted in the update's attention alone,
+#: seeds 5-8 read 4.2e-3 to 8.2e-3 (the cache's keys taken one position late) and 1.4e-2 to 5.8e-2 (the
+#: first key block a row visits left unflagged).
+DECODER_BF16 = 1.2e-3
+
+
 @pytest.mark.parametrize("model", list(MODELS))
 def test_the_stored_rows_are_what_the_update_evaluates_from_the_carry_of_the_rollouts_start(first_run, model):
     """On-policy before the first step: the log-probabilities and values the acting steps wrote,
     one step at a time through the carry, are those of ``evaluate_sequences`` over the whole
-    rollout from ``state0`` (for the decoder: one chunk through the carried caches)."""
+    rollout from ``state0`` (for the decoder: one chunk through the carried caches).  The
+    decoder's chunk attends blockwise with an online softmax where its acting step forms the
+    scores whole (``ops/blockwise_attention.py``): in this run's bfloat16 the one rounds the
+    probabilities before the division by their sum and the other after it, so the two agree to
+    a fraction of that dtype's rounding (``DECODER_BF16``) and no longer to float32's; in
+    float32 they do (the next test)."""
+    atol = DECODER_BF16 if model == "decoder" else 1e-5
     for update in first_run(model)["updates"]:
+        np.testing.assert_allclose(update["rows"]["logprobs"], update["evaluated"]["logprobs"], atol=atol)
+        np.testing.assert_allclose(update["rows"]["values"], update["evaluated"]["values"], atol=atol)
+
+
+def test_in_float32_the_decoders_stored_rows_are_the_updates_to_that_dtypes_rounding(tmp_path):
+    """The same comparison with nothing narrower than float32 in either program: what is left
+    between the acting steps' whole scores and the update's blockwise ones is summation order."""
+    for update in rollouts("decoder", tmp_path, extra=["mesh.precision=32-true"])["updates"]:
         np.testing.assert_allclose(update["rows"]["logprobs"], update["evaluated"]["logprobs"], atol=1e-5)
         np.testing.assert_allclose(update["rows"]["values"], update["evaluated"]["values"], atol=1e-5)
+
+
+def cache_one_position_late(patch):
+    """The update's chunk takes the cache's keys for one position later than they are: a
+    window's oldest key drops out.  An acting step (one query a row) is left as it is."""
+    from sheeprl_tpu.models import decoder
+
+    attend = decoder.grouped_attention
+
+    def late(q, k, v, cache, *rest, **kwargs):
+        ck, cv, kv_pos, kv_seg = cache
+        if q.shape[1] > 1:
+            kv_pos = jnp.where(kv_pos >= 0, kv_pos + 1, kv_pos)
+        return attend(q, k, v, (ck, cv, kv_pos, kv_seg), *rest, **kwargs)
+
+    patch.setattr(decoder, "grouped_attention", late)
+
+
+def first_visited_block_unflagged(patch):
+    """The update's kernel skips the first key block that a row should visit."""
+    from sheeprl_tpu.ops import blockwise_attention
+
+    flags_of = blockwise_attention.key_block_flags
+
+    def unflagged(*args):
+        flags = flags_of(*args)
+        return flags * (jnp.arange(flags.shape[1])[None] != flags.argmax(1)[:, None])
+
+    patch.setattr(blockwise_attention, "key_block_flags", unflagged)
+
+
+def gap(update):
+    return max(float(np.abs(update["rows"][name] - update["evaluated"][name]).max()) for name in ("logprobs", "values"))
+
+
+@pytest.mark.parametrize("fault", [cache_one_position_late, first_visited_block_unflagged], ids=lambda f: f.__name__)
+def test_a_fault_planted_in_the_updates_attention_reads_three_times_over_the_bfloat16_bound(fault, tmp_path, monkeypatch):
+    """What ``DECODER_BF16`` is for: the rounding stays under it, a wrong mask or a filled block
+    skipped does not, in the same bfloat16 run from the same seed."""
+    fault(monkeypatch)
+    updates = rollouts("decoder", tmp_path)["updates"]
+    assert gap(updates[0]) == 0  # nothing is carried into the first rollout: the fault has no key to hide
+    assert gap(updates[1]) >= 3 * DECODER_BF16
+
+
+def test_the_updates_program_notes_the_tile_its_blockwise_attention_took(first_run):
+    """``blockwise_attention`` in the scope map of ``ppo_recurrent/train_fn``: an entry a layer
+    whose chunk went through the kernel, as ``grouped_attention`` decided it from the shapes of
+    that layer's call (8 tokens x 2 query heads on one key head; 16 slots, or the window's 4)."""
+    tile = {"query_tile": STEPS * 2, "own_keys": "merged outside the kernel"}
+    assert first_run("decoder")["notes"]["blockwise_attention"] == {"layer_0": {**tile, "key_block": 16}, "layer_1": {**tile, "key_block": 4}}
 
 
 def test_the_acting_copy_of_the_weights_is_gone_when_the_update_runs(tmp_path):

@@ -409,32 +409,3 @@ def test_the_new_layers_matmul_weights_are_cast_and_the_tied_table_taps_and_bias
     assert cast["embed"].dtype == jnp.float32  # the lookups read it exactly; the tied head casts it where it multiplies
     held = decoder.hold_buffers(jax.tree.map(jnp.ones_like, cast))
     assert not held["layers_1"]["expert_bias"].any() and held["layers_1"]["router"].all() and held["embed"].all()
-
-
-def test_attention_in_row_groups_is_the_whole_one(monkeypatch):
-    """Past ``SCORE_BYTES`` of float32 scores the rows go through ``grouped_attention`` in
-    equal groups, one after another and recomputed in the backward pass: outputs and
-    gradients are the whole pass's (here 8 rows in 4 groups of 2)."""
-    from sheeprl_tpu.ops import ring_attention
-
-    rng = np.random.default_rng(0)
-    B, T, Hq, Hkv, D, C = 8, 6, 4, 2, 8, 16
-    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
-    q, ck, cv, k, v = normal(B, T, Hq, D), normal(B, C, Hkv, D), normal(B, C, Hkv, D), normal(B, T, Hkv, D), normal(B, T, Hkv, D)
-    c_pos = jnp.asarray(np.where(rng.random((B, C)) < 0.7, np.arange(C)[None], -1), jnp.int32)
-    q_pos, q_seg = jnp.broadcast_to(jnp.arange(C, C + T)[None], (B, T)), jnp.zeros((B, T), jnp.int32)
-
-    def both():  # traced anew at each call: the threshold is read while tracing
-        def attend(q, ck, cv, k, v):
-            return ring_attention.grouped_attention(q, [(ck, cv, c_pos, jnp.where(c_pos >= 0, 0, -1)), (k, v, q_pos, q_seg)], q_pos, q_seg, 5)
-
-        loops = "scan" in str(jax.make_jaxpr(attend)(q, ck, cv, k, v))
-        return loops, (attend(q, ck, cv, k, v), jax.grad(lambda *a: (attend(*a) ** 2).sum(), argnums=(0, 1, 2, 3, 4))(q, ck, cv, k, v))
-
-    loops, whole = both()
-    assert not loops
-    monkeypatch.setattr(ring_attention, "SCORE_BYTES", 4 * B * Hq * T * (C + T) // 3)  # three groups are not equal: four
-    loops, grouped = both()
-    assert loops
-    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(grouped)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
