@@ -1,18 +1,20 @@
 """A sparse-expert decoder as a sequence policy: RMSNorm, rotary embedding, grouped-query
-attention of a chunk against a carried cache, a gated short convolution with a carried
-tail, a dense or an expert feed-forward, and an expert layer that is told which experts
-it holds.
+attention of a chunk against a carried cache, latent attention over a compressed cache
+that keys and values share, a gated short convolution with a carried tail, a dense or an
+expert feed-forward, and an expert layer that is told which experts it holds.
 
 ``DecoderConfig`` describes layer kinds, not one model (``howto/decoder_policy.md`` gives
-the equations of the two published models that run on it).  A layer is ``h = x +
+the equations of the three published models that run on it).  A layer is ``h = x +
 Mixer(norm(x))``, ``out = h + FFN(norm(h))``.  Its *mixer* is per layer (``mixers``) full
-attention, sliding-window attention, or a gated short convolution; attention may norm
-each head's queries and keys (``qk_norm``) and rotates them where ``rope_layout`` says.
-Its *feed-forward* is dense in the ``dense_layers`` leading layers and an expert mixture
-after them, gated by ``activation``.  The *router* keeps the ``experts_per_token``
+attention, sliding-window attention, latent attention, or a gated short convolution;
+attention may norm each head's queries and keys (``qk_norm``) and rotates them where
+``rope_layout`` says.  Its *feed-forward* is dense in the ``dense_layers`` leading layers and
+an expert mixture after them, gated by ``activation``, with (``shared_width``) a dense part
+beside it that every token passes.  The *router* keeps the ``experts_per_token``
 largest of a softmax over all ``num_experts``, or (``router="sigmoid"``) of sigmoid scores
-plus a selection bias that the weights do not see; it reads the layer's input or the
-normed state the experts read (``router_reads``).  The head is a table of its own or the
+plus a selection bias that the weights do not see, and scales the kept weights by
+``routed_scale``; it reads the layer's input or the normed state the experts read
+(``router_reads``).  The head is a table of its own or the
 embedding's (``tie_embeddings``).  A chip holds ``heads_held`` query heads,
 ``kv_heads_held`` key heads, ``experts_held`` experts (``expert_offset`` onward) and
 ``vocab_held`` rows of the tables: it routes over all the experts and computes its own
@@ -20,8 +22,22 @@ experts' part of the result, and what the absent heads and experts would add is 
 out.  No exchange between chips is written here, and nothing stands in for the absent
 ones.
 
+*Latent attention* (MLA) as published: ``q = a W_q`` by head ``[q_nope, q_pe]``; ``[c_raw,
+k_pe] = a W_kv_a``; ``c = norm(c_raw)``; ``[k_nope_h, v_h] = split(c W_kv_b)``; the rotation on
+every ``q_pe`` and on the one ``k_pe`` all heads share; scores of ``[q_nope_h, q_pe_h]``
+against ``[k_nope_h, k_pe]`` over ``sqrt(qk_nope_head_dim + qk_rope_head_dim)``.  What runs
+here, on both paths, is a regrouping of those sums that never forms a cached row's
+per-head keys and values: with ``W_kv_b = [W_uk | W_uv]`` by head, ``q_nope . (W_uk c) =
+(W_uk^T q_nope) . c`` and ``sum_s p_s (W_uv c_s) = W_uv (sum_s p_s c_s)``, so a head's query
+is ``[W_uk^T q_nope, q_pe]``, the row ``[c, k_pe]`` is the one key head of all the query
+heads, its first ``kv_lora_rank`` columns are the values, and ``W_uv`` meets the weighted
+sum.  A row is kept as ``[c, k_pe, zeros]``, ``latent_width`` wide (whole lanes); the
+queries are padded alike.  ``W_kv_b`` is a weight wherever it multiplies, so it takes
+gradient through the cached rows a query sees, while the cache itself takes none.
+
 The carry is a tree: ``{"pos": [B], "layers": (state of layer 0, ...)}`` with a layer's
-state by its mixer's kind: ``{"k", "v", "pos"}`` or ``{"conv": [B, taps - 1, D]}``.
+state by its mixer's kind: ``{"k", "v", "pos"}``, ``{"latent": [B, capacity, latent_width],
+"pos"}`` or ``{"conv": [B, taps - 1, D]}``.
 ``pos`` is the row's next position inside its episode; an attention layer's cache holds
 keys (rotated already) and values in ``capacity`` slots (full layers) or ``window`` slots
 (a ring), each with the position it holds (``-1``: empty), written at ``position %
@@ -71,7 +87,7 @@ class DecoderConfig:
     vocab_held: int
     layers: int
     window: int
-    mixers: Tuple[str, ...]  # per layer: "full" | "window" (attention) | "conv" (gated short convolution)
+    mixers: Tuple[str, ...]  # per layer: "full" | "window" (attention) | "latent" (attention over a compressed cache) | "conv" (gated short convolution)
     rope_layout: Tuple[int, ...]  # per attention layer: 1 = rotary embedding, 0 = no positional encoding
     rope_theta: float = 1.5e6
     rms_norm_eps: float = 1e-6
@@ -86,6 +102,13 @@ class DecoderConfig:
     router_reads: str = "input"  # "input": the layer's input | "ffn_norm": the normed state the experts read
     activation: str = "relu"  # the gate of the feed-forward: "relu" (ReGLU) | "silu" (SwiGLU)
     tie_embeddings: bool = False  # the head is the embedding table
+    # a "latent" layer: what it compresses keys and values to, a head's part without and with rotation, a head's values
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    shared_width: int = 0  # of the dense feed-forward that every token passes beside its routed experts (0: none)
+    routed_scale: float = 1.0  # on the routed experts' weights after their renormalisation; the shared part is not scaled
 
     @classmethod
     def from_cfg(cls, d: Any) -> "DecoderConfig":
@@ -122,6 +145,12 @@ class DecoderConfig:
             router_reads=str(d["router_reads"]),
             activation=str(d["activation"]),
             tie_embeddings=bool(d["tie_embeddings"]),
+            kv_lora_rank=int(d["kv_lora_rank"]),
+            qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+            v_head_dim=int(d["v_head_dim"]),
+            shared_width=int(d["n_shared_experts"]) * int(d["moe_ffn_hidden_size"]),
+            routed_scale=float(d["routed_scaling_factor"]),
         )
 
     def slots(self, layer: int) -> int:
@@ -138,9 +167,15 @@ class DecoderConfig:
         width = self.kv_heads_held * self.head_dim
         return width // LANES if self.head_dim < LANES and LANES % self.head_dim == 0 and width % LANES == 0 else 1
 
+    @property
+    def latent_width(self) -> int:
+        """What a slot of a latent layer's cache holds: the normed latent, then the rotated
+        key that the heads share, then zeros up to whole ``LANES``."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // LANES) * LANES
+
 
 #: the published configs' names for a layer's mixer -> ``DecoderConfig.mixers``
-LAYER_TYPES = {"conv": "conv", "full_attention": "full", "sliding_attention": "window"}
+LAYER_TYPES = {"conv": "conv", "full_attention": "full", "sliding_attention": "window", "latent_attention": "latent"}
 ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 ROUTER_EPS = 1e-6  # in the denominator of the sigmoid router's renormalisation
 #: the minor axis of the chip's memory tiles: a cache whose rows are narrower is given another
@@ -171,7 +206,7 @@ def route(
     experts, in float32 (a tie flipped by rounding sends a token elsewhere).  Without
     ``expert_bias`` the most probable of a softmax; with it (``[E]``, no trained weight)
     sigmoid scores, the ``k`` largest of score + bias chosen and weighted by the score
-    alone.  Third: ``[N]``, whether the bias changed a token's chosen set (``None``
+    alone (renormalised: over their sum + ``ROUTER_EPS``).  Third: ``[N]``, whether the bias changed a token's chosen set (``None``
     without a bias)."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
     if expert_bias is None:
@@ -294,6 +329,16 @@ def lane_grouped_attention(q, k, v, cache, cache_seg, q_pos, q_seg, window, mesh
     return jnp.stack(outs, 2).reshape(B, T, Hq, hd), visited
 
 
+def _blocks_visited(visited, layer: int) -> Dict[str, jax.Array]:
+    """The counters of a layer whose chunk went blockwise through its cache (none where
+    ``grouped_attention`` says nothing of blocks), and the call's facts noted under the layer."""
+    if visited is None:
+        return {}
+    flags, how = visited
+    note("blockwise_attention", {f"layer_{layer}": how})
+    return {"key_blocks": jnp.float32(flags.size), "key_blocks_visited": flags.sum().astype(jnp.float32)}
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     layer: int
@@ -319,7 +364,8 @@ class DecoderLayer(nn.Module):
             w_router = self.param("router", init, (D, c.num_experts))
             bias = self.param("expert_bias", nn.initializers.zeros, (c.num_experts,)) if c.router == "sigmoid" else None
             with scope("policy/router"):
-                return route(r, w_router, c.experts_per_token, c.norm_topk_prob, bias)
+                top_w, top_i, moved = route(r, w_router, c.experts_per_token, c.norm_topk_prob, bias)
+                return top_w * c.routed_scale, top_i, moved
 
         if not dense and c.router_reads == "input":
             top_w, top_i, moved = routed(x.reshape(B * T, D))
@@ -335,6 +381,32 @@ class DecoderLayer(nn.Module):
                 y = (gate_out.astype(jnp.float32) * causal_taps(z, state["conv"], conv_kernel, q_seg)).astype(dt)
                 h = x + _dot(y, conv_out.astype(dt), preferred_element_type=jnp.float32)
             made = {"conv": z}
+        elif kind == "latent":
+            r, dn, dr, dv = c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+            attn_norm = self.param("attn_norm", nn.initializers.ones, (D,))
+            wq = self.param("wq", init, (D, Hq * (dn + dr)))
+            wkv_a = self.param("wkv_a", init, (D, r + dr))
+            kv_norm = self.param("kv_norm", nn.initializers.ones, (r,))
+            wkv_b = self.param("wkv_b", init, (r, Hq * (dn + dv)))
+            wo = self.param("wo", init, (Hq * dv, D))
+            with scope("policy/attention_latent"):
+                a = rms_norm(x, attn_norm, c.rms_norm_eps).astype(dt)
+                q_nope, q_pe = jnp.split(_dot(a, wq.astype(dt)).reshape(B, T, Hq, dn + dr), [dn], -1)
+                down = _dot(a, wkv_a.astype(dt))
+                latent = rms_norm(down[..., :r], kv_norm, c.rms_norm_eps).astype(dt)[:, :, None]  # [B, T, 1, r]
+                q_pe, k_pe = rope(q_pe, q_pos, c.rope_theta), rope(down[:, :, None, r:], q_pos, c.rope_theta)
+                # the up-projection is folded into the queries and the output: the cache is attended as it is kept
+                w_uk, w_uv = jnp.split(wkv_b.astype(dt).reshape(r, Hq, dn + dv), [dn], -1)
+                q_latent = jnp.einsum("bthd,rhd->bthr", q_nope, w_uk, precision=_precision(q_nope)).astype(dt)
+                lanes = lambda *parts: jnp.concatenate([*parts, jnp.zeros((*parts[0].shape[:3], c.latent_width - r - dr), dt)], -1)  # noqa: E731
+                own = lanes(latent, k_pe)  # [B, T, 1, latent_width]: one key head, whose values are its first r columns
+                kept = state["latent"].astype(dt)[:, :, None]  # [B, slots, 1, latent_width]: keys and values at once
+                held = (kept, kept, state["pos"], jnp.where(state["pos"] >= 0, 0, -1))
+                o, visited = grouped_attention(lanes(q_latent, q_pe), own, latent, held, q_pos, q_seg, None, dn + dr, self.mesh)
+                counters = _blocks_visited(visited, self.layer)
+                o = jnp.einsum("bthr,rhd->bthd", o, w_uv, precision=_precision(o)).astype(dt)
+                h = x + _dot(o.reshape(B, T, Hq * dv), wo.astype(dt), preferred_element_type=jnp.float32)
+            made = {"latent": own[:, :, 0]}
         else:
             attn_norm = self.param("attn_norm", nn.initializers.ones, (D,))
             wq = self.param("wq", init, (D, Hq * hd))
@@ -360,10 +432,7 @@ class DecoderLayer(nn.Module):
                 else:
                     held = (state["k"].astype(dt), state["v"].astype(dt), state["pos"], cache_seg)
                     o, visited = grouped_attention(q, k, v, held, q_pos, q_seg, window, mesh=self.mesh)
-                if visited is not None:  # the chunk went blockwise through the cache
-                    flags, how = visited
-                    counters = {"key_blocks": jnp.float32(flags.size), "key_blocks_visited": flags.sum().astype(jnp.float32)}
-                    note("blockwise_attention", {f"layer_{self.layer}": how})
+                counters = _blocks_visited(visited, self.layer)
                 h = x + jnp.dot(o.reshape(B, T, Hq * hd), wo.astype(dt), preferred_element_type=jnp.float32)
             made = {"k": k, "v": v}
         ffn_norm = self.param("ffn_norm", nn.initializers.ones, (D,))
@@ -388,6 +457,14 @@ class DecoderLayer(nn.Module):
         counters = {**counters, **routing}
         if moved is not None:
             counters["bias_moved"] = moved.sum().astype(jnp.float32)
+        if c.shared_width:  # every token's, whatever it was routed to; every chip of a group computes it alike
+            shared_gate = self.param("shared_gate", init, (D, c.shared_width))
+            shared_up = self.param("shared_up", init, (D, c.shared_width))
+            shared_down = self.param("shared_down", init, (c.shared_width, D))
+            with scope("policy/shared_expert"):
+                ms = m.astype(dt)
+                g = act(_dot(ms, shared_gate.astype(dt))) * _dot(ms, shared_up.astype(dt))
+                y = y + _dot(g, shared_down.astype(dt), preferred_element_type=jnp.float32)
         return h + y.reshape(B, T, D), made, counters
 
 
@@ -464,14 +541,14 @@ class DecoderPolicy(nn.Module):
                 layers.append({"conv": jnp.concatenate([old["conv"][:, 1:], made["conv"].astype(old["conv"].dtype)], 1)})
                 continue
             slot = pos % old["pos"].shape[1]
-            k, v = (_into_slot(old[name], made[name][:, 0], rows, slot) for name in ("k", "v"))
-            layers.append({"k": k, "v": v, "pos": old["pos"].at[rows, slot].set(pos)})
+            kept = {name: _into_slot(old[name], new[:, 0], rows, slot) for name, new in made.items()}  # keys and values, or the latent
+            layers.append({**kept, "pos": old["pos"].at[rows, slot].set(pos)})
         return [self.logits(hidden[:, 0])], values, {"pos": pos + 1, "layers": tuple(layers)}
 
 
 def _into_slot(cache, new: jax.Array, rows: jax.Array, slot: jax.Array):
-    """A step's keys (or values) ``[B, Hkv, hd]`` written into their rows' slots of a cache
-    kept as one array or a lane-full of heads an array."""
+    """A step's keys (or values) ``[B, Hkv, hd]``, or its latents ``[B, width]``, written into
+    their rows' slots of a cache kept as one array or a lane-full of heads an array."""
     if isinstance(cache, tuple):
         new = new.reshape(len(rows), len(cache), 1, -1)
         return tuple(c.at[rows, slot].set(new[:, j].astype(c.dtype)) for j, c in enumerate(cache))
@@ -500,6 +577,9 @@ def zero_state(sizes: DecoderConfig, n: int, dtype: Any) -> Dict[str, Any]:
         if sizes.mixers[i] == "conv":
             layers.append({"conv": jnp.zeros((n, sizes.conv_taps - 1, sizes.hidden_size), dtype)})
             continue
+        if sizes.mixers[i] == "latent":
+            layers.append({"latent": jnp.zeros((n, sizes.capacity, sizes.latent_width), dtype), "pos": jnp.full((n, sizes.capacity), -1, jnp.int32)})
+            continue
         shape = (n, sizes.slots(i), sizes.kv_heads_held, sizes.head_dim)  # a buffer each: the acting step is given them to overwrite
         if sizes.lane_groups > 1:
             kv = lambda: tuple(jnp.zeros((*shape[:2], 1, LANES), dtype) for _ in range(sizes.lane_groups))  # noqa: E731
@@ -510,16 +590,20 @@ def zero_state(sizes: DecoderConfig, n: int, dtype: Any) -> Dict[str, Any]:
 
 
 def carry_kinds(state: Dict[str, Any]) -> Dict[str, Dict[str, int]]:
-    """How many layers of a carry hold a cache and how many a convolution tail, with their bytes."""
-    out = {"cache": {"layers": 0, "bytes": 0}, "conv": {"layers": 0, "bytes": 0}}
+    """How many layers of a carry hold a cache of keys and values, how many a convolution
+    tail and how many a latent cache, with their bytes."""
+    out = {"cache": {"layers": 0, "bytes": 0}, "conv": {"layers": 0, "bytes": 0}, "latent": {"layers": 0, "bytes": 0}}
     for layer_state in state["layers"]:
-        kind = out["conv" if "conv" in layer_state else "cache"]
+        kind = out[next((name for name in ("conv", "latent") if name in layer_state), "cache")]
         kind["layers"] += 1
         kind["bytes"] += sum(x.nbytes for x in jax.tree.leaves(layer_state))
     return out
 
 
-MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head", "conv_in", "conv_out", "dense_gate", "dense_up", "dense_down")
+MATMUL_WEIGHTS = (
+    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head", "conv_in", "conv_out", "dense_gate", "dense_up", "dense_down",
+    "wkv_a", "wkv_b", "shared_gate", "shared_up", "shared_down",
+)  # fmt: skip
 #: leaves that are no trained weight: no gradient reaches them and the optimizer leaves them as they are
 BUFFERS = ("expert_bias",)
 

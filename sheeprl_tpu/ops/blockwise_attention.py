@@ -51,6 +51,8 @@ from jax.sharding import PartitionSpec as P
 KEY_BLOCK = 512
 #: the most query rows (tokens x query heads of one key head) a grid step holds
 QUERY_ROWS = 1024
+#: the VMEM a kernel is granted without asking, less some room, and the most it asks for (a v5e has 128 MiB)
+VMEM_GRANTED, VMEM_MOST = 14 << 20, 96 << 20
 #: a masked score: finite, so that a row that has seen no key yet subtracts it from itself
 MASKED = float(jnp.finfo(jnp.float32).min)
 _CONTRACT_LAST = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]
@@ -76,15 +78,16 @@ class Visited(NamedTuple):
     how: dict  # the call's static facts, for the program's note: query tile, key block, where the chunk's own keys join
 
 
-def tiles(rows: int, slots: int, head_dim: int) -> Optional[Tuple[int, int]]:
+def tiles(rows: int, slots: int, head_dim: int, value_dim: Optional[int] = None) -> Optional[Tuple[int, int]]:
     """``(query tile, key block)`` for ``rows`` query rows a key head over a cache of
-    ``slots`` keys ``head_dim`` wide, or ``None`` where the kernel does not take the
-    shape: on the chip a block's minor dimensions are whole (8, 128) tiles."""
+    ``slots`` keys ``head_dim`` wide with values ``value_dim`` wide (the keys' width unless
+    given), or ``None`` where the kernel does not take the shape: on the chip a block's
+    minor dimensions are whole (8, 128) tiles."""
     key_block = KEY_BLOCK if slots % KEY_BLOCK == 0 else slots
     query_tile = next((t for t in range(min(rows, QUERY_ROWS), 0, -1) if rows % t == 0 and (t == rows or t % 16 == 0)), None)
     ok = key_block <= 2 * KEY_BLOCK and query_tile is not None
     if not _interpret():
-        ok = ok and head_dim % 128 == 0 and key_block % 128 == 0 and query_tile % 8 == 0
+        ok = ok and head_dim % 128 == 0 and (value_dim or head_dim) % 128 == 0 and key_block % 128 == 0 and query_tile % 8 == 0
     return (query_tile, key_block) if ok else None
 
 
@@ -168,12 +171,25 @@ def _bwd_kernel(flags_ref, hold_ref, q_ref, k_ref, v_ref, qpos_ref, qseg_ref, kp
         dq_ref[...] = acc_scr[...] * scale
 
 
-def _call(kernel, flags, args, out_widths, scratch_widths, tile, scale, window, mesh):
+def _vmem_limit(query_tile: int, key_block: int, D: int, Dv: int, itemsize: int) -> Optional[int]:
+    """The VMEM to ask the compiler for, or ``None`` where a grid step fits what it grants
+    unasked (``VMEM_GRANTED``): the step's blocks twice (they are double-buffered), its
+    float32 output and accumulator, and some five score-sized float32 temporaries."""
+    wide = max(D, Dv)
+    blocks = itemsize * (query_tile * (D + Dv) + key_block * (D + Dv))
+    step = 2 * blocks + 3 * 4 * query_tile * wide + 5 * 4 * query_tile * key_block
+    return None if step <= VMEM_GRANTED else min(2 * step, VMEM_MOST)
+
+
+def _call(kernel, flags, args, Dv, out_widths, scratch_widths, tile, scale, window, mesh):
     """One of the two kernels over the grid (row, key head, query tile, key block).
-    ``args``: ``q [B, H, R, D]``, the cache's ``k``, ``v`` ``[B, slots, H * D]``, ``q_pos``,
-    ``q_seg`` ``[B, R, 1]``, ``kv_pos``, ``kv_seg`` ``[B, 1, slots]``, then any more arrays
-    shaped by the queries (``[B, H, R, width]``).  Outputs (``[B, H, R, width]``) and
-    scratch (``[query tile, width]``) are float32, one of each width given."""
+    ``args``: ``q [B, H, R, D]``, the cache's ``k`` ``[B, slots, H * D]`` and ``v``, of which a
+    key head's values are column block ``h`` of width ``Dv`` (``[B, slots, H * Dv]``, or, for
+    one key head, any array at least ``Dv`` wide: the keys' own, where the values are the
+    keys' first columns), ``q_pos``, ``q_seg`` ``[B, R, 1]``, ``kv_pos``, ``kv_seg`` ``[B, 1,
+    slots]``, then any more arrays shaped by the queries (``[B, H, R, width]``).  Outputs
+    (``[B, H, R, width]``) and scratch (``[query tile, width]``) are float32, one of each
+    width given."""
     B, H, R, D = args[0].shape
     n_blocks = flags.shape[1]
     query_tile, key_block = tile
@@ -181,9 +197,12 @@ def _call(kernel, flags, args, out_widths, scratch_widths, tile, scale, window, 
     def rows(width):  # a block of an array shaped by the queries
         return pl.BlockSpec((None, None, query_tile, width), lambda b, h, i, j, *_: (b, h, i, 0))
 
-    cache = pl.BlockSpec((None, key_block, D), lambda b, h, i, j, flags, hold: (b, hold[b * n_blocks + j], h))
+    def cache(width):  # a key head's block of the cache: keys, or values
+        return pl.BlockSpec((None, key_block, width), lambda b, h, i, j, flags, hold: (b, hold[b * n_blocks + j], h))
+
     q_id = pl.BlockSpec((None, query_tile, 1), lambda b, h, i, j, *_: (b, i, 0))
     kv_id = pl.BlockSpec((None, 1, key_block), lambda b, h, i, j, flags, hold: (b, 0, hold[b * n_blocks + j]))
+    limit = _vmem_limit(query_tile, key_block, D, Dv, args[0].dtype.itemsize)
 
     def run(flags, *args):
         B = args[0].shape[0]  # a shard's rows under ``shard_map``
@@ -192,12 +211,12 @@ def _call(kernel, flags, args, out_widths, scratch_widths, tile, scale, window, 
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, H, R // query_tile, n_blocks),
-                in_specs=[rows(D), cache, cache, q_id, q_id, kv_id, kv_id] + [rows(a.shape[-1]) for a in args[7:]],
+                in_specs=[rows(D), cache(D), cache(Dv), q_id, q_id, kv_id, kv_id] + [rows(a.shape[-1]) for a in args[7:]],
                 out_specs=[rows(w) for w in out_widths],
                 scratch_shapes=[pltpu.VMEM((query_tile, w), jnp.float32) for w in scratch_widths],
             ),
             out_shape=[jax.ShapeDtypeStruct((B, H, R, w), jnp.float32) for w in out_widths],
-            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"), **({"vmem_limit_bytes": limit} if limit else {})),
             interpret=_interpret(),
         )(flags.reshape(-1), _block_to_hold(flags).reshape(-1), *args)
 
@@ -254,26 +273,29 @@ def _to_rows(x):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11, 12, 13))
 def cache_and_own_attention(q, k, v, cache_k, cache_v, flags, q_pos, q_seg, kv_pos, kv_seg, scale, window, tile, mesh):
-    """Attention of ``q`` ``[B, Tq, Hq, D]`` over a cache (``cache_k``, ``cache_v``: ``[B,
-    slots, Hkv, D]`` with ``kv_pos``, ``kv_seg`` ``[B, slots]``) and the chunk's own ``k``,
-    ``v`` ``[B, Tq, Hkv, D]`` (at ``q_pos``, ``q_seg``), one softmax over both, in
-    ``q.dtype``.  ``flags``: ``key_block_flags``; ``tile``: ``tiles``'s pair.  Differentiable in
-    ``q``, ``k`` and ``v``; the cache takes no gradient."""
+    """Attention of ``q`` ``[B, Tq, Hq, D]`` over a cache (``cache_k``: ``[B, slots, Hkv, D]``,
+    ``cache_v``: ``[B, slots, Hkv, Dv]`` with ``kv_pos``, ``kv_seg`` ``[B, slots]``) and the
+    chunk's own ``k`` ``[B, Tq, Hkv, D]`` and ``v`` ``[B, Tq, Hkv, Dv]`` (at ``q_pos``,
+    ``q_seg``), one softmax over both -> ``[B, Tq, Hq, Dv]`` in ``q.dtype``.  The values may
+    be narrower than the keys, and those of a cache with one key head may be the first
+    ``Dv`` columns of a wider array, ``cache_k`` itself too (a latent that is key and value
+    at once is then stored once).  ``flags``: ``key_block_flags``; ``tile``: ``tiles``'s pair.
+    Differentiable in ``q``, ``k`` and ``v``; the cache takes no gradient."""
     return _attention_fwd(q, k, v, cache_k, cache_v, flags, q_pos, q_seg, kv_pos, kv_seg, scale, window, tile, mesh)[0]
 
 
 def _attention_fwd(q, k, v, cache_k, cache_v, flags, q_pos, q_seg, kv_pos, kv_seg, scale, window, tile, mesh):
     Tq, Hkv = q.shape[1], k.shape[2]
     held = _cache_and_ids(cache_k, cache_v, q_pos, q_seg, kv_pos, kv_seg, q.shape[2] // Hkv)
-    D = q.shape[3]
-    o_c, lse_c = _call(_fwd_kernel, flags, (_by_key_head(q, Hkv), *held), (D, 1), (1, 1, D), tile, scale, window, mesh)
+    Dv = v.shape[3]
+    o_c, lse_c = _call(_fwd_kernel, flags, (_by_key_head(q, Hkv), *held), Dv, (Dv, 1), (1, 1, Dv), tile, scale, window, mesh)
     s, mask, _ = _own_part(q, k, q_pos, q_seg, scale, window)
     lse_c = _rows_of(lse_c, Tq)
     lse = jnp.logaddexp(lse_c, jax.nn.logsumexp(s, -1, keepdims=True))  # [B, Hkv, G, Tq, 1]; MASKED (about) where no key is seen
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     own = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v, precision=_precision(v.dtype), preferred_element_type=jnp.float32)
     share_c = _to_rows(jnp.exp(lse_c - lse))  # the cache's share of the softmax (its part is zeros where it is seen by none)
-    out = _by_token(_by_key_head(own.reshape(q.shape), Hkv) + share_c * o_c, Tq)  # float32
+    out = _by_token(_by_key_head(own.reshape(*q.shape[:3], Dv), Hkv) + share_c * o_c, Tq)  # float32
     return out.astype(q.dtype), (q, k, v, cache_k, cache_v, flags, q_pos, q_seg, kv_pos, kv_seg, out, lse)
 
 
@@ -283,10 +305,10 @@ def _attention_bwd(scale, window, tile, mesh, residuals, do):
     held = _cache_and_ids(cache_k, cache_v, q_pos, q_seg, kv_pos, kv_seg, q.shape[2] // Hkv)
     delta = _by_key_head((do.astype(jnp.float32) * out).sum(-1, keepdims=True), Hkv)  # [B, Hkv, R, 1]
     per_row = (_by_key_head(do, Hkv), _to_rows(lse), delta)
-    (dq_c,) = _call(_bwd_kernel, flags, (_by_key_head(q, Hkv), *held, *per_row), (q.shape[3],), (q.shape[3],), tile, scale, window, mesh)
+    (dq_c,) = _call(_bwd_kernel, flags, (_by_key_head(q, Hkv), *held, *per_row), v.shape[3], (q.shape[3],), (q.shape[3],), tile, scale, window, mesh)
     s, mask, qg = _own_part(q, k, q_pos, q_seg, scale, window)
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-    dog = do.reshape(qg.shape)
+    dog = do.reshape(*qg.shape[:4], v.shape[3])
     full = jax.lax.Precision.HIGHEST  # a float32 operand (dS) enters these products whole
     dp = jnp.einsum("bqhgd,bkhd->bhgqk", dog, v, precision=_precision(v.dtype), preferred_element_type=jnp.float32)
     ds = p * (dp - _rows_of(delta, Tq)) * scale

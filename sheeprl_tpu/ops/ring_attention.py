@@ -171,18 +171,21 @@ def grouped_attention(q, k, v, cache, q_pos, q_seg, window=None, head_dim=None, 
     """Grouped-query attention of a chunk's queries over a carried cache, then the chunk's
     own keys, masked by absolute position, one softmax over both.
 
-    ``q``: ``[B, Tq, Hq, D]``; ``k``, ``v``: the chunk's own ``[B, Tq, Hkv, D]`` with ``Hq`` a
-    multiple of ``Hkv`` (query head ``h`` reads key head ``h // (Hq // Hkv)``); ``cache``:
-    ``(k, v, kv_pos, kv_seg)`` with ``k, v``: ``[B, slots, Hkv, D]``; ``q_pos`` / ``kv_pos``:
-    ``[B, Tq]`` / ``[B, slots]`` positions inside the episode; ``q_seg`` / ``kv_seg``: int
-    segments (a key of another segment, e.g. an empty cache slot given ``-1``, is never
-    visible).  A key is visible iff it is of the query's segment, not after it, and, with
-    ``window``, fewer than ``window`` positions before it.  The cache is read where it lies;
-    scores and softmax are float32, the scores over ``sqrt(head_dim)`` (``D`` unless given:
-    queries padded with zeros to a wider ``D`` keep their own).  Returns ``[B, Tq, Hq, D]`` in
-    ``q.dtype`` (a query that sees no key returns zeros) and what a blockwise call visited.
-    The cache is an input, the carry as it stood: it takes no gradient, whichever way the
-    call goes (``q`` and the chunk's own ``k``, ``v`` do).
+    ``q``: ``[B, Tq, Hq, D]``; ``k``, ``v``: the chunk's own ``[B, Tq, Hkv, D]`` and ``[B, Tq,
+    Hkv, Dv]`` with ``Hq`` a multiple of ``Hkv`` (query head ``h`` reads key head ``h // (Hq //
+    Hkv)``) and ``Dv`` the keys' width or less; ``cache``: ``(k, v, kv_pos, kv_seg)`` with ``k``:
+    ``[B, slots, Hkv, D]`` and ``v``: ``[B, slots, Hkv, Dv]``, or, with one key head, any array
+    whose first ``Dv`` columns are the values: ``k`` itself, for a latent that is key and
+    value at once and is stored once; ``q_pos`` / ``kv_pos``: ``[B, Tq]`` / ``[B, slots]``
+    positions inside the episode; ``q_seg`` / ``kv_seg``: int segments (a key of another
+    segment, e.g. an empty cache slot given ``-1``, is never visible).  A key is visible iff
+    it is of the query's segment, not after it, and, with ``window``, fewer than ``window``
+    positions before it.  The cache is read where it lies; scores and softmax are float32,
+    the scores over ``sqrt(head_dim)`` (``D`` unless given: queries padded with zeros to a
+    wider ``D`` keep their own).  Returns ``[B, Tq, Hq, Dv]`` in ``q.dtype`` (a query that sees
+    no key returns zeros) and what a blockwise call visited.  The cache is an input, the
+    carry as it stood: it takes no gradient, whichever way the call goes (``q`` and the
+    chunk's own ``k``, ``v`` do).
 
     By the shape of the call: one query a row (an acting step, whose scores are small and
     whose time is the cache's read) forms the scores whole, ``_grouped_attention``, and
@@ -195,7 +198,9 @@ def grouped_attention(q, k, v, cache, q_pos, q_seg, window=None, head_dim=None, 
     several."""
     B, Tq, Hq, D = q.shape
     ck, cv, kv_pos, kv_seg = cache
-    tile = blockwise_attention.tiles(Tq * Hq // k.shape[2], kv_pos.shape[1], D) if Tq > 1 else None
+    if cv.shape[-1] != v.shape[-1] and k.shape[2] != 1:
+        raise ValueError(f"values that are the first columns of a wider array need one key head: {cv.shape} for values {v.shape}")
+    tile = blockwise_attention.tiles(Tq * Hq // k.shape[2], kv_pos.shape[1], D, v.shape[-1]) if Tq > 1 else None
     if tile is None:
         if Tq > 1 and jax.default_backend() == "tpu":
             _log.warning("grouped_attention: a chunk %s forms its float32 scores whole: the blockwise kernel does not take %d slots of width %d", q.shape[:3], kv_pos.shape[1], D)  # fmt: skip
@@ -210,7 +215,9 @@ def grouped_attention(q, k, v, cache, q_pos, q_seg, window=None, head_dim=None, 
 def _grouped_attention(q, blocks, q_pos, q_seg, window, head_dim) -> jax.Array:
     """``grouped_attention`` with the scores of every block of keys (``(k, v, kv_pos, kv_seg)``
     each) formed whole in float32 and one softmax over them all: the acting path, and
-    the oracle of the blockwise one."""
+    the oracle of the blockwise one.  The values are as wide as the narrowest block's; a
+    block handed a wider array (the keys' own) gives its first columns, taken after the
+    product: the array is read as it lies, and a step's output is small."""
     B, Tq, Hq, D = q.shape
     Hkv = blocks[0][0].shape[2]
     qg = q.reshape(B, Tq, Hkv, Hq // Hkv, D)
@@ -222,9 +229,11 @@ def _grouped_attention(q, blocks, q_pos, q_seg, window, head_dim) -> jax.Array:
     mask = jnp.concatenate(masks, -1)
     s = jnp.where(mask, jnp.concatenate(scores, -1), jnp.finfo(jnp.float32).min)
     p = jnp.where(mask, jax.nn.softmax(s, -1), 0.0)
+    Dv = min(v.shape[-1] for _, v, _, _ in blocks)  # a block whose values are the first columns of a wider array is multiplied whole
     out, at = 0.0, 0
     for _, v, kv_pos, _ in blocks:
         n = kv_pos.shape[1]
-        out = out + jnp.einsum("bhgqk,bkhd->bqhgd", p[..., at : at + n].astype(v.dtype), v, preferred_element_type=jnp.float32)
+        part = jnp.einsum("bhgqk,bkhd->bqhgd", p[..., at : at + n].astype(v.dtype), v, preferred_element_type=jnp.float32)
+        out = out + (part if v.shape[-1] == Dv else part[..., :Dv])
         at += n
-    return out.reshape(B, Tq, Hq, D).astype(q.dtype)
+    return out.reshape(B, Tq, Hq, Dv).astype(q.dtype)

@@ -281,6 +281,87 @@ def test_in_bfloat16_the_blockwise_program_is_as_near_the_float32_one_as_the_who
         assert ratio.max() >= 3 * AS_NEAR, ratio
 
 
+# ---- a key wider than its value: a latent cache that is key and value at once, stored once ----
+
+
+def latent_inputs(rng, fills, firsts, T, Hq, Hkv, D, Dv, slots, dtype, shared):
+    """As ``inputs``, with values ``Dv`` wide under keys ``D`` wide.  ``shared``: the cache is
+    one array (one key head) whose first ``Dv`` columns are the values, handed in as both."""
+    (q, k, _), (ck, _, kv_pos, kv_seg), q_pos, q_seg = inputs(rng, fills, firsts, T, Hq, Hkv, D, slots, dtype)
+    v = jnp.asarray(rng.standard_normal((len(fills), T, Hkv, Dv)), dtype)
+    cv = ck if shared else jnp.asarray(rng.standard_normal((len(fills), slots, Hkv, Dv)), dtype)
+    return (q, k, v), (ck, cv, kv_pos, kv_seg), q_pos, q_seg
+
+
+def sliced(cache, Dv):
+    """The cache with its values cut out as an array of their own: what the whole-scores oracle is given."""
+    return (cache[0], cache[1][..., :Dv], *cache[2:])
+
+
+# (fills, episode starts, T, Hq, Hkv, D, Dv, slots, the values are the keys' first columns, the head's width that the scores are over the root of)
+LATENT_CASES = {
+    "sixteen_heads_on_a_latent_five_lanes_wide": ((0, 5, 20, 32), (), 4, 16, 1, 640, 512, 32, True, 576),
+    "an_episode_that_starts_inside_the_chunk": ((9, 9, 20), ((0, 3), (1, 0), (2, 7)), 8, 4, 1, 24, 16, 32, True, 12),
+    "values_that_do_not_divide_the_keys_width": ((3, 17, 32), ((1, 2),), 6, 4, 1, 128, 96, 32, True, 100),
+    "narrower_values_in_an_array_of_their_own": ((0, 12, 30), ((2, 5),), 8, 4, 2, 16, 8, 32, False, None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(LATENT_CASES))
+def test_a_key_wider_than_its_value_gives_the_whole_scores_outputs_and_gradients(case, dtype):
+    """The kernel pair with ``D != Dv``, the cache's values a column block of its keys: outputs
+    ``[.., Dv]`` and the gradients of the queries and of the chunk's own keys and values
+    against the whole-scores program over the values cut out as an array of their own."""
+    fills, firsts, T, Hq, Hkv, D, Dv, slots, shared, hd = LATENT_CASES[case]
+    rng = np.random.default_rng(0)
+    qkv, cache, q_pos, q_seg = latent_inputs(rng, fills, firsts, T, Hq, Hkv, D, Dv, slots, dtype, shared)
+    weights = jnp.asarray(rng.standard_normal((*qkv[0].shape[:3], Dv)), jnp.float32)
+    blockwise = lambda qkv: ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, None, hd)  # noqa: E731
+    out, visited = blockwise(qkv)
+    assert visited is not None and out.shape == (len(fills), T, Hq, Dv) and (cache[1] is cache[0]) == shared
+    got = out_and_grads(lambda qkv: blockwise(qkv)[0], qkv, weights)
+    want = out_and_grads(lambda qkv: whole(qkv, sliced(cache, Dv), q_pos, q_seg, None, hd), qkv, weights)
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 else dict(rtol=3e-2, atol=3e-2)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape and np.isfinite(np.asarray(a, np.float32)).all(), name
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("fault", [None, first_visited_block_unflagged], ids=["as_it_is", "first_visited_block_unflagged"])
+@pytest.mark.parametrize("case", [c for c in LATENT_CASES if LATENT_CASES[c][8]])
+def test_in_bfloat16_the_latent_call_is_as_near_the_float32_program_as_the_whole_scores_one(case, fault, monkeypatch):
+    """The file's own measure (``AS_NEAR``) for the call with shared storage: as near as the
+    whole-scores program as it is, three times past it with a visited block unflagged."""
+    fills, firsts, T, Hq, Hkv, D, Dv, slots, _, hd = LATENT_CASES[case]
+    rng = np.random.default_rng(0)
+    qkv, cache, q_pos, q_seg = latent_inputs(rng, fills, firsts, T, Hq, Hkv, D, Dv, slots, jnp.bfloat16, True)
+    weights = jnp.asarray(rng.standard_normal((*qkv[0].shape[:3], Dv)), jnp.float32)
+    exact = out_and_grads(lambda qkv: whole(qkv, float32_of(sliced(cache, Dv)), q_pos, q_seg, None, hd), float32_of(qkv), weights)
+    rounded = out_and_grads(lambda qkv: whole(qkv, sliced(cache, Dv), q_pos, q_seg, None, hd), qkv, weights)
+    if fault:
+        fault(monkeypatch, cache)
+    blockwise = out_and_grads(lambda qkv: ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, None, hd)[0], qkv, weights)
+    far = lambda got: np.array([np.abs(np.asarray(a, np.float32) - np.asarray(b)).mean() for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(exact))])  # noqa: E731
+    ratio = far(blockwise) / far(rounded)
+    assert (ratio <= AS_NEAR).all() if fault is None else ratio.max() >= 3 * AS_NEAR, ratio
+
+
+def test_one_query_a_row_reads_a_shared_latent_as_it_lies_and_keeps_its_first_columns():
+    """The acting step's call: the scores whole, the cache's array multiplied whole as the
+    values and the product's first ``Dv`` columns kept; and several key heads cannot share."""
+    rng = np.random.default_rng(9)
+    qkv, cache, q_pos, q_seg = latent_inputs(rng, (0, 7, 31), ((0, 0),), 1, 4, 1, 24, 16, 32, jnp.float32, True)
+    out, visited = ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, None, 12)
+    assert visited is None and out.shape == (3, 1, 4, 16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole(qkv, sliced(cache, 16), q_pos, q_seg, None, 12)), rtol=1e-6, atol=1e-6)
+    d_cache = jax.grad(lambda c: ring_attention.grouped_attention(*qkv, (c, c, *cache[2:]), q_pos, q_seg, None, 12)[0].sum())(cache[0])
+    assert not np.asarray(d_cache).any()
+    two, held, q_pos2, q_seg2 = latent_inputs(rng, (4, 9), (), 4, 4, 2, 16, 8, 32, jnp.float32, True)
+    with pytest.raises(ValueError, match="one key head"):
+        ring_attention.grouped_attention(*two, held, q_pos2, q_seg2)
+
+
 @pytest.mark.parametrize("slots", [32, 20], ids=["blockwise", "scores_whole"])
 def test_the_cache_takes_no_gradient_whichever_way_a_chunk_goes(slots):
     """The cache is the carry as it stood, an input of the update: the kernel's backward pass
@@ -336,4 +417,35 @@ def test_the_kernels_compile_for_the_chip_at_the_benchmarks_widths(topology, mon
     text = jax.jit(step).lower(shaped((B, T, G, D)), own, own, held, held, shaped((B, slots), jnp.int32), ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     cache_sized = [line for line in text.splitlines() if " copy(" in line and f"bf16[{B // chips},{slots}," in line.split(" copy(")[0]]
+    assert not cache_sized, cache_sized
+
+
+def test_the_kernels_compile_for_the_chip_over_a_latent_cache_at_moonlights_widths(topology, monkeypatch):
+    """Forward and backward for a TPU v5e at ``moonlight16b_1of8.rl_gen32``'s shapes: 32 rows x
+    64 tokens x 16 heads = 1,024 query rows on one key head 640 wide (the 512-wide latent,
+    the 64-wide rotated key, zeros to five lane-fulls) whose values are its first 512
+    columns, 8,192 slots in blocks of 512, scores over ``sqrt(192)``, bfloat16.  The
+    1,024-row tile fits once the kernels ask for their VMEM; the narrower shapes of the
+    other two decoders ask for none, as before; no copy of the cache is made on the way in."""
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(blockwise_attention, "KEY_BLOCK", 512)
+    monkeypatch.setattr(blockwise_attention, "_interpret", lambda: False)
+    B, T, H, D, Dv, slots = 32, 64, 16, 640, 512, 8192
+    assert blockwise_attention.tiles(T * H, slots, D, Dv) == (1024, 512) and blockwise_attention.tiles(T * H, slots, D, 576) is None
+    assert blockwise_attention._vmem_limit(1024, 512, D, Dv, 2) > blockwise_attention.VMEM_GRANTED
+    assert blockwise_attention._vmem_limit(512, 512, 128, 128, 2) is None and blockwise_attention._vmem_limit(1024, 512, 128, 128, 2) is None
+    placed = SingleDeviceSharding(topology.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=placed)  # noqa: E731
+
+    def step(q, k, v, latent, kv_pos, q_pos, q_seg):
+        kept = latent[:, :, None]  # the carry keeps [B, slots, 640]
+        cache = (kept, kept, kv_pos, jnp.where(kv_pos >= 0, 0, -1))
+        loss = lambda q, k, v: ring_attention.grouped_attention(q, k, v, cache, q_pos, q_seg, None, 192)[0].astype(jnp.float32).sum()  # noqa: E731
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    ids = shaped((B, T), jnp.int32)
+    text = jax.jit(step).lower(shaped((B, T, H, D)), shaped((B, T, 1, D)), shaped((B, T, 1, Dv)), shaped((B, slots, D)), shaped((B, slots), jnp.int32), ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    cache_sized = [line for line in text.splitlines() if " copy(" in line and f"bf16[{B},{slots}," in line.split(" copy(")[0]]
     assert not cache_sized, cache_sized

@@ -1,6 +1,7 @@
 """The sparse-expert decoder policy (``sheeprl_tpu/models/decoder.py``) against the
-benchmark's plain references (``perfbench/configs/smallthinker21b_1of4_reference.py`` and
-``perfbench/configs/lfm2_8b_a1b_1of4_reference.py``, which share no code with it or with
+benchmark's plain references (``perfbench/configs/smallthinker21b_1of4_reference.py``,
+``perfbench/configs/lfm2_8b_a1b_1of4_reference.py`` and
+``perfbench/configs/moonlight16b_1of8_reference.py``, which share no code with it or with
 each other) on seeded weights, at a small size on the CPU: window 8, 8 experts, float32."""
 
 import jax
@@ -9,6 +10,7 @@ import numpy as np
 import pytest
 
 from perfbench.configs import lfm2_8b_a1b_1of4_reference as lfm2
+from perfbench.configs import moonlight16b_1of8_reference as moon
 from perfbench.configs import smallthinker21b_1of4_reference as ref
 from sheeprl_tpu.algos.ppo.utils import chunked_log_prob_and_entropy, log_prob_and_entropy
 from sheeprl_tpu.models import decoder
@@ -31,7 +33,26 @@ LFM2 = {
 }  # fmt: skip
 
 
+#: Moonlight's layers (latent attention + dense, latent attention + routed and shared experts), small
+MOON = {
+    "hidden_size": 32, "heads_held": 4, "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8,
+    "num_experts": 8, "experts_held": 8, "expert_offset": 0, "experts_per_token": 2, "expert_width": 16, "shared_width": 32,
+    "routed_scale": 2.446, "dense_width": 48, "dense_layers": 1, "vocab_held": 48, "layers": 3, "rope_theta": 50000.0, "norm_eps": 1e-5,
+    "router_eps": 1e-20, "norm_topk_prob": True, "cache_capacity": 48, "router_scale": 2.0, "branch_scale": 0.25, "bias_scale": 0.05,
+}  # fmt: skip
+
+
 def config_of(S) -> decoder.DecoderConfig:
+    if "kv_lora_rank" in S:  # Moonlight's layer kinds
+        return decoder.DecoderConfig(
+            hidden_size=S["hidden_size"], head_dim=S["qk_nope_head_dim"] + S["qk_rope_head_dim"], heads_held=S["heads_held"], kv_heads_held=S["heads_held"],
+            num_experts=S["num_experts"], experts_held=S["experts_held"], experts_per_token=S["experts_per_token"], expert_width=S["expert_width"],
+            vocab_held=S["vocab_held"], layers=S["layers"], window=0, mixers=("latent",) * S["layers"], rope_layout=(1,) * S["layers"],
+            rope_theta=S["rope_theta"], rms_norm_eps=S["norm_eps"], expert_offset=S["expert_offset"], capacity=S["cache_capacity"],
+            dense_layers=S["dense_layers"], dense_width=S["dense_width"], router="sigmoid", router_reads="ffn_norm", activation="silu",
+            kv_lora_rank=S["kv_lora_rank"], qk_nope_head_dim=S["qk_nope_head_dim"], qk_rope_head_dim=S["qk_rope_head_dim"], v_head_dim=S["v_head_dim"],
+            shared_width=S["shared_width"], routed_scale=S["routed_scale"],
+        )  # fmt: skip
     common = dict(
         hidden_size=S["hidden_size"], head_dim=S["head_dim"], heads_held=S["heads_held"], kv_heads_held=S["kv_heads_held"],
         num_experts=S["num_experts"], experts_held=S["experts_held"], experts_per_token=S["experts_per_token"],
@@ -49,12 +70,14 @@ def config_of(S) -> decoder.DecoderConfig:
 
 
 def model_of(name, monkeypatch=None):
-    """``(reference module, sizes)`` of one of the two published layers at the small size;
+    """``(reference module, sizes)`` of one of the three published models at the small size;
     ``lfm2_lanes``: with four key heads of 8 on a chip whose lanes are 16 wide, so that the
     cache is kept two heads an array (``decoder.lane_grouped_attention``)."""
     if name == "lfm2_lanes":
         monkeypatch.setattr(decoder, "LANES", 16)
         return lfm2, {**LFM2, "heads_held": 8, "kv_heads_held": 4}
+    if name == "moonlight":
+        return moon, MOON
     return (lfm2, LFM2) if name == "lfm2" else (ref, SIZES)
 
 
@@ -74,13 +97,15 @@ def reference_forward(S, weights, n, tokens, prev, is_first, pos, ep, ref=ref):
     return run(weights, tokens, prev, jnp.asarray(is_first), jnp.asarray(pos), jnp.asarray(ep))
 
 
-#: one layer of each kind: SmallThinker's two, and LFM2's three (mixer + feed-forward)
+#: one layer of each kind: SmallThinker's two, LFM2's three and Moonlight's two (mixer + feed-forward)
 ONE_LAYER = {
     "full": (ref, {**SIZES, "layers": 1, "window_layout": [0], "rope_layout": [0]}),
     "window": (ref, {**SIZES, "layers": 1, "window_layout": [1], "rope_layout": [1]}),
     "conv_dense": (lfm2, {**LFM2, "layers": 1, "layer_types": ["conv"], "dense_layers": 1}),
     "conv_experts": (lfm2, {**LFM2, "layers": 1, "layer_types": ["conv"], "dense_layers": 0}),
     "attention_qk_norm_experts": (lfm2, {**LFM2, "layers": 1, "layer_types": ["full_attention"], "dense_layers": 0}),
+    "latent_dense": (moon, {**MOON, "layers": 1, "dense_layers": 1}),
+    "latent_experts_shared": (moon, {**MOON, "layers": 1, "dense_layers": 0}),
 }
 
 
@@ -96,14 +121,14 @@ def test_one_layer_of_each_kind_matches_the_reference(kind):
     np.testing.assert_array_equal(np.asarray(q_pos), pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v), atol=2e-5)
-    if kind == "conv_dense":
-        assert aux == {}  # no router, no counters
+    if kind.endswith("_dense"):
+        assert not [name for name in aux if name.startswith("MoE/")]  # no router, none of its counters
     else:
         assert float(aux["MoE/dropped"]) == 0.0 and float(aux["MoE/held_share"]) == 1.0
-        assert ("MoE/bias_moved_share" in aux) == (ref is lfm2)
+        assert ("MoE/bias_moved_share" in aux) == (ref is not ONE_LAYER["full"][0])
 
 
-@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes"])
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes", "moonlight"])
 def test_acting_through_the_caches_matches_the_full_forward_pass(model, monkeypatch):
     """40 steps a row, one token at a time through the carry (the 8-slot rings wrap four
     times over; episodes end inside, so caches and convolution tails are both emptied),
@@ -126,9 +151,13 @@ def test_acting_through_the_caches_matches_the_full_forward_pass(model, monkeypa
     assert np.asarray(state["pos"]).tolist() == (pos[:, -1] + 1).tolist()
     if model == "lfm2_lanes":  # two arrays of two key heads each, a lane-full wide
         assert [x.shape for x in state["layers"][1]["k"]] == [(n, S["cache_capacity"], 1, 16)] * 2 and cfg.lane_groups == 2
+    if model == "moonlight":  # one array a layer: the latent, the rotated key, zeros to a lane-full; nothing by head
+        assert [sorted(layer) for layer in state["layers"]] == [["latent", "pos"]] * 3 and cfg.latent_width == decoder.LANES
+        assert state["layers"][0]["latent"].shape == (n, S["cache_capacity"], decoder.LANES)
+        assert not np.asarray(state["layers"][0]["latent"][..., S["kv_lora_rank"] + S["qk_rope_head_dim"] :]).any()
 
 
-@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes"])
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes", "moonlight"])
 def test_a_chunk_after_carried_steps_matches_the_full_forward_pass(model, monkeypatch):
     """The update's view: 12 steps through the carry, then a chunk of 10 tokens in one piece
     that reads the carry as a constant, with an episode start inside the chunk (row 0: at
@@ -224,6 +253,157 @@ def test_the_four_expert_shares_add_up_under_a_bias_that_changes_the_choice():
     assert 0 < moved < n * t  # the bias changed some tokens' experts, not all
     assert float(jnp.abs(whole - mixed).max()) > 1e-2
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole - x), atol=2e-5)
+
+
+# ---- Moonlight's layer: latent attention (MLA), a shared expert beside the routed ones, a scaled router ----
+
+
+def carried_and_chunk(S, weights, seed, n=2, carried=12, t=22, firsts=((0, 17), (0, 4))):
+    """Twelve tokens a row through the program's carry, and the same rows in the reference's
+    context; then the ten that follow as one chunk (an episode starts inside row 0's)."""
+    tokens, prev, is_first = sequences(np.random.default_rng(seed), n, t, S["vocab_held"], firsts)
+    ep, pos, _, _ = moon.episodes_and_positions(is_first, np.zeros(n, np.int32), np.zeros(n, np.int32))
+    cfg = config_of(S)
+    policy = decoder.DecoderPolicy(cfg)
+    step = jax.jit(lambda state, tok, prv, first: policy.apply(weights, tok, prv, first, state, method=decoder.DecoderPolicy.step))
+    state = decoder.zero_state(cfg, n, jnp.float32)
+    for i in range(carried):
+        _, _, state = step(state, tokens[:, i], prev[:, i], is_first[:, i : i + 1])
+    a, b = slice(0, carried), slice(carried, t)
+    arrays = lambda c: (tokens[:, c], prev[:, c], jnp.asarray(is_first[:, c]), jnp.asarray(pos[:, c]), jnp.asarray(ep[:, c]))  # noqa: E731
+    context = moon.empty_context(S, n, carried)
+    _, _, made, _ = moon.forward(S, weights, context, *arrays(a))
+    context = moon.append(context, made, jnp.asarray(pos[:, a]), jnp.asarray(ep[:, a]))
+    return policy, state, context, arrays(b), arrays(slice(0, t)), carried
+
+
+ATTENTION_LEAVES = ("wq", "wkv_a", "wkv_b", "wo", "kv_norm", "attn_norm")
+
+
+@pytest.mark.parametrize("feed_forward", ["dense", "experts_shared"])
+def test_the_latent_space_form_is_the_naive_form_with_its_gradients(feed_forward):
+    """The program attends the cache in the latent's space (``W_kv_b`` folded into the queries
+    and the output, one key head 128 lanes wide whose values are its first columns); the
+    reference forms every head's keys and values of every row from the row's latent.
+    Outputs agree to float32 rounding, and so do the gradients of every attention leaf:
+    ``W_kv_b`` takes its gradient through the cached rows too, which themselves are constants."""
+    S = {**MOON, "layers": 1, "dense_layers": 1 if feed_forward == "dense" else 0}
+    weights = moon.make_weights(S, 17)
+    policy, state, context, chunk, _, _ = carried_and_chunk(S, weights, 3)
+    mix = jnp.asarray(np.random.default_rng(5).standard_normal((2, 10, S["hidden_size"])), jnp.float32)
+    program = lambda w: (policy.apply(w, *chunk[:3], state)[0] * mix).sum()  # noqa: E731
+    naive = lambda w: (moon.forward(S, w, context, *chunk)[0] * mix).sum()  # noqa: E731
+    (got, g_got), (want, g_want) = jax.jit(jax.value_and_grad(program))(weights), jax.jit(jax.value_and_grad(naive))(weights)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for name in ATTENTION_LEAVES:
+        a, b = np.asarray(g_got["params"]["layers_0"][name]), np.asarray(g_want["params"]["layers_0"][name])
+        assert np.abs(b).max() > 1e-4, name
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6 * np.abs(b).max() + 1e-7, err_msg=name)
+
+
+def test_the_up_projections_gradient_runs_through_the_cached_rows_and_the_cache_takes_none():
+    """Two layers, twelve carried rows, a chunk of ten.  The reference with the earlier rows'
+    ``c`` and ``k_pe`` as constants (``stop_gradient`` where it reads them) is what the program
+    computes, leaf by leaf.  ``W_kv_b``'s gradient is not what the chunk's own keys alone give
+    (the cached rows are most of what a query sees), and a carry that took a cotangent, the
+    earlier rows recomputed from the weights being trained, gives ``W_kv_a`` another gradient:
+    the program's is not that one."""
+    S = {**MOON, "layers": 2}
+    weights = moon.make_weights(S, 19)
+    policy, state, context, chunk, whole, carried = carried_and_chunk(S, weights, 4)
+    mix = jnp.asarray(np.random.default_rng(6).standard_normal((2, 10, S["hidden_size"])), jnp.float32)
+    program = jax.jit(jax.grad(lambda w: (policy.apply(w, *chunk[:3], state)[0] * mix).sum()))(weights)
+    constant = jax.jit(jax.grad(lambda w: (moon.forward(S, w, context, *chunk)[0] * mix).sum()))(weights)
+    empty = moon.empty_context(S, 2, 0)
+    recomputed = jax.jit(jax.grad(lambda w: (moon.forward(S, w, empty, *whole)[0][:, carried:] * mix).sum()))(weights)
+    cut_off = jax.jit(jax.grad(lambda w: (moon.forward(S, w, jax.tree.map(jnp.zeros_like, context) | {"ep": context["ep"] * 0 - 1}, *chunk)[0] * mix).sum()))(weights)
+    far = lambda a, b: float(np.abs(np.asarray(a) - np.asarray(b)).max() / np.abs(np.asarray(b)).max())  # noqa: E731
+    for layer in ("layers_0", "layers_1"):
+        for name in ATTENTION_LEAVES:
+            assert far(program["params"][layer][name], constant["params"][layer][name]) < 2e-4, (layer, name)
+        assert far(program["params"][layer]["wkv_b"], cut_off["params"][layer]["wkv_b"]) > 0.05  # the cached rows carry gradient to W_kv_b
+    assert far(program["params"]["layers_0"]["wkv_a"], recomputed["params"]["layers_0"]["wkv_a"]) > 0.05  # and take none themselves
+    d_cache = jax.grad(lambda s: (policy.apply(weights, *chunk[:3], s)[0] * mix).sum(), allow_int=True)(state)
+    assert all(not np.asarray(layer["latent"]).any() for layer in d_cache["layers"])
+
+
+def test_the_eight_expert_shares_add_up_with_attention_and_the_shared_expert_counted_once():
+    """Moonlight's expert layer shared by eight chips (one of 8 experts each here; 8 of 64 at
+    the published size): every chip holds the attention and the shared expert whole and
+    computes them alike, so they are counted once, and the eight routed parts add up to the
+    uncut reference's layer, under a selection bias wide enough to change some tokens' experts."""
+    S = {**MOON, "layers": 1, "dense_layers": 0, "bias_scale": 0.1}
+    L = dict(moon.make_weights(S, 3)["params"]["layers_0"])
+    rng = np.random.default_rng(4)
+    n, t = 2, 12
+    x = jnp.asarray(rng.standard_normal((n, t, S["hidden_size"])), jnp.float32)
+    is_first = np.zeros((n, t), np.float32)
+    is_first[:, 0] = 1
+    is_first[1, 7] = 1
+    ep, pos, _, _ = moon.episodes_and_positions(is_first, np.zeros(n, np.int32), np.zeros(n, np.int32))
+    whole, _, _ = jax.jit(lambda L, x: moon.layer(S, 0, L, x, moon.empty_context(S, n, 0), jnp.asarray(pos), jnp.asarray(ep)))(L, x)
+    q_pos, q_seg = decoder.positions(jnp.asarray(is_first), jnp.zeros(n, jnp.int32))
+
+    def share(cut, part):
+        cfg = config_of(cut)
+        cache = decoder.zero_state(cfg, n, jnp.float32)["layers"][0]
+        return jax.jit(decoder.DecoderLayer(cfg, 0).apply)({"params": part}, x, cache, q_pos, q_seg)
+
+    alike, _, _ = share(S, {**L, "w_down": jnp.zeros_like(L["w_down"])})  # x + attention + the shared expert: what every chip computes alike
+    total, moved = alike - x, 0.0
+    for i in range(8):
+        e = slice(i, i + 1)
+        out, _, counters = share({**S, "experts_held": 1, "expert_offset": i}, {**L, "w_gate": L["w_gate"][e], "w_up": L["w_up"][e], "w_down": L["w_down"][e]})
+        total = total + (out - alike)
+        moved = float(counters["bias_moved"])
+        assert float(counters["dropped"]) == 0.0
+    assert 0 < moved < n * t  # the bias changed some tokens' experts, not all
+    assert float(jnp.abs(whole - alike).max()) > 1e-2
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole - x), atol=2e-5)
+    no_shared, _, _ = jax.jit(lambda L, x: moon.layer({**S, "shared_here": False}, 0, L, x, moon.empty_context(S, n, 0), jnp.asarray(pos), jnp.asarray(ep)))(L, x)
+    assert float(jnp.abs(whole - no_shared).max()) > 1e-2  # the shared expert is a part of the layer worth counting
+
+
+def test_the_routed_weights_carry_the_scale_and_the_shared_expert_does_not():
+    S = {**MOON, "layers": 1, "dense_layers": 0}
+    L = dict(moon.make_weights(S, 23)["params"]["layers_0"])
+    n, t = 2, 6
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((n, t, S["hidden_size"])), jnp.float32)
+    q_pos, q_seg = decoder.positions(jnp.zeros((n, t)).at[:, 0].set(1.0), jnp.zeros(n, jnp.int32))
+
+    def out(scale, part):
+        cfg = config_of({**S, "routed_scale": scale})
+        return decoder.DecoderLayer(cfg, 0).apply({"params": part}, x, decoder.zero_state(cfg, n, jnp.float32)["layers"][0], q_pos, q_seg)[0]
+
+    alike = out(2.446, {**L, "w_down": jnp.zeros_like(L["w_down"])})  # attention and the shared expert: the same whatever the scale
+    np.testing.assert_array_equal(np.asarray(alike), np.asarray(out(1.0, {**L, "w_down": jnp.zeros_like(L["w_down"])})))
+    routed, plain = out(2.446, L) - alike, out(1.0, L) - alike
+    assert float(jnp.abs(plain).max()) > 1e-3
+    np.testing.assert_allclose(np.asarray(routed), 2.446 * np.asarray(plain), rtol=1e-4, atol=1e-6)
+    top_w, _, _ = decoder.route(x.reshape(n * t, -1), L["router"], 2, True, L["expert_bias"])
+    np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, rtol=1e-6)  # renormalised before the scale, which the layer applies
+
+
+def test_moonlights_carry_holds_a_third_kind_of_state_and_its_tree_is_the_references():
+    S = {**MOON, "layers": 5}
+    cfg = config_of(S)
+    ids = jnp.zeros((1,), jnp.int32)
+    state = decoder.zero_state(cfg, 3, jnp.bfloat16)
+    assert [sorted(layer) for layer in state["layers"]] == [["latent", "pos"]] * 5
+    assert state["layers"][0]["latent"].shape == (3, 48, 128) and state["layers"][0]["latent"].dtype == jnp.bfloat16
+    kinds = decoder.carry_kinds(state)
+    assert kinds["latent"] == {"layers": 5, "bytes": 5 * 3 * 48 * (128 * 2 + 4)} and kinds["cache"]["layers"] == kinds["conv"]["layers"] == 0
+    emptied = decoder.emptied(jax.tree.map(lambda x: x + 1, state["layers"][0]), jnp.asarray([True, False, False]))
+    assert np.asarray(emptied["pos"])[0].tolist() == [-1] * 48 and np.asarray(emptied["pos"])[1].tolist() == [0] * 48
+    one = decoder.zero_state(cfg, 1, jnp.float32)
+    tree = jax.eval_shape(lambda k: decoder.DecoderPolicy(cfg).init(k, ids, ids, jnp.ones((1, 1)), one, method=decoder.DecoderPolicy.step), jax.random.PRNGKey(0))
+    have = {"/".join(str(k.key) for k in path): tuple(x.shape) for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    assert have == moon.flat_shapes(S)
+    cast = decoder.cast_matmul_weights(moon.make_weights(S, 1), jnp.bfloat16)["params"]
+    narrow = lambda layer: {k for k, v in cast[layer].items() if v.dtype == jnp.bfloat16}  # noqa: E731
+    assert narrow("layers_0") == {"wq", "wkv_a", "wkv_b", "wo", "dense_gate", "dense_up", "dense_down"}
+    assert narrow("layers_1") == {"wq", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down", "shared_gate", "shared_up", "shared_down"}
+    assert cast["head"].dtype == jnp.bfloat16 and cast["embed"].dtype == jnp.float32 and cast["layers_1"]["kv_norm"].dtype == jnp.float32
 
 
 def test_the_choice_follows_score_plus_bias_and_the_weights_follow_the_score():
@@ -328,7 +508,8 @@ def test_smallthinkers_parameter_tree_and_carry_are_what_they_were():
     assert sorted(tree["params"]["layers_0"]) == ["attn_norm", "ffn_norm", "router", "w_down", "w_gate", "w_up", "wk", "wo", "wq", "wv"]
     assert all(sorted(layer) == ["k", "pos", "v"] for layer in state["layers"]) and sorted(state) == ["layers", "pos"]
     assert [layer["k"].shape[1] for layer in state["layers"]] == [32, 8, 32, 8]
-    assert decoder.carry_kinds(state) == {"cache": {"layers": 4, "bytes": sum(x.nbytes for x in jax.tree.leaves(state["layers"]))}, "conv": {"layers": 0, "bytes": 0}}
+    none = {"layers": 0, "bytes": 0}
+    assert decoder.carry_kinds(state) == {"cache": {"layers": 4, "bytes": sum(x.nbytes for x in jax.tree.leaves(state["layers"]))}, "conv": none, "latent": none}
 
 
 def test_lfm2s_carry_holds_two_kinds_of_state_and_its_tree_is_the_references():
@@ -343,6 +524,7 @@ def test_lfm2s_carry_holds_two_kinds_of_state_and_its_tree_is_the_references():
     tree = jax.eval_shape(lambda k: decoder.DecoderPolicy(cfg).init(k, ids, ids, jnp.ones((1, 1)), one, method=decoder.DecoderPolicy.step), jax.random.PRNGKey(0))
     have = {"/".join(str(k.key) for k in path): tuple(x.shape) for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
     assert have == lfm2.flat_shapes(LFM2)
+    assert kinds["latent"] == {"layers": 0, "bytes": 0} and sorted(tree["params"]["layers_1"]) == sorted(lfm2.layer_shapes(LFM2, 1))  # no leaf of the new kinds
 
 
 def test_no_assignment_is_dropped_when_every_token_picks_the_same_expert():
