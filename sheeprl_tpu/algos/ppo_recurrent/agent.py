@@ -346,5 +346,6 @@ def build_decoder_agent(ctx, action_space, obs_space, cfg) -> Tuple[DecoderPPOAg
     agent = DecoderPPOAgent(dcfg, ctx.compute_dtype, ctx.mesh if ctx.mesh.size > 1 else None)
     ids = jnp.zeros((1,), jnp.int32)
     state0 = decoder.zero_state(dcfg, 1, ctx.compute_dtype)
-    params = agent.init(ctx.rng(), ids, ids, jnp.ones((1, 1)), state0, method=DecoderPPOAgent.step)
+    # under jit, one compile: run eagerly, each of a step's ~300 operations (the attention kernel's too) is compiled on its own
+    params = jax.jit(lambda key: agent.init(key, ids, ids, jnp.ones((1, 1)), state0, method=DecoderPPOAgent.step))(ctx.rng())
     return agent, ctx.replicate(params)

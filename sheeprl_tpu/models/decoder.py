@@ -51,14 +51,15 @@ the tail and itself by segment: acting is the chunk of one token, whose keys and
 input are then written; training reads the carry as it stood when the rollout began and
 writes nothing.  An episode that starts empties its row of every layer's state.
 
-Attention takes one of two programs by the shape of its call.  One token a row (acting,
-the bootstrap value) forms its float32 scores over every slot whole: they are small, and
-the step's time is the cache's read.  A chunk (the update) goes blockwise through the
-cache, a key block at a time with an online softmax, the scores never leaving the chip
-and a block that the row has not filled (or that lies out of a window's reach) not
-visited at all (``ops/blockwise_attention.py``); the chunk's own keys are merged in
-afterwards.  The update reports the share of key blocks it visited
-(``Attn/key_blocks_visited_share``), and its trace notes ``blockwise_attention``.
+Attention takes one of two programs by the shape of its call.  A chunk (the update) goes
+blockwise through the cache, a key block at a time with an online softmax, the scores
+never leaving the chip and a block that the row has not filled (or that lies out of a
+window's reach) not visited at all (``ops/blockwise_attention.py``); the chunk's own keys
+are merged in afterwards.  One token a row (acting, the bootstrap value) goes the same
+way where its query rows a key head fill a whole bfloat16 tile (a latent layer's sixteen
+heads on its one key head), and otherwise forms its float32 scores over every slot whole.
+The update reports the share of key blocks it visited (``Attn/key_blocks_visited_share``);
+the trace notes ``blockwise_attention``, the acting call's tile too (``act_layer_<n>``).
 """
 
 from __future__ import annotations
@@ -329,12 +330,16 @@ def lane_grouped_attention(q, k, v, cache, cache_seg, q_pos, q_seg, window, mesh
     return jnp.stack(outs, 2).reshape(B, T, Hq, hd), visited
 
 
-def _blocks_visited(visited, layer: int) -> Dict[str, jax.Array]:
+def _blocks_visited(visited, layer: int, tokens: int) -> Dict[str, jax.Array]:
     """The counters of a layer whose chunk went blockwise through its cache (none where
-    ``grouped_attention`` says nothing of blocks), and the call's facts noted under the layer."""
+    ``grouped_attention`` says nothing of blocks, nor for one token a row: an acting step
+    hands back nothing more), and the call's facts noted under the layer."""
     if visited is None:
         return {}
     flags, how = visited
+    if tokens == 1:
+        note("blockwise_attention", {f"act_layer_{layer}": how})
+        return {}
     note("blockwise_attention", {f"layer_{layer}": how})
     return {"key_blocks": jnp.float32(flags.size), "key_blocks_visited": flags.sum().astype(jnp.float32)}
 
@@ -400,10 +405,10 @@ class DecoderLayer(nn.Module):
                 q_latent = jnp.einsum("bthd,rhd->bthr", q_nope, w_uk, precision=_precision(q_nope)).astype(dt)
                 lanes = lambda *parts: jnp.concatenate([*parts, jnp.zeros((*parts[0].shape[:3], c.latent_width - r - dr), dt)], -1)  # noqa: E731
                 own = lanes(latent, k_pe)  # [B, T, 1, latent_width]: one key head, whose values are its first r columns
-                kept = state["latent"].astype(dt)[:, :, None]  # [B, slots, 1, latent_width]: keys and values at once
-                held = (kept, kept, state["pos"], jnp.where(state["pos"] >= 0, 0, -1))
+                kept = state["latent"].astype(dt)[:, :, None]  # [B, slots, 1, latent_width]: the keys, whose first r columns are the values
+                held = (kept, None, state["pos"], jnp.where(state["pos"] >= 0, 0, -1))
                 o, visited = grouped_attention(lanes(q_latent, q_pe), own, latent, held, q_pos, q_seg, None, dn + dr, self.mesh)
-                counters = _blocks_visited(visited, self.layer)
+                counters = _blocks_visited(visited, self.layer, T)
                 o = jnp.einsum("bthr,rhd->bthd", o, w_uv, precision=_precision(o)).astype(dt)
                 h = x + _dot(o.reshape(B, T, Hq * dv), wo.astype(dt), preferred_element_type=jnp.float32)
             made = {"latent": own[:, :, 0]}
@@ -432,7 +437,7 @@ class DecoderLayer(nn.Module):
                 else:
                     held = (state["k"].astype(dt), state["v"].astype(dt), state["pos"], cache_seg)
                     o, visited = grouped_attention(q, k, v, held, q_pos, q_seg, window, mesh=self.mesh)
-                counters = _blocks_visited(visited, self.layer)
+                counters = _blocks_visited(visited, self.layer, T)
                 h = x + jnp.dot(o.reshape(B, T, Hq * hd), wo.astype(dt), preferred_element_type=jnp.float32)
             made = {"k": k, "v": v}
         ffn_norm = self.param("ffn_norm", nn.initializers.ones, (D,))

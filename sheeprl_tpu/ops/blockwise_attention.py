@@ -1,5 +1,6 @@
-"""Blockwise attention of a chunk's queries over a carried cache (Pallas, TPU): the
-scores stay on the chip, and a key block that a row has not filled is not visited.
+"""Blockwise attention of a chunk's queries (or of one query a row whose query rows a key
+head fill a bfloat16 tile) over a carried cache (Pallas, TPU): the scores stay on the
+chip, and a key block that a row has not filled is not visited.
 
 ``ops/ring_attention.py::_grouped_attention`` writes the float32 scores ``[B, Hkv, G, Tq,
 slots]`` to HBM and walks them once each for the mask, the softmax and the cast, and
@@ -51,6 +52,10 @@ from jax.sharding import PartitionSpec as P
 KEY_BLOCK = 512
 #: the most query rows (tokens x query heads of one key head) a grid step holds
 QUERY_ROWS = 1024
+#: the query rows a key head that one query a row needs to go blockwise: a whole bfloat16 (16, 128) tile.
+#: Fewer pay a grid step a row and a key block (~0.35 us skipped) for a few products, more than the
+#: whole scores cost them (PERF.md section 7, PR 36)
+ONE_QUERY_ROWS = 16
 #: the VMEM a kernel is granted without asking, less some room, and the most it asks for (a v5e has 128 MiB)
 VMEM_GRANTED, VMEM_MOST = 14 << 20, 96 << 20
 #: a masked score: finite, so that a row that has seen no key yet subtracts it from itself
@@ -171,28 +176,32 @@ def _bwd_kernel(flags_ref, hold_ref, q_ref, k_ref, v_ref, qpos_ref, qseg_ref, kp
         dq_ref[...] = acc_scr[...] * scale
 
 
-def _vmem_limit(query_tile: int, key_block: int, D: int, Dv: int, itemsize: int) -> Optional[int]:
+def _vmem_limit(query_tile: int, key_block: int, D: int, Dv: int, itemsize: int, values_in_keys: bool = False) -> Optional[int]:
     """The VMEM to ask the compiler for, or ``None`` where a grid step fits what it grants
-    unasked (``VMEM_GRANTED``): the step's blocks twice (they are double-buffered), its
-    float32 output and accumulator, and some five score-sized float32 temporaries."""
+    unasked (``VMEM_GRANTED``): the step's blocks twice (they are double-buffered; one
+    cache block where the values are in the keys'), its float32 output and accumulator,
+    and some five score-sized float32 temporaries."""
     wide = max(D, Dv)
-    blocks = itemsize * (query_tile * (D + Dv) + key_block * (D + Dv))
+    blocks = itemsize * (query_tile * (D + Dv) + key_block * (D + (0 if values_in_keys else Dv)))
     step = 2 * blocks + 3 * 4 * query_tile * wide + 5 * 4 * query_tile * key_block
     return None if step <= VMEM_GRANTED else min(2 * step, VMEM_MOST)
 
 
 def _call(kernel, flags, args, Dv, out_widths, scratch_widths, tile, scale, window, mesh):
     """One of the two kernels over the grid (row, key head, query tile, key block).
-    ``args``: ``q [B, H, R, D]``, the cache's ``k`` ``[B, slots, H * D]`` and ``v``, of which a
-    key head's values are column block ``h`` of width ``Dv`` (``[B, slots, H * Dv]``, or, for
-    one key head, any array at least ``Dv`` wide: the keys' own, where the values are the
-    keys' first columns), ``q_pos``, ``q_seg`` ``[B, R, 1]``, ``kv_pos``, ``kv_seg`` ``[B, 1,
-    slots]``, then any more arrays shaped by the queries (``[B, H, R, width]``).  Outputs
-    (``[B, H, R, width]``) and scratch (``[query tile, width]``) are float32, one of each
-    width given."""
+    ``args``: ``q [B, H, R, D]``, the cache's ``k`` ``[B, slots, H * D]`` and ``v`` ``[B, slots, H
+    * Dv]`` (a key head's values are column block ``h``), or ``None``: the values are the
+    first ``Dv`` columns of the keys' own block (one key head), which a visited step then
+    copies into VMEM once for both products; ``q_pos``, ``q_seg`` ``[B, R, 1]``, ``kv_pos``,
+    ``kv_seg`` ``[B, 1, slots]``, then any more arrays shaped by the queries (``[B, H, R,
+    width]``).  Outputs (``[B, H, R, width]``) and scratch (``[query tile, width]``) are
+    float32, one of each width given."""
     B, H, R, D = args[0].shape
     n_blocks = flags.shape[1]
     query_tile, key_block = tile
+    kernel, values = functools.partial(kernel, scale=scale, window=window), [Dv]
+    if args[2] is None:
+        kernel, values, args = _values_in_keys(kernel, Dv), [], (*args[:2], *args[3:])
 
     def rows(width):  # a block of an array shaped by the queries
         return pl.BlockSpec((None, None, query_tile, width), lambda b, h, i, j, *_: (b, h, i, 0))
@@ -202,16 +211,16 @@ def _call(kernel, flags, args, Dv, out_widths, scratch_widths, tile, scale, wind
 
     q_id = pl.BlockSpec((None, query_tile, 1), lambda b, h, i, j, *_: (b, i, 0))
     kv_id = pl.BlockSpec((None, 1, key_block), lambda b, h, i, j, flags, hold: (b, 0, hold[b * n_blocks + j]))
-    limit = _vmem_limit(query_tile, key_block, D, Dv, args[0].dtype.itemsize)
+    limit = _vmem_limit(query_tile, key_block, D, Dv, args[0].dtype.itemsize, values_in_keys=not values)
 
     def run(flags, *args):
         B = args[0].shape[0]  # a shard's rows under ``shard_map``
         return pl.pallas_call(
-            functools.partial(kernel, scale=scale, window=window),
+            kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, H, R // query_tile, n_blocks),
-                in_specs=[rows(D), cache(D), cache(Dv), q_id, q_id, kv_id, kv_id] + [rows(a.shape[-1]) for a in args[7:]],
+                in_specs=[rows(D), cache(D), *map(cache, values), q_id, q_id, kv_id, kv_id] + [rows(a.shape[-1]) for a in args[6 + len(values) :]],
                 out_specs=[rows(w) for w in out_widths],
                 scratch_shapes=[pltpu.VMEM((query_tile, w), jnp.float32) for w in scratch_widths],
             ),
@@ -224,6 +233,16 @@ def _call(kernel, flags, args, Dv, out_widths, scratch_widths, tile, scale, wind
         spec = P("data") if B % mesh.shape["data"] == 0 else P()
         run = jax.shard_map(run, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
     return run(flags, *args)
+
+
+def _values_in_keys(kernel, Dv):
+    """``kernel`` with its values' block the first ``Dv`` columns of the keys' block: a view
+    of what is in VMEM already, not a second copy of the cache's block."""
+
+    def shared(flags_ref, hold_ref, q_ref, k_ref, *refs):
+        return kernel(flags_ref, hold_ref, q_ref, k_ref, k_ref.at[:, :Dv], *refs)
+
+    return shared
 
 
 def _by_key_head(x, Hkv):
@@ -241,8 +260,9 @@ def _by_token(x, Tq):
 def _cache_and_ids(cache_k, cache_v, q_pos, q_seg, kv_pos, kv_seg, G):
     """The kernels' view of the cache and the ids.  A key head is a lane-aligned column
     block of ``[B, slots, Hkv * D]``, which is the cache as it lies for one key head (the
-    chip keeps ``[B, slots, 1, D]`` in that order; any other view of it is a copy of it)."""
-    heads_side_by_side = lambda x: x.reshape(*x.shape[:2], -1)  # noqa: E731
+    chip keeps ``[B, slots, 1, D]`` in that order; any other view of it is a copy of it).
+    ``cache_v`` ``None`` (values that are the keys' first columns) stays ``None``."""
+    heads_side_by_side = lambda x: None if x is None else x.reshape(*x.shape[:2], -1)  # noqa: E731
     per_query_row = lambda x: jnp.repeat(x, G, axis=1)[:, :, None]  # noqa: E731  [B, Tq] -> [B, Tq * G, 1]
     return heads_side_by_side(cache_k), heads_side_by_side(cache_v), per_query_row(q_pos), per_query_row(q_seg), kv_pos[:, None, :], kv_seg[:, None, :]
 
@@ -277,9 +297,9 @@ def cache_and_own_attention(q, k, v, cache_k, cache_v, flags, q_pos, q_seg, kv_p
     ``cache_v``: ``[B, slots, Hkv, Dv]`` with ``kv_pos``, ``kv_seg`` ``[B, slots]``) and the
     chunk's own ``k`` ``[B, Tq, Hkv, D]`` and ``v`` ``[B, Tq, Hkv, Dv]`` (at ``q_pos``,
     ``q_seg``), one softmax over both -> ``[B, Tq, Hq, Dv]`` in ``q.dtype``.  The values may
-    be narrower than the keys, and those of a cache with one key head may be the first
-    ``Dv`` columns of a wider array, ``cache_k`` itself too (a latent that is key and value
-    at once is then stored once).  ``flags``: ``key_block_flags``; ``tile``: ``tiles``'s pair.
+    be narrower than the keys; ``cache_v`` ``None``: they are the first ``Dv`` columns of
+    ``cache_k`` (one key head: a latent that is key and value at once, stored once and read
+    once).  ``flags``: ``key_block_flags``; ``tile``: ``tiles``'s pair.
     Differentiable in ``q``, ``k`` and ``v``; the cache takes no gradient."""
     return _attention_fwd(q, k, v, cache_k, cache_v, flags, q_pos, q_seg, kv_pos, kv_seg, scale, window, tile, mesh)[0]
 

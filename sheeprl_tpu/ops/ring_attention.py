@@ -174,9 +174,9 @@ def grouped_attention(q, k, v, cache, q_pos, q_seg, window=None, head_dim=None, 
     ``q``: ``[B, Tq, Hq, D]``; ``k``, ``v``: the chunk's own ``[B, Tq, Hkv, D]`` and ``[B, Tq,
     Hkv, Dv]`` with ``Hq`` a multiple of ``Hkv`` (query head ``h`` reads key head ``h // (Hq //
     Hkv)``) and ``Dv`` the keys' width or less; ``cache``: ``(k, v, kv_pos, kv_seg)`` with ``k``:
-    ``[B, slots, Hkv, D]`` and ``v``: ``[B, slots, Hkv, Dv]``, or, with one key head, any array
-    whose first ``Dv`` columns are the values: ``k`` itself, for a latent that is key and
-    value at once and is stored once; ``q_pos`` / ``kv_pos``: ``[B, Tq]`` / ``[B, slots]``
+    ``[B, slots, Hkv, D]`` and ``v``: ``[B, slots, Hkv, Dv]``, or ``None`` where, with one key
+    head, the values are the first ``Dv`` columns of ``k`` (a latent that is key and value at
+    once, stored once and read once); ``q_pos`` / ``kv_pos``: ``[B, Tq]`` / ``[B, slots]``
     positions inside the episode; ``q_seg`` / ``kv_seg``: int segments (a key of another
     segment, e.g. an empty cache slot given ``-1``, is never visible).  A key is visible iff
     it is of the query's segment, not after it, and, with ``window``, fewer than ``window``
@@ -187,24 +187,27 @@ def grouped_attention(q, k, v, cache, q_pos, q_seg, window=None, head_dim=None, 
     carry as it stood: it takes no gradient, whichever way the call goes (``q`` and the
     chunk's own ``k``, ``v`` do).
 
-    By the shape of the call: one query a row (an acting step, whose scores are small and
-    whose time is the cache's read) forms the scores whole, ``_grouped_attention``, and
-    nothing is said of blocks (``None``); a chunk of queries goes blockwise through the
-    cache with the scores kept on the chip and the key blocks that the row has not filled
-    skipped (``ops/blockwise_attention.py``; its ``Visited``: the ``[B, blocks]`` flags and
-    the tile taken), where that kernel takes the shapes.  A chunk whose shapes it does not
-    take forms its scores whole too, ``B * Hq * Tq * slots`` of them in float32 at once, and
-    on the chip says so in the log.  ``mesh``: the devices the rows are spread over, if
-    several."""
+    By the shape of the call: a chunk of queries goes blockwise through the cache with the
+    scores kept on the chip and the key blocks that the row has not filled skipped
+    (``ops/blockwise_attention.py``; its ``Visited``: the ``[B, blocks]`` flags and the tile
+    taken), where that kernel takes the shapes; so does one query a row (an acting step,
+    whose time is the cache's read) where its query rows a key head fill whole bfloat16
+    tiles (``blockwise_attention.ONE_QUERY_ROWS``).  Other one-query calls form the scores
+    whole, ``_grouped_attention``, and nothing is said of blocks (``None``): the kernel would
+    take a grid step a row and a key block for a few products.  A chunk whose shapes the
+    kernel does not take forms its scores whole too, ``B * Hq * Tq * slots`` of them in
+    float32 at once, and on the chip says so in the log.  ``mesh``: the devices the rows are
+    spread over, if several."""
     B, Tq, Hq, D = q.shape
     ck, cv, kv_pos, kv_seg = cache
-    if cv.shape[-1] != v.shape[-1] and k.shape[2] != 1:
-        raise ValueError(f"values that are the first columns of a wider array need one key head: {cv.shape} for values {v.shape}")
-    tile = blockwise_attention.tiles(Tq * Hq // k.shape[2], kv_pos.shape[1], D, v.shape[-1]) if Tq > 1 else None
+    if cv is None and k.shape[2] != 1:
+        raise ValueError(f"values that are the keys' first columns need one key head: keys {ck.shape}, values {v.shape}")
+    rows = Tq * Hq // k.shape[2]
+    tile = blockwise_attention.tiles(rows, kv_pos.shape[1], D, v.shape[-1]) if Tq > 1 or rows % blockwise_attention.ONE_QUERY_ROWS == 0 else None
     if tile is None:
         if Tq > 1 and jax.default_backend() == "tpu":
             _log.warning("grouped_attention: a chunk %s forms its float32 scores whole: the blockwise kernel does not take %d slots of width %d", q.shape[:3], kv_pos.shape[1], D)  # fmt: skip
-        held = (jax.lax.stop_gradient(ck), jax.lax.stop_gradient(cv), kv_pos, kv_seg)
+        held = (jax.lax.stop_gradient(ck), jax.lax.stop_gradient(ck if cv is None else cv), kv_pos, kv_seg)
         return _grouped_attention(q, [held, (k, v, q_pos, q_seg)], q_pos, q_seg, window, head_dim), None
     flags = blockwise_attention.key_block_flags(q_pos, q_seg, kv_pos, kv_seg, window, tile[1])
     scale = float((head_dim or D) ** -0.5)

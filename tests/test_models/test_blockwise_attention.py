@@ -134,14 +134,16 @@ def test_a_query_that_sees_no_key_returns_zeros_and_takes_no_gradient():
             assert not np.asarray(x).any()  # NaN is not zero either
 
 
+@pytest.mark.parametrize("T,Hq,firsts", [(8, 4, ((2, 4),)), (1, 16, ())], ids=["chunk", "one_query_a_row"])
 @pytest.mark.parametrize("window,ring", [(None, False), (16, True)], ids=["full", "ring"])
-def test_a_block_that_is_not_flagged_is_not_read(window, ring):
+def test_a_block_that_is_not_flagged_is_not_read(window, ring, T, Hq, firsts):
     """Every unflagged block of the cache's keys and values filled with NaN: outputs and
-    gradients are what they were, and the share is the one counted by hand."""
+    gradients are what they were, and the share is the one counted by hand; for a chunk,
+    and for one query a row whose sixteen query rows on the key head take the kernel too."""
     rng = np.random.default_rng(3)
     fills = (0, 5, 20, 100) if ring else (0, 5, 20, 32)
     slots = 32
-    qkv, cache, q_pos, q_seg = inputs(rng, fills, ((2, 4),), 8, 4, 1, 16, slots, jnp.float32, ring)
+    qkv, cache, q_pos, q_seg = inputs(rng, fills, firsts, T, Hq, 1, 16, slots, jnp.float32, ring)
     weights = jnp.asarray(rng.standard_normal(qkv[0].shape), jnp.float32)
     attend = lambda cache: lambda qkv: ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, window)  # noqa: E731
     flags = np.asarray(attend(cache)(qkv)[1].flags)
@@ -210,8 +212,45 @@ def test_the_kernels_mask_is_the_whole_scores_mask():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# one query a row: (query heads, key heads, head width, lanes of a lane-grouped cache or None) -> takes the kernel
+ONE_QUERY = {
+    "lfm2_lane_grouped_eight_rows": ((16, 4, 8, 16), False),
+    "smallthinker_seven_rows": ((7, 1, 16, None), False),
+    "two_rows": ((4, 2, 16, None), False),
+    "moonlight_sixteen_rows": ((16, 1, 16, None), True),
+    "thirty_two_rows": ((32, 1, 16, None), True),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_QUERY))
+def test_one_query_a_row_takes_the_kernel_only_where_its_rows_a_key_head_fill_a_bf16_tile(case, monkeypatch):
+    """The acting step's call by its shape: query rows a key head a multiple of 16 go blockwise;
+    LFM2's lane-grouped calls (two key heads of a lane-full, four query heads each: 8 rows)
+    and SmallThinker's 7 rows form the scores whole, and their output is the oracle's own."""
+    (Hq, Hkv, hd, lanes), blockwise = ONE_QUERY[case]
+    rng = np.random.default_rng(10)
+    fills = (0, 5, 20, 32)
+    qkv, cache, q_pos, q_seg = inputs(rng, fills, (), 1, Hq, Hkv, hd, 32, jnp.float32)
+    if lanes:
+        monkeypatch.setattr(decoder, "LANES", lanes)
+        arrays = lambda x: tuple(x.reshape(len(fills), 32, -1, lanes).transpose(2, 0, 1, 3)[:, :, :, None])  # noqa: E731
+        state = {"k": arrays(cache[0]), "v": arrays(cache[1]), "pos": cache[2]}
+        out, visited = decoder.lane_grouped_attention(*qkv, state, cache[3], q_pos, q_seg, None)
+    else:
+        out, visited = ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg)
+    assert (visited is not None) == blockwise
+    want = whole(qkv, cache, q_pos, q_seg, None)
+    if blockwise:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6)
+    elif lanes is None:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
 def test_shapes_the_kernel_does_not_take_go_the_whole_scores_way(monkeypatch):
-    """One query a row never takes it; nor, on the chip, a head narrower than its lanes."""
+    """One query a row of fewer rows a key head than a bf16 tile never takes it; nor, on the
+    chip, a head narrower than its lanes."""
     rng = np.random.default_rng(5)
     qkv, cache, q_pos, q_seg = inputs(rng, (3, 9), (), 1, 4, 2, 16, 16, jnp.float32)
     out, visited = ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg)
@@ -286,16 +325,16 @@ def test_in_bfloat16_the_blockwise_program_is_as_near_the_float32_one_as_the_who
 
 def latent_inputs(rng, fills, firsts, T, Hq, Hkv, D, Dv, slots, dtype, shared):
     """As ``inputs``, with values ``Dv`` wide under keys ``D`` wide.  ``shared``: the cache is
-    one array (one key head) whose first ``Dv`` columns are the values, handed in as both."""
+    one array (one key head) whose first ``Dv`` columns are the values, which are ``None``."""
     (q, k, _), (ck, _, kv_pos, kv_seg), q_pos, q_seg = inputs(rng, fills, firsts, T, Hq, Hkv, D, slots, dtype)
     v = jnp.asarray(rng.standard_normal((len(fills), T, Hkv, Dv)), dtype)
-    cv = ck if shared else jnp.asarray(rng.standard_normal((len(fills), slots, Hkv, Dv)), dtype)
+    cv = None if shared else jnp.asarray(rng.standard_normal((len(fills), slots, Hkv, Dv)), dtype)
     return (q, k, v), (ck, cv, kv_pos, kv_seg), q_pos, q_seg
 
 
 def sliced(cache, Dv):
     """The cache with its values cut out as an array of their own: what the whole-scores oracle is given."""
-    return (cache[0], cache[1][..., :Dv], *cache[2:])
+    return (cache[0], (cache[0] if cache[1] is None else cache[1])[..., :Dv], *cache[2:])
 
 
 # (fills, episode starts, T, Hq, Hkv, D, Dv, slots, the values are the keys' first columns, the head's width that the scores are over the root of)
@@ -319,7 +358,7 @@ def test_a_key_wider_than_its_value_gives_the_whole_scores_outputs_and_gradients
     weights = jnp.asarray(rng.standard_normal((*qkv[0].shape[:3], Dv)), jnp.float32)
     blockwise = lambda qkv: ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, None, hd)  # noqa: E731
     out, visited = blockwise(qkv)
-    assert visited is not None and out.shape == (len(fills), T, Hq, Dv) and (cache[1] is cache[0]) == shared
+    assert visited is not None and out.shape == (len(fills), T, Hq, Dv) and (cache[1] is None) == shared
     got = out_and_grads(lambda qkv: blockwise(qkv)[0], qkv, weights)
     want = out_and_grads(lambda qkv: whole(qkv, sliced(cache, Dv), q_pos, q_seg, None, hd), qkv, weights)
     tol = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 else dict(rtol=3e-2, atol=3e-2)
@@ -347,16 +386,37 @@ def test_in_bfloat16_the_latent_call_is_as_near_the_float32_program_as_the_whole
     assert (ratio <= AS_NEAR).all() if fault is None else ratio.max() >= 3 * AS_NEAR, ratio
 
 
-def test_one_query_a_row_reads_a_shared_latent_as_it_lies_and_keeps_its_first_columns():
-    """The acting step's call: the scores whole, the cache's array multiplied whole as the
-    values and the product's first ``Dv`` columns kept; and several key heads cannot share."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_one_query_a_row_reads_a_shared_latent_as_it_lies_and_keeps_its_first_columns(dtype):
+    """The acting step's call of a latent layer: sixteen query heads on the one key head fill a
+    bf16 tile, so it goes blockwise, the cache's array read once as keys and as values, whose
+    first ``Dv`` columns it keeps.  Rows whose caches hold nothing (an episode that starts),
+    a part of one block, three blocks and every block; under a window of no position no row
+    sees a key, its own neither, and gets zeros.  In float32 the output is the whole-scores
+    program's; in bfloat16 it lies no farther from the float32 program than that program in
+    bfloat16 does (``AS_NEAR``).  The cache takes no gradient; several key heads cannot share."""
     rng = np.random.default_rng(9)
-    qkv, cache, q_pos, q_seg = latent_inputs(rng, (0, 7, 31), ((0, 0),), 1, 4, 1, 24, 16, 32, jnp.float32, True)
-    out, visited = ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, None, 12)
-    assert visited is None and out.shape == (3, 1, 4, 16)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(whole(qkv, sliced(cache, 16), q_pos, q_seg, None, 12)), rtol=1e-6, atol=1e-6)
-    d_cache = jax.grad(lambda c: ring_attention.grouped_attention(*qkv, (c, c, *cache[2:]), q_pos, q_seg, None, 12)[0].sum())(cache[0])
-    assert not np.asarray(d_cache).any()
+    fills = (0, 5, 3 * KEY_BLOCK - 2, 4 * KEY_BLOCK)
+    qkv, cache, q_pos, q_seg = latent_inputs(rng, fills, ((0, 0),), 1, 16, 1, 24, 16, 4 * KEY_BLOCK, dtype, True)
+    attend = lambda qkv, cache=cache, window=None: ring_attention.grouped_attention(*qkv, cache, q_pos, q_seg, window, 12)  # noqa: E731
+    out, visited = attend(qkv)
+    assert out.shape == (4, 1, 16, 16) and out.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(visited.flags), [[0, 0, 0, 0], [1, 0, 0, 0], [1, 1, 1, 0], [1, 1, 1, 1]])
+    weights = jnp.asarray(rng.standard_normal((*qkv[0].shape[:3], 16)), jnp.float32)
+    exact = out_and_grads(lambda qkv: whole(qkv, float32_of(sliced(cache, 16)), q_pos, q_seg, None, 12), float32_of(qkv), weights)
+    got = out_and_grads(lambda qkv: attend(qkv)[0], qkv, weights)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(exact[0]), rtol=1e-6, atol=1e-6)
+        for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(exact[1])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
+    else:
+        rounded = out_and_grads(lambda qkv: whole(qkv, sliced(cache, 16), q_pos, q_seg, None, 12), qkv, weights)
+        far = lambda got: np.array([np.abs(np.asarray(a, np.float32) - np.asarray(b)).mean() for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(exact))])  # noqa: E731
+        assert (far(got) / far(rounded) <= AS_NEAR).all(), far(got) / far(rounded)
+    none_seen, visited = attend(qkv, window=0)
+    assert not np.asarray(visited.flags).any() and not np.asarray(none_seen, np.float32).any()
+    d_cache = jax.grad(lambda c: attend(qkv, (c, None, *cache[2:]))[0].astype(jnp.float32).sum())(cache[0])
+    assert not np.asarray(d_cache, np.float32).any()
     two, held, q_pos2, q_seg2 = latent_inputs(rng, (4, 9), (), 4, 4, 2, 16, 8, 32, jnp.float32, True)
     with pytest.raises(ValueError, match="one key head"):
         ring_attention.grouped_attention(*two, held, q_pos2, q_seg2)
@@ -433,19 +493,74 @@ def test_the_kernels_compile_for_the_chip_over_a_latent_cache_at_moonlights_widt
     monkeypatch.setattr(blockwise_attention, "_interpret", lambda: False)
     B, T, H, D, Dv, slots = 32, 64, 16, 640, 512, 8192
     assert blockwise_attention.tiles(T * H, slots, D, Dv) == (1024, 512) and blockwise_attention.tiles(T * H, slots, D, 576) is None
-    assert blockwise_attention._vmem_limit(1024, 512, D, Dv, 2) > blockwise_attention.VMEM_GRANTED
+    shared = blockwise_attention._vmem_limit(1024, 512, D, Dv, 2, values_in_keys=True)  # one cache block where the values are in the keys'
+    assert blockwise_attention._vmem_limit(1024, 512, D, Dv, 2) > shared > blockwise_attention.VMEM_GRANTED
     assert blockwise_attention._vmem_limit(512, 512, 128, 128, 2) is None and blockwise_attention._vmem_limit(1024, 512, 128, 128, 2) is None
     placed = SingleDeviceSharding(topology.devices[0])
     shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=placed)  # noqa: E731
 
     def step(q, k, v, latent, kv_pos, q_pos, q_seg):
         kept = latent[:, :, None]  # the carry keeps [B, slots, 640]
-        cache = (kept, kept, kv_pos, jnp.where(kv_pos >= 0, 0, -1))
+        cache = (kept, None, kv_pos, jnp.where(kv_pos >= 0, 0, -1))
         loss = lambda q, k, v: ring_attention.grouped_attention(q, k, v, cache, q_pos, q_seg, None, 192)[0].astype(jnp.float32).sum()  # noqa: E731
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     ids = shaped((B, T), jnp.int32)
     text = jax.jit(step).lower(shaped((B, T, H, D)), shaped((B, T, 1, D)), shaped((B, T, 1, Dv)), shaped((B, slots, D)), shaped((B, slots), jnp.int32), ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 2
+    cache_sized = [line for line in text.splitlines() if " copy(" in line and f"bf16[{B},{slots}," in line.split(" copy(")[0]]
+    assert not cache_sized, cache_sized
+
+
+# one acting step's attention for a described v5e: (query heads, key heads, head width, slots, window, lane-grouped, kernels)
+ACTING = {
+    "moonlight": ((16, 1, 640, 8192, None, False), 1),
+    "lfm2": ((32, 8, 64, 8192, None, True), 0),
+    "smallthinker_full": ((7, 1, 128, 8192, None, False), 0),
+    "smallthinker_window": ((7, 1, 128, 4096, 4096, False), 0),
+}
+
+
+@pytest.mark.parametrize("case", list(ACTING))
+def test_an_acting_step_compiles_for_the_chip_with_the_kernel_where_its_rows_fill_a_tile(case, topology, monkeypatch):
+    """One token a row, as ``jit_act`` calls it at the benchmark's widths, then the row's slot
+    written into the donated cache.  Moonlight's 32 rows x 16 heads on one latent key head 640
+    wide (values its first 512 columns) over ``[32, 8192, 1, 640]`` take the kernel, once a
+    layer, and neither the call nor the write copies the cache.  LFM2's four lane-fulls of
+    8 query rows over ``[64, 8192, 1, 128]`` and SmallThinker's 7 rows take none: their
+    scores are formed whole as before."""
+    from jax.sharding import SingleDeviceSharding
+
+    (Hq, Hkv, hd, slots, window, laned), kernels = ACTING[case]
+    monkeypatch.setattr(blockwise_attention, "KEY_BLOCK", 512)
+    monkeypatch.setattr(blockwise_attention, "_interpret", lambda: False)
+    placed = SingleDeviceSharding(topology.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=placed)  # noqa: E731
+    latent = hd > decoder.LANES
+    B, Dv, lanes = (32, 512, hd) if latent else (64, hd, decoder.LANES)
+    rows = jnp.arange(B)
+
+    def step(q, k, v, cache, kv_pos, q_pos):
+        q_seg, kv_seg = jnp.zeros_like(q_pos), jnp.where(kv_pos >= 0, 0, -1)
+        slot = q_pos[:, 0] % slots
+        if latent:
+            kept = cache[:, :, None]
+            out, _ = ring_attention.grouped_attention(q, k, v, (kept, None, kv_pos, kv_seg), q_pos, q_seg, None, 192)
+            return out, cache.at[rows, slot].set(k[:, 0, 0])
+        if laned:
+            out, _ = decoder.lane_grouped_attention(q, k, v, {"k": cache, "v": cache, "pos": kv_pos}, kv_seg, q_pos, q_seg, window)
+            return out, tuple(c.at[rows, slot].set(k[:, 0].reshape(B, len(cache), 1, -1)[:, j]) for j, c in enumerate(cache))
+        out, _ = ring_attention.grouped_attention(q, k, v, (cache, cache, kv_pos, kv_seg), q_pos, q_seg, window)
+        return out, cache.at[rows, slot].set(k[:, 0])
+
+    if latent:
+        cache = shaped((B, slots, hd))
+    elif laned:
+        cache = tuple(shaped((B, slots, 1, lanes)) for _ in range(Hkv * hd // lanes))
+    else:
+        cache = shaped((B, slots, Hkv, hd))
+    args = (shaped((B, 1, Hq, hd)), shaped((B, 1, Hkv, hd)), shaped((B, 1, Hkv, Dv)), cache, shaped((B, slots), jnp.int32), shaped((B, 1), jnp.int32))
+    text = jax.jit(step, donate_argnums=3).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
     cache_sized = [line for line in text.splitlines() if " copy(" in line and f"bf16[{B},{slots}," in line.split(" copy(")[0]]
     assert not cache_sized, cache_sized

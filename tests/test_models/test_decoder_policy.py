@@ -78,6 +78,8 @@ def model_of(name, monkeypatch=None):
         return lfm2, {**LFM2, "heads_held": 8, "kv_heads_held": 4}
     if name == "moonlight":
         return moon, MOON
+    if name == "moonlight_sixteen_heads":  # as many query rows on the latent's key head as the cell's: the acting step goes blockwise
+        return moon, {**MOON, "heads_held": 16}
     return (lfm2, LFM2) if name == "lfm2" else (ref, SIZES)
 
 
@@ -128,7 +130,7 @@ def test_one_layer_of_each_kind_matches_the_reference(kind):
         assert ("MoE/bias_moved_share" in aux) == (ref is not ONE_LAYER["full"][0])
 
 
-@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes", "moonlight"])
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes", "moonlight", "moonlight_sixteen_heads"])
 def test_acting_through_the_caches_matches_the_full_forward_pass(model, monkeypatch):
     """40 steps a row, one token at a time through the carry (the 8-slot rings wrap four
     times over; episodes end inside, so caches and convolution tails are both emptied),
@@ -151,10 +153,51 @@ def test_acting_through_the_caches_matches_the_full_forward_pass(model, monkeypa
     assert np.asarray(state["pos"]).tolist() == (pos[:, -1] + 1).tolist()
     if model == "lfm2_lanes":  # two arrays of two key heads each, a lane-full wide
         assert [x.shape for x in state["layers"][1]["k"]] == [(n, S["cache_capacity"], 1, 16)] * 2 and cfg.lane_groups == 2
-    if model == "moonlight":  # one array a layer: the latent, the rotated key, zeros to a lane-full; nothing by head
+    if model.startswith("moonlight"):  # one array a layer: the latent, the rotated key, zeros to a lane-full; nothing by head
         assert [sorted(layer) for layer in state["layers"]] == [["latent", "pos"]] * 3 and cfg.latent_width == decoder.LANES
         assert state["layers"][0]["latent"].shape == (n, S["cache_capacity"], decoder.LANES)
         assert not np.asarray(state["layers"][0]["latent"][..., S["kv_lora_rank"] + S["qk_rope_head_dim"] :]).any()
+
+
+def test_a_latent_acting_step_compiles_once_as_its_caches_fill_and_notes_its_tile(monkeypatch):
+    """Moonlight's layer kinds with sixteen query heads on each latent layer's one key head: the
+    acting step goes through the blockwise kernel, and its flags and blocks to hold are data, so
+    one jitted step serves caches that hold nothing, one block, three blocks and every block,
+    compiled once.  Its logits and values are those of the whole-scores step over the same
+    carry, and its trace notes the tile that each latent layer's call took."""
+    from sheeprl_tpu.obs import perf as obs_perf
+    from sheeprl_tpu.ops import blockwise_attention
+
+    monkeypatch.setattr(blockwise_attention, "KEY_BLOCK", 16)
+    S = {**MOON, "heads_held": 16, "cache_capacity": 64}
+    cfg = config_of(S)
+    weights = moon.make_weights(S, 17)
+    policy = decoder.DecoderPolicy(cfg)
+    act = lambda state, tok, prv, first: policy.apply(weights, tok, prv, first, state, method=decoder.DecoderPolicy.step)  # noqa: E731
+    blockwise, whole = jax.jit(act), jax.jit(act)
+    n, rng = 3, np.random.default_rng(5)
+    r, dr = S["kv_lora_rank"], S["qk_rope_head_dim"]
+    obs_perf.reset()
+    try:
+        for i, fill in enumerate((0, 16, 48, 64)):
+            pos = np.where(np.arange(64)[None] < fill, np.arange(64)[None], -1).repeat(n, 0).astype(np.int32)
+            latent = rng.standard_normal((n, 64, cfg.latent_width)).astype(np.float32)
+            latent[..., r + dr :] = 0.0
+            state = {"pos": jnp.full((n,), fill, jnp.int32), "layers": tuple({"latent": jnp.asarray(latent), "pos": jnp.asarray(pos)} for _ in range(S["layers"]))}
+            tok, prv = (jnp.asarray(rng.integers(0, S["vocab_held"], n), jnp.int32) for _ in range(2))
+            first = jnp.full((n, 1), float(fill == 0))
+            (logits,), value, _ = blockwise(state, tok, prv, first)
+            if i == 0:
+                notes = dict(obs_perf._notes.get("blockwise_attention", {}))
+                monkeypatch.setattr(blockwise_attention, "ONE_QUERY_ROWS", 10**9)  # the whole-scores step traces with no shape taking the kernel
+            (want,), want_v, _ = whole(state, tok, prv, first)
+            np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=2e-5, err_msg=f"fill {fill}")
+            np.testing.assert_allclose(np.asarray(value), np.asarray(want_v), atol=2e-5)
+    finally:
+        obs_perf.reset()
+    assert blockwise._cache_size() == 1 and whole._cache_size() == 1
+    tile = {"query_tile": 16, "key_block": 16, "own_keys": "merged outside the kernel"}
+    assert notes == {f"act_layer_{i}": tile for i in range(S["layers"])}
 
 
 @pytest.mark.parametrize("model", ["smallthinker", "lfm2", "lfm2_lanes", "moonlight"])
