@@ -39,7 +39,7 @@ from sheeprl_tpu.algos.ppo_recurrent.agent import (
     evaluate_sequences,
     make_zero_state,
 )
-from sheeprl_tpu.models.decoder import carry_kinds, cast_matmul_weights, hold_buffers
+from sheeprl_tpu.models.decoder import cast_matmul_weights, hold_buffers
 from sheeprl_tpu.analysis.strict import assert_finite, maybe_inject_nonfinite, strict_guard
 from sheeprl_tpu.checkpoint.manager import CheckpointManager
 from sheeprl_tpu.fault.guard import TrainingGuard
@@ -339,8 +339,6 @@ def main(ctx, cfg) -> None:
         call (which that call finds in jit's cache).  No reference to ``args`` outlives this:
         the acting parameters are a copy that must go before the update needs the room."""
         obs_perf.note("acting_boundary", acting_boundary(act_jit.lower(*args).compile(), args))
-        if is_decoder:  # how many layers carry a cache and how many a convolution tail, with their bytes
-            obs_perf.note("carry_kinds", carry_kinds(args[-1][0]))
 
     zero_state = make_zero_state(cfg, ctx.compute_dtype)
     is_attention = cfg.algo.get("sequence_model", "lstm") == "attention"
@@ -351,17 +349,21 @@ def main(ctx, cfg) -> None:
 
     for update in range(start_update, num_updates + 1):
         monitor.advance()
-        if is_attention:
-            # The attention context never crosses a rollout boundary: training
-            # attends within the rollout only, so acting resets its window here —
-            # the policies stay EXACTLY on-policy.
-            carry = (zero_state(num_envs), carry[1])
-        # the state at the rollout's start, which the update reads: a copy, since the acting
-        # steps overwrite theirs
-        state0 = jax.tree.map(jnp.copy, carry[0])
-        act_params = acting_params(params)
-        if update == start_update and obs_perf.perf_enabled(cfg):
-            note_boundary(act_params, *inputs.host(obs, prev_stored, is_first_np), carry)
+        # Every host phase of the cycle is a span, so that a capture names the device's idle
+        # time under each; `monitor.advance` stays outside them, since it closes and opens the
+        # update's own annotations (and may start or stop a capture)
+        with span("Time/rollout_prep"):
+            if is_attention:
+                # The attention context never crosses a rollout boundary: training
+                # attends within the rollout only, so acting resets its window here —
+                # the policies stay EXACTLY on-policy.
+                carry = (zero_state(num_envs), carry[1])
+            # the state at the rollout's start, which the update reads: a copy, since the acting
+            # steps overwrite theirs
+            state0 = jax.tree.map(jnp.copy, carry[0])
+            act_params = acting_params(params)
+            if update == start_update and obs_perf.perf_enabled(cfg):
+                note_boundary(act_params, *inputs.host(obs, prev_stored, is_first_np), carry)
         env_t0 = time.perf_counter()
         with timer("Time/env_interaction_time"):
             for _ in range(rollout_steps):
@@ -376,118 +378,125 @@ def main(ctx, cfg) -> None:
                 else:
                     env_act_np = env_act_np.astype(np.int32)  # ids come back in the float32 buffer, exact
                     env_actions = env_act_np[..., 0] if len(actions_dim) == 1 else env_act_np
-                next_obs, reward, terminated, truncated, info = envs.step(env_actions)
-                done = np.logical_or(terminated, truncated)
-                reward = np.asarray(reward, dtype=np.float32).reshape(num_envs)
+                with span("Rollout/env_step"):
+                    next_obs, reward, terminated, truncated, info = envs.step(env_actions)
+                    done = np.logical_or(terminated, truncated)
+                    reward = np.asarray(reward, dtype=np.float32).reshape(num_envs)
 
                 # Bootstrap truncated episodes with V(final_obs) under the current
                 # recurrent state (reference ppo_recurrent.py:309-335).  Every row goes
                 # through the model (nothing is written) and the truncated rows' values are
                 # kept; the previous action is the one just taken.
                 if truncated.any() and "final_obs" in info:
-                    trunc_idx = np.nonzero(truncated)[0]
-                    final_obs = {k: np.array(next_obs[k]) for k in obs_keys}
+                    with span("Rollout/truncation_value"):  # a device call over all rows and a fetch that waits for it
+                        trunc_idx = np.nonzero(truncated)[0]
+                        final_obs = {k: np.array(next_obs[k]) for k in obs_keys}
+                        for k in obs_keys:
+                            final_obs[k][trunc_idx] = np.stack([np.asarray(info["final_obs"][i][k]) for i in trunc_idx])
+                        taken = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
+                        v_final = value_fn(act_params, *inputs.host(final_obs, taken, not_first), carry[0])
+                        reward[trunc_idx] += gamma * np.asarray(jax.device_get(v_final))[trunc_idx]
+
+                with span("Rollout/store"):  # the row, and what the next acting call reads of this step
                     for k in obs_keys:
-                        final_obs[k][trunc_idx] = np.stack([np.asarray(info["final_obs"][i][k]) for i in trunc_idx])
-                    taken = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
-                    v_final = value_fn(act_params, *inputs.host(final_obs, taken, not_first), carry[0])
-                    reward[trunc_idx] += gamma * np.asarray(jax.device_get(v_final))[trunc_idx]
+                        step_data[k] = obs[k][None]
+                    step_data["actions"] = env_act_np.reshape(num_envs, -1).astype(np.float32)[None]
+                    step_data["prev_actions"] = prev_stored[None].copy()
+                    step_data["is_first"] = is_first_np[None].copy()
+                    step_data["logprobs"] = logprob_np.reshape(num_envs, 1)[None]
+                    step_data["values"] = value_np.reshape(num_envs, 1)[None]
+                    step_data["rewards"] = reward.reshape(num_envs, 1)[None]
+                    step_data["dones"] = done.astype(np.float32).reshape(num_envs, 1)[None]
+                    rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
-                for k in obs_keys:
-                    step_data[k] = obs[k][None]
-                step_data["actions"] = env_act_np.reshape(num_envs, -1).astype(np.float32)[None]
-                step_data["prev_actions"] = prev_stored[None].copy()
-                step_data["is_first"] = is_first_np[None].copy()
-                step_data["logprobs"] = logprob_np.reshape(num_envs, 1)[None]
-                step_data["values"] = value_np.reshape(num_envs, 1)[None]
-                step_data["rewards"] = reward.reshape(num_envs, 1)[None]
-                step_data["dones"] = done.astype(np.float32).reshape(num_envs, 1)[None]
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
-
-                prev_stored = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
-                prev_stored[done] = 0
-                is_first_np = done.astype(np.float32).reshape(num_envs, 1)
-                obs = host_obs(next_obs)
-                policy_step += num_envs * world
-                record_episode_stats(aggregator, info)
+                    prev_stored = _onehot_actions(env_act_np, actions_dim, is_continuous, as_ids=is_decoder)
+                    prev_stored[done] = 0
+                    is_first_np = done.astype(np.float32).reshape(num_envs, 1)
+                    obs = host_obs(next_obs)
+                    policy_step += num_envs * world
+                    record_episode_stats(aggregator, info)
         env_time = time.perf_counter() - env_t0
 
-        local = rb.to_tensor()
-        next_value = value_fn(act_params, *inputs.host(obs, prev_stored, is_first_np), carry[0])
-        act_params = None  # the decoder's copy goes before the update needs the room
-        returns, advantages = gae_fn(local["rewards"], local["values"], local["dones"], next_value[:, None])
-        seq_data = {
-            **{k: local[k] for k in obs_keys},
-            "actions": local["actions"],
-            "prev_actions": local["prev_actions"],
-            "is_first": local["is_first"],
-            "logprobs": local["logprobs"][..., 0],
-            "values": local["values"][..., 0],
-            "returns": returns[..., 0],
-            "advantages": advantages[..., 0],
-        }
+        with span("Time/update_prep"):  # the rollout's rows, the bootstrap and the advantages, the update's key
+            local = rb.to_tensor()
+            next_value = value_fn(act_params, *inputs.host(obs, prev_stored, is_first_np), carry[0])
+            act_params = None  # the decoder's copy goes before the update needs the room
+            returns, advantages = gae_fn(local["rewards"], local["values"], local["dones"], next_value[:, None])
+            seq_data = {
+                **{k: local[k] for k in obs_keys},
+                "actions": local["actions"],
+                "prev_actions": local["prev_actions"],
+                "is_first": local["is_first"],
+                "logprobs": local["logprobs"][..., 0],
+                "values": local["values"][..., 0],
+                "returns": returns[..., 0],
+                "advantages": advantages[..., 0],
+            }
 
-        clip_coef = cfg.algo.clip_coef
-        ent_coef = cfg.algo.ent_coef
-        if cfg.algo.anneal_clip_coef:
-            clip_coef = polynomial_decay(update, initial=clip_coef, final=0.0, max_decay_steps=num_updates)
-        if cfg.algo.anneal_ent_coef:
-            ent_coef = polynomial_decay(update, initial=ent_coef, final=0.0, max_decay_steps=num_updates)
+            clip_coef = cfg.algo.clip_coef
+            ent_coef = cfg.algo.ent_coef
+            if cfg.algo.anneal_clip_coef:
+                clip_coef = polynomial_decay(update, initial=clip_coef, final=0.0, max_decay_steps=num_updates)
+            if cfg.algo.anneal_ent_coef:
+                ent_coef = polynomial_decay(update, initial=ent_coef, final=0.0, max_decay_steps=num_updates)
 
-        key = ctx.rng()
-        if recorder is not None:  # device-array references only: no host sync
-            recorder.stage_step(
-                batch=seq_data,
-                # the decoder's update is given its parameters and moments to overwrite: no reference to them survives it
-                carry={} if is_decoder else {"params": params, "opt_state": opt_state, "state0": state0},
-                key=key,
-                scalars={"clip_coef": float(clip_coef), "ent_coef": float(ent_coef), "update": update},
-            )
+            key = ctx.rng()
+            if recorder is not None:  # device-array references only: no host sync
+                recorder.stage_step(
+                    batch=seq_data,
+                    # the decoder's update is given its parameters and moments to overwrite: no reference to them survives it
+                    carry={} if is_decoder else {"params": params, "opt_state": opt_state, "state0": state0},
+                    key=key,
+                    scalars={"clip_coef": float(clip_coef), "ent_coef": float(ent_coef), "update": update},
+                )
         with timer("Time/train_time"), monitor.phase("dispatch"):
             t0 = time.perf_counter()
-            params, opt_state, train_metrics = train_fn(
-                params, opt_state, seq_data, state0, key, clip_coef, ent_coef
-            )
-            train_metrics = jax.device_get(train_metrics)
+            with span("Time/update_call"):
+                params, opt_state, train_metrics = train_fn(
+                    params, opt_state, seq_data, state0, key, clip_coef, ent_coef
+                )
+            with span("Time/update_fetch"):  # waits for the update
+                train_metrics = jax.device_get(train_metrics)
             train_time = time.perf_counter() - t0
-        assert_finite(cfg, train_metrics, "ppo_recurrent/update")
-        for k, v in train_metrics.items():
-            aggregator.update(k, float(v))
+        with span("Time/update_after"):
+            assert_finite(cfg, train_metrics, "ppo_recurrent/update")
+            for k, v in train_metrics.items():
+                aggregator.update(k, float(v))
 
-        if logger is not None and (policy_step - last_log >= cfg.metric.log_every or update == num_updates or cfg.dry_run):
-            metrics = aggregator.compute()
-            metrics["Time/sps_train"] = (
-                cfg.algo.update_epochs * num_batches / train_time if train_time > 0 else 0.0
-            )
-            metrics["Time/sps_env_interaction"] = policy_steps_per_iter / world / env_time if env_time > 0 else 0.0
-            monitor.log_metrics(logger, metrics, policy_step)
-            aggregator.reset()
-            last_log = policy_step
+            if logger is not None and (policy_step - last_log >= cfg.metric.log_every or update == num_updates or cfg.dry_run):
+                metrics = aggregator.compute()
+                metrics["Time/sps_train"] = (
+                    cfg.algo.update_epochs * num_batches / train_time if train_time > 0 else 0.0
+                )
+                metrics["Time/sps_env_interaction"] = policy_steps_per_iter / world / env_time if env_time > 0 else 0.0
+                monitor.log_metrics(logger, metrics, policy_step)
+                aggregator.reset()
+                last_log = policy_step
 
-        def save_ckpt():
-            nonlocal last_checkpoint
-            path = ckpt_manager.save(
-                policy_step,
-                {
-                    "params": params,
-                    "opt_state": opt_state,
-                    "update": update,
-                    "policy_step": policy_step,
-                    "last_log": last_log,
-                    "last_checkpoint": policy_step,
-                },
-            )
-            last_checkpoint = policy_step
-            return path
+            def save_ckpt():
+                nonlocal last_checkpoint
+                path = ckpt_manager.save(
+                    policy_step,
+                    {
+                        "params": params,
+                        "opt_state": opt_state,
+                        "update": update,
+                        "policy_step": policy_step,
+                        "last_log": last_log,
+                        "last_checkpoint": policy_step,
+                    },
+                )
+                last_checkpoint = policy_step
+                return path
 
-        if (
-            cfg.checkpoint.every > 0
-            and (policy_step - last_checkpoint) >= cfg.checkpoint.every
-            or update == num_updates
-            and cfg.checkpoint.save_last
-        ):
-            save_ckpt()
-        guard.boundary(policy_step, save_ckpt)
+            if (
+                cfg.checkpoint.every > 0
+                and (policy_step - last_checkpoint) >= cfg.checkpoint.every
+                or update == num_updates
+                and cfg.checkpoint.save_last
+            ):
+                save_ckpt()
+            guard.boundary(policy_step, save_ckpt)
 
     monitor.close()
     envs.close()

@@ -594,17 +594,6 @@ def zero_state(sizes: DecoderConfig, n: int, dtype: Any) -> Dict[str, Any]:
     return {"pos": jnp.zeros((n,), jnp.int32), "layers": tuple(layers)}
 
 
-def carry_kinds(state: Dict[str, Any]) -> Dict[str, Dict[str, int]]:
-    """How many layers of a carry hold a cache of keys and values, how many a convolution
-    tail and how many a latent cache, with their bytes."""
-    out = {"cache": {"layers": 0, "bytes": 0}, "conv": {"layers": 0, "bytes": 0}, "latent": {"layers": 0, "bytes": 0}}
-    for layer_state in state["layers"]:
-        kind = out[next((name for name in ("conv", "latent") if name in layer_state), "cache")]
-        kind["layers"] += 1
-        kind["bytes"] += sum(x.nbytes for x in jax.tree.leaves(layer_state))
-    return out
-
-
 MATMUL_WEIGHTS = (
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head", "conv_in", "conv_out", "dense_gate", "dense_up", "dense_down",
     "wkv_a", "wkv_b", "shared_gate", "shared_up", "shared_down",

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sheeprl_tpu.obs.tracer import span
+
 
 _distributed_initialized = False
 
@@ -361,12 +363,13 @@ class MeshContext:
     def _draw(self, chain_attr: str, buf_attr: str, seed_fn) -> jax.Array:
         buf = getattr(self, buf_attr)
         if not buf:
-            chain = getattr(self, chain_attr)
-            if chain is None:
-                chain = seed_fn()
-            keys = jax.random.split(chain, self._RNG_BATCH + 1)
-            setattr(self, chain_attr, keys[0])
-            buf = [keys[i] for i in range(self._RNG_BATCH, 0, -1)]  # pop() keeps order
+            with span("Time/rng_refill"):  # an eager split and 64 eager slices, once in 64 draws
+                chain = getattr(self, chain_attr)
+                if chain is None:
+                    chain = seed_fn()
+                keys = jax.random.split(chain, self._RNG_BATCH + 1)
+                setattr(self, chain_attr, keys[0])
+                buf = [keys[i] for i in range(self._RNG_BATCH, 0, -1)]  # pop() keeps order
         sub = buf.pop()
         setattr(self, buf_attr, buf)
         return sub

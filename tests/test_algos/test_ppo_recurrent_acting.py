@@ -5,10 +5,11 @@ inputs go into the jitted call as host arrays, one a dtype, the sampling key liv
 donated carry, and nothing but the one buffer of per-env results comes back.
 
 Each model is run through the CLI for two rollouts with the module's ``jax`` / ``jnp`` seen
-through counting proxies and the update wrapped (as the benchmark's adapter wraps it), so the
-tests read what the program itself did: the calls it made in order, the rows it stored and
-the carry it handed to the update."""
+through counting proxies, the update wrapped (as the benchmark's adapter wraps it) and a span
+tracer active, so the tests read what the program itself did: the calls it made in order, the
+rows it stored, the carry it handed to the update and the spans it opened."""
 
+import re
 from unittest import mock
 
 import jax
@@ -19,6 +20,7 @@ import pytest
 from sheeprl_tpu.algos.ppo_recurrent import ppo_recurrent as program
 from sheeprl_tpu.cli import run
 from sheeprl_tpu.obs import perf as obs_perf
+from sheeprl_tpu.obs import tracer
 from sheeprl_tpu.parallel.mesh import MeshContext
 
 ENVS, STEPS, UPDATES = 4, 8, 2
@@ -70,8 +72,10 @@ class CountedJit:
 
 def rollouts(model, tmp_path, rank=0, extra=()):
     """Two rollouts and updates of ``model`` from seed 5 through ``cli.run``: the module's calls
-    in order, the rows each update was given, and the acting call's note."""
+    in order, the rows each update was given, the acting call's note, and the spans
+    ``(name, start, end, depth)`` in start order."""
     log, updates, notes = [], [], {}
+    spans = tracer.SpanTracer()
     real_make, real_local_rng = program.make_ppo_recurrent_train_fn, MeshContext.local_rng
 
     def make_train_fn(ctx, agent, cfg, obs_keys):
@@ -108,6 +112,7 @@ def rollouts(model, tmp_path, rank=0, extra=()):
         patch.setattr(program, "prepare_obs", lambda *args: log.append("prepare_obs"))
         patch.setattr(program, "make_ppo_recurrent_train_fn", make_train_fn)
         patch.setattr(MeshContext, "local_rng", local_rng)
+        patch.setattr(tracer, "_ACTIVE", spans)
         run(
             MODELS[model]
             + [
@@ -117,7 +122,9 @@ def rollouts(model, tmp_path, rank=0, extra=()):
             ]  # fmt: skip
         )
     assert len(updates) == UPDATES
-    return {"log": log, "updates": updates, "notes": notes}
+    events = sorted((e for e in spans.chrome_trace()["traceEvents"] if e["ph"] == "X"), key=lambda e: (e["ts"], -e["dur"]))
+    opened = [(e["name"], e["ts"], e["ts"] + e["dur"], e["args"]["depth"]) for e in events]
+    return {"log": log, "updates": updates, "notes": notes, "spans": opened}
 
 
 @pytest.fixture(scope="module")
@@ -169,6 +176,37 @@ def test_one_seed_stores_the_same_rows_and_another_rank_does_not(first_run, mode
         for name in ("actions", "logprobs", "values"):
             np.testing.assert_array_equal(a["rows"][name], b["rows"][name], err_msg=name)
     assert any((a["rows"]["actions"] != b["rows"]["actions"]).any() for a, b in zip(first, other))
+
+
+#: the acting iteration's spans in program order, a letter each: call, fetch, env step, the truncation's bootstrap, store
+ITERATION = {"Rollout/act_call": "c", "Rollout/action_fetch": "f", "Rollout/env_step": "e", "Rollout/truncation_value": "t", "Rollout/store": "s"}
+#: a cycle's outermost spans in program order: the update boundary's around the rollout and the update
+CYCLE = ("Time/rollout_prep", "Time/env_interaction_time", "Time/update_prep", "Time/train_time", "Time/update_after")
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_every_host_phase_of_the_cycle_is_a_span_in_program_order(first_run, model):
+    """Each acting iteration opens, in the rollout, the call, the fetch, the env's step, the
+    truncation's bootstrap where an episode was cut, and the store; each span of the update
+    boundary opens once an update, the update's call and fetch inside ``Time/train_time``."""
+    ran = first_run(model)
+    opened = ran["spans"]
+    cycle = [name for name, _, _, depth in opened if depth == 0 and name in CYCLE]
+    assert cycle == list(CYCLE) * UPDATES
+    rollouts = [(a, b) for name, a, b, depth in opened if name == "Time/env_interaction_time"]
+    bootstraps = 0
+    for a, b in rollouts:
+        inside = "".join(ITERATION.get(name, "?") for name, s, e, depth in opened if a <= s and e <= b and depth == 1)
+        assert re.fullmatch(f"(cfet?s){{{STEPS}}}", inside), inside
+        bootstraps += inside.count("t")
+    for a, b in [(a, b) for name, a, b, _ in opened if name == "Time/train_time"]:
+        assert [name for name, s, e, _ in opened if a <= s and e <= b and name.startswith("Time/update_")] == ["Time/update_call", "Time/update_fetch"]
+    # a bootstrap's span for every value call between two acting steps of one rollout, and no other
+    acts = [i for i, name in enumerate(ran["log"]) if name == "act"]
+    between = [ran["log"][i + 1 : j] for i, j in zip(acts, acts[1:]) if "train_fn" not in ran["log"][i + 1 : j]]
+    assert bootstraps == sum(calls.count("value") for calls in between)
+    if model == "decoder":
+        assert bootstraps >= 1
 
 
 #: The most the decoder's stored rows may lie from the update's evaluation in bfloat16 (log-probabilities

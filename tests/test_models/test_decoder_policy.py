@@ -42,6 +42,17 @@ MOON = {
 }  # fmt: skip
 
 
+def carry_kinds(state):
+    """How many layers of a carry hold a cache of keys and values, how many a convolution
+    tail and how many a latent cache, with their bytes."""
+    out = {"cache": {"layers": 0, "bytes": 0}, "conv": {"layers": 0, "bytes": 0}, "latent": {"layers": 0, "bytes": 0}}
+    for layer_state in state["layers"]:
+        kind = out[next((name for name in ("conv", "latent") if name in layer_state), "cache")]
+        kind["layers"] += 1
+        kind["bytes"] += sum(x.nbytes for x in jax.tree.leaves(layer_state))
+    return out
+
+
 def config_of(S) -> decoder.DecoderConfig:
     if "kv_lora_rank" in S:  # Moonlight's layer kinds
         return decoder.DecoderConfig(
@@ -434,7 +445,7 @@ def test_moonlights_carry_holds_a_third_kind_of_state_and_its_tree_is_the_refere
     state = decoder.zero_state(cfg, 3, jnp.bfloat16)
     assert [sorted(layer) for layer in state["layers"]] == [["latent", "pos"]] * 5
     assert state["layers"][0]["latent"].shape == (3, 48, 128) and state["layers"][0]["latent"].dtype == jnp.bfloat16
-    kinds = decoder.carry_kinds(state)
+    kinds = carry_kinds(state)
     assert kinds["latent"] == {"layers": 5, "bytes": 5 * 3 * 48 * (128 * 2 + 4)} and kinds["cache"]["layers"] == kinds["conv"]["layers"] == 0
     emptied = decoder.emptied(jax.tree.map(lambda x: x + 1, state["layers"][0]), jnp.asarray([True, False, False]))
     assert np.asarray(emptied["pos"])[0].tolist() == [-1] * 48 and np.asarray(emptied["pos"])[1].tolist() == [0] * 48
@@ -552,7 +563,7 @@ def test_smallthinkers_parameter_tree_and_carry_are_what_they_were():
     assert all(sorted(layer) == ["k", "pos", "v"] for layer in state["layers"]) and sorted(state) == ["layers", "pos"]
     assert [layer["k"].shape[1] for layer in state["layers"]] == [32, 8, 32, 8]
     none = {"layers": 0, "bytes": 0}
-    assert decoder.carry_kinds(state) == {"cache": {"layers": 4, "bytes": sum(x.nbytes for x in jax.tree.leaves(state["layers"]))}, "conv": none, "latent": none}
+    assert carry_kinds(state) == {"cache": {"layers": 4, "bytes": sum(x.nbytes for x in jax.tree.leaves(state["layers"]))}, "conv": none, "latent": none}
 
 
 def test_lfm2s_carry_holds_two_kinds_of_state_and_its_tree_is_the_references():
@@ -561,7 +572,7 @@ def test_lfm2s_carry_holds_two_kinds_of_state_and_its_tree_is_the_references():
     state = decoder.zero_state(cfg, 3, jnp.bfloat16)
     assert [sorted(layer) for layer in state["layers"]] == [["conv"], ["k", "pos", "v"], ["conv"], ["conv"], ["conv"]]
     assert state["layers"][0]["conv"].shape == (3, 2, 32) and state["layers"][0]["conv"].dtype == jnp.bfloat16
-    kinds = decoder.carry_kinds(state)
+    kinds = carry_kinds(state)
     assert kinds["conv"] == {"layers": 4, "bytes": 4 * 3 * 2 * 32 * 2} and kinds["cache"]["layers"] == 1
     one = decoder.zero_state(cfg, 1, jnp.float32)
     tree = jax.eval_shape(lambda k: decoder.DecoderPolicy(cfg).init(k, ids, ids, jnp.ones((1, 1)), one, method=decoder.DecoderPolicy.step), jax.random.PRNGKey(0))

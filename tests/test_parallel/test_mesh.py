@@ -53,6 +53,22 @@ def test_rng_chain_advances():
     assert not np.array_equal(np.asarray(k1), np.asarray(k2))
 
 
+def test_a_key_chain_refill_is_one_span_in_64_draws_and_the_chain_is_as_it_was():
+    from sheeprl_tpu.obs import tracer
+
+    spans = tracer.SpanTracer()
+    previous = tracer.set_active(spans)
+    try:
+        ctx = MeshContext(mesh=build_mesh(), seed=7)
+        keys = [ctx.rng() for _ in range(130)]
+    finally:
+        tracer.set_active(previous)
+    assert spans.percentiles()["Time/rng_refill"]["count"] == 3  # draws 1, 65 and 129
+    first = jax.random.split(jax.random.PRNGKey(7), 65)
+    np.testing.assert_array_equal(np.asarray(keys[0]), np.asarray(first[1]))
+    np.testing.assert_array_equal(np.asarray(keys[64]), np.asarray(jax.random.split(first[0], 65)[1]))
+
+
 def test_precision_policy():
     ctx = MeshContext(mesh=build_mesh(), precision="bf16-mixed")
     assert ctx.compute_dtype == jnp.bfloat16
