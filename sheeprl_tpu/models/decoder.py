@@ -60,6 +60,12 @@ way where its query rows a key head fill a whole bfloat16 tile (a latent layer's
 heads on its one key head), and otherwise forms its float32 scores over every slot whole.
 The update reports the share of key blocks it visited (``Attn/key_blocks_visited_share``);
 the trace notes ``blockwise_attention``, the acting call's tile too (``act_layer_<n>``).
+
+The expert layer, too, takes one of two programs by the shape of its call: a few tokens
+(an acting step's one a row) go through every held expert in one batched product a weight,
+which reads each expert's weights once; many (the update's chunk) go through grouped
+products of the tokens each expert was chosen for (``EVERY_HELD_TOKENS``).  The trace notes
+each layer's (``expert_path``: ``act_layer_<n>`` for one token a row, ``layer_<n>`` for a chunk).
 """
 
 from __future__ import annotations
@@ -182,6 +188,14 @@ ROUTER_EPS = 1e-6  # in the denominator of the sigmoid router's renormalisation
 #: the minor axis of the chip's memory tiles: a cache whose rows are narrower is given another
 #: layout on the device, and the acting step's one-row write then copies the cache whole, twice
 LANES = 128
+#: the most tokens an expert layer's call puts through every held expert (``expert_path``).  A
+#: product of N tokens does 2N flops for each weight it reads, N flops a byte of bfloat16;
+#: under the chip's ridge (TPU v5e: 197e12 flop/s over 819e9 B/s, ~240 flops a byte) it waits
+#: on the read, so every held expert over every token costs one read of the held weights,
+#: where grouped products walk a row tile (up to 512 rows) an expert that few tokens leave
+#: mostly empty.  128 leaves room under the ridge; an acting step's 32 or 64 tokens lie under
+#: it, an update's thousands far over it, and those keep the grouped products.
+EVERY_HELD_TOKENS = 128
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -245,6 +259,30 @@ def _grouped(rows: jax.Array, w: jax.Array, group_sizes: jax.Array, valid: jax.A
     return jnp.where(valid, jax.lax.ragged_dot(rows, w, group_sizes, precision=_precision(rows)), 0)
 
 
+def expert_path(tokens: int) -> str:
+    """Which of ``expert_layer``'s two programs a call of ``tokens`` tokens takes."""
+    return "every_held" if tokens <= EVERY_HELD_TOKENS else "grouped"
+
+
+def _every_held(
+    m: jax.Array, top_w: jax.Array, local: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+    activation: Callable[[jax.Array], jax.Array],
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:  # fmt: skip
+    """``expert_layer`` for few tokens: every token through every held expert, one batched
+    product a weight (the experts the batch dimension of both operands, so each expert's
+    weights are read once), the down product in the compute dtype as the grouped one leaves
+    it; then, in float32, weighted by ``top_w`` where the token chose the expert, by 0 where
+    it did not, and summed over the experts.  ``m`` and ``w_*`` are in the compute dtype."""
+    chosen = local[..., None] == jnp.arange(w_gate.shape[0])  # [N, K, E_held]
+    weight = jnp.where(chosen, top_w[..., None], 0.0).sum(1)  # [N, E_held]: one choice an expert at most
+    rows = jnp.broadcast_to(m, (w_gate.shape[0], *m.shape))
+    product = lambda a, w: jnp.einsum("end,edf->enf", a, w, precision=_precision(a))  # noqa: E731
+    y = product(activation(product(rows, w_gate)) * product(rows, w_up), w_down).astype(jnp.float32)
+    load = chosen.sum((0, 1))
+    counters = {"held": load.sum().astype(jnp.float32), "load_max": load.max().astype(jnp.float32), "dropped": jnp.float32(0.0)}
+    return jnp.sum(y * weight.T[..., None], 0), counters
+
+
 def expert_layer(
     m: jax.Array, top_w: jax.Array, top_i: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, offset: int, dtype: Any,
     activation: Callable[[jax.Array], jax.Array] = jax.nn.relu,
@@ -253,14 +291,20 @@ def expert_layer(
     ``top_w`` / ``top_i``: ``[N, K]`` over all the experts, ``w_*``: the held experts'
     weights ``[E_held, ...]``, which are experts ``offset .. offset + E_held - 1``.
 
-    The ``N * K`` assignments are sorted by expert, those of experts not held last; the
-    held ones' rows go through grouped products (``jax.lax.ragged_dot``: static shapes,
-    groups as long as the routing makes them, so no capacity and no dropped token) and
-    are added back into their tokens with the renormalised weights."""
+    The program follows from ``N`` (``expert_path``).  Over ``EVERY_HELD_TOKENS`` the ``N *
+    K`` assignments are sorted by expert, those of experts not held last; the held ones'
+    rows go through grouped products (``jax.lax.ragged_dot``: static shapes, groups as long
+    as the routing makes them, so no capacity and no dropped token) and are added back
+    into their tokens with the renormalised weights.  Up to it every token goes through
+    every held expert (``_every_held``): the same products of each (token, chosen expert)
+    in the same dtypes, and the others multiplied by 0."""
     N, K = top_i.shape
     held_n = w_gate.shape[0]
     local = top_i - offset
     held = (local >= 0) & (local < held_n)
+    if expert_path(N) == "every_held":
+        w_gate, w_up, w_down = w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype)
+        return _every_held(m.astype(dtype), top_w, local, w_gate, w_up, w_down, activation)
     group = jnp.where(held, local, held_n).reshape(-1)  # [N * K]; held_n: not held here
     order = jnp.argsort(group, stable=True)
     token = order // K
@@ -459,6 +503,7 @@ class DecoderLayer(nn.Module):
             top_w, top_i, moved = routed(m)
         with scope("policy/experts"):
             y, routing = expert_layer(m, top_w, top_i, w_gate, w_up, w_down, c.expert_offset, dt, act)
+        note("expert_path", {f"{'act_' if T == 1 else ''}layer_{self.layer}": expert_path(B * T)})
         counters = {**counters, **routing}
         if moved is not None:
             counters["bias_moved"] = moved.sum().astype(jnp.float32)

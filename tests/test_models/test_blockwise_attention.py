@@ -2,7 +2,7 @@
 interpret mode here): outputs, the gradients of the queries and of the chunk's own keys
 and values, and the key-block flags against ``ring_attention._grouped_attention``, which
 forms the scores whole; and the kernels compiled at the benchmark's widths for a
-described chip."""
+described chip, with the acting call's expert layer beside them."""
 
 import jax
 import jax.numpy as jnp
@@ -564,3 +564,26 @@ def test_an_acting_step_compiles_for_the_chip_with_the_kernel_where_its_rows_fil
     assert text.count("tpu_custom_call") == kernels
     cache_sized = [line for line in text.splitlines() if " copy(" in line and f"bf16[{B},{slots}," in line.split(" copy(")[0]]
     assert not cache_sized, cache_sized
+
+
+# one acting call's expert layer for a described v5e: (tokens, experts held, experts a token, width, expert width)
+ACTING_EXPERTS = {"moonlight": (32, 8, 6, 2048, 1408), "lfm2": (64, 8, 4, 2048, 1792), "smallthinker": (64, 16, 6, 2560, 768)}
+
+
+@pytest.mark.parametrize("cell", list(ACTING_EXPERTS))
+def test_an_acting_calls_expert_layer_compiles_for_the_chip_with_no_grouped_product_and_no_copy_of_a_weight(cell, topology):
+    """At the cell's widths with bfloat16 weights: every token goes through every held expert
+    (``decoder.expert_layer`` at an acting call's token count), no grouped product is left, and
+    no expert weight is copied or transposed on its way into the batched products."""
+    from jax.sharding import SingleDeviceSharding
+
+    n, held, k, width, expert_width = ACTING_EXPERTS[cell]
+    assert decoder.expert_path(n) == "every_held"
+    placed = SingleDeviceSharding(topology.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=placed)  # noqa: E731
+    weights = [(held, width, expert_width), (held, width, expert_width), (held, expert_width, width)]
+    layer = jax.jit(lambda m, top_w, top_i, *w: decoder.expert_layer(m, top_w, top_i, *w, 0, jnp.bfloat16, jax.nn.silu))
+    text = layer.lower(shaped((n, width), jnp.float32), shaped((n, k), jnp.float32), shaped((n, k), jnp.int32), *map(shaped, weights)).compile().as_text()
+    assert "ragged" not in text
+    results = [line.split(" = ", 1)[1] for line in text.splitlines() if " copy(" in line or " transpose(" in line]
+    assert not [r for r in results if any(r.startswith("bf16[%s]" % ",".join(map(str, s))) for s in weights)]

@@ -4,6 +4,9 @@ benchmark's plain references (``perfbench/configs/smallthinker21b_1of4_reference
 ``perfbench/configs/moonlight16b_1of8_reference.py``, which share no code with it or with
 each other) on seeded weights, at a small size on the CPU: window 8, 8 experts, float32."""
 
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -581,8 +584,16 @@ def test_lfm2s_carry_holds_two_kinds_of_state_and_its_tree_is_the_references():
     assert kinds["latent"] == {"layers": 0, "bytes": 0} and sorted(tree["params"]["layers_1"]) == sorted(lfm2.layer_shapes(LFM2, 1))  # no leaf of the new kinds
 
 
-def test_no_assignment_is_dropped_when_every_token_picks_the_same_expert():
-    """No capacity: the grouped products take groups as long as the routing makes them."""
+def take_path(monkeypatch, path):
+    """Every call of ``decoder.expert_layer`` takes ``path``, whatever its token count."""
+    monkeypatch.setattr(decoder, "EVERY_HELD_TOKENS", 10**9 if path == "every_held" else 0)
+
+
+@pytest.mark.parametrize("path", ["grouped", "every_held"])
+def test_no_assignment_is_dropped_when_every_token_picks_the_same_expert(path, monkeypatch):
+    """No capacity: the grouped products take groups as long as the routing makes them, and
+    every token through every held expert leaves out no chosen pair either."""
+    take_path(monkeypatch, path)
     rng = np.random.default_rng(5)
     N, D, F, E = 24, 16, 8, 4
     m = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
@@ -594,6 +605,133 @@ def test_no_assignment_is_dropped_when_every_token_picks_the_same_expert():
     want = 0.7 * (jax.nn.relu(m @ w_gate[1]) * (m @ w_up[1])) @ w_down[1]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
     assert float(counters["held"]) == N and float(counters["load_max"]) == N and float(counters["dropped"]) == 0.0
+
+
+#: the three cells' acting calls at a small width: (tokens, experts held, experts a token)
+ACTING_CALLS = {"moonlight": (32, 8, 6), "lfm2": (64, 8, 4), "smallthinker": (64, 16, 6)}
+
+
+def routed(routing, n, held, k, rng):
+    """``(m [n, 64], top_w, top_i)`` over four times the held experts, the chip holding the
+    second quarter.  ``no_held``: a quarter of the tokens chose no expert held here;
+    ``one_expert``: every token chose held expert 1, and otherwise experts not held;
+    ``biased``: a sigmoid router whose selection bias lifts two held experts over the rest."""
+    E = 4 * held
+    m = jnp.asarray(rng.standard_normal((n, 64)), jnp.float32)
+    w_router = jnp.asarray(rng.standard_normal((64, E)) * 0.3, jnp.float32)
+    if routing == "biased":
+        top_w, top_i, moved = decoder.route(m, w_router, k, True, jnp.zeros(E).at[held + 2].set(0.5).at[held + 5].set(0.5))
+        assert 0 < int(moved.sum()) < n
+        return m, top_w, top_i
+    top_w, top_i, _ = decoder.route(m, w_router, k, True)
+    top_i, away = np.array(top_i), np.concatenate([np.arange(held), np.arange(2 * held, E)])
+    if routing == "no_held":
+        top_i[: n // 4] = [rng.choice(away, k, replace=False) for _ in range(n // 4)]
+    else:
+        top_i = np.stack([np.concatenate([[held + 1], rng.choice(away, k - 1, replace=False)]) for _ in range(n)])
+    return m, top_w, jnp.asarray(top_i, jnp.int32)
+
+
+@pytest.mark.parametrize("routing", ["no_held", "one_expert", "biased"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("cell", list(ACTING_CALLS))
+def test_every_held_expert_gives_what_the_grouped_products_give(cell, dtype, routing, monkeypatch):
+    """An acting call's expert layer, both ways: every token through every held expert
+    against grouped products of the tokens each expert was chosen for.  The same output,
+    within one rounding of the compute dtype (float32: its own), and the same counters."""
+    n, held, k = ACTING_CALLS[cell]
+    rng = np.random.default_rng(k * held + n)
+    m, top_w, top_i = routed(routing, n, held, k, rng)
+    weights = [jnp.asarray(rng.standard_normal(shape) * 0.2, jnp.float32) for shape in ((held, 64, 32), (held, 64, 32), (held, 32, 64))]
+    got = {}
+    for path in ("grouped", "every_held"):
+        take_path(monkeypatch, path)
+        got[path] = jax.jit(lambda *a: decoder.expert_layer(*a, held, jnp.dtype(dtype), jax.nn.silu))(m, top_w, top_i, *weights)
+    (want, want_n), (out, out_n) = got["grouped"], got["every_held"]
+    want, out = np.asarray(want), np.asarray(out)
+    rounding = 2.0**-8 if dtype == "bfloat16" else 2e-6
+    assert np.abs(out - want).max() <= rounding * np.abs(want).max() and np.abs(want).max() > 0.1
+    assert {name: float(x) for name, x in out_n.items()} == {name: float(x) for name, x in want_n.items()}
+    none = ~np.any((np.asarray(top_i) >= held) & (np.asarray(top_i) < 2 * held), -1)  # tokens that chose no expert held here
+    assert not out[none].any()
+    if routing == "no_held":
+        assert none[: n // 4].all()
+    if routing == "one_expert":
+        assert float(out_n["held"]) == float(out_n["load_max"]) == n
+
+
+#: the three decoder cells: configuration, traffic, and the small sizes above that carry the keys this file's configs read
+CELLS = {"smallthinker": ("smallthinker21b_1of4", "rl_gen", SIZES), "lfm2": ("lfm2_8b_a1b_1of4", "rl_gen", LFM2), "moonlight": ("moonlight16b_1of8", "rl_gen32", MOON)}
+
+
+def rehearsal_of(model):
+    """A cell's sizes as its CPU rehearsal runs them (its configuration's file) and the rows
+    and tokens a row of its traffic: the acting call's token count and the update's."""
+    name, traffic, small = CELLS[model]
+    root = pathlib.Path(__file__).resolve().parents[2] / "perfbench"
+    config = json.loads((root / "configs" / f"{name}.json").read_text())
+    sizes = {**config["sizes"], **config["rehearsal"]["sizes"]}
+    mix = json.loads((root / "traffic" / f"{traffic}.json").read_text())
+    return {**small, **{key: sizes[key] for key in small if key in sizes}}, mix["num_envs"], mix["rollout_steps"]
+
+
+@pytest.mark.parametrize("model", list(CELLS))
+def test_an_acting_step_goes_through_every_held_expert_and_the_update_keeps_its_grouped_products(model):
+    """At each decoder cell's rehearsal widths and its traffic's token counts (an acting call
+    of one token a row, an update of the whole rollout): the acting step's program holds no
+    grouped product, the update's forward pass its three a layer, and the trace notes each
+    expert layer's path under the call's kind."""
+    from sheeprl_tpu.obs import perf as obs_perf
+
+    S, envs, steps = rehearsal_of(model)
+    cfg = config_of(S)
+    policy = decoder.DecoderPolicy(cfg, jnp.bfloat16)
+    state = decoder.zero_state(cfg, envs, jnp.bfloat16)
+    ids, chunk = jnp.zeros((envs,), jnp.int32), jnp.zeros((envs, steps), jnp.int32)
+    weights = jax.eval_shape(lambda key: policy.init(key, ids, ids, jnp.ones((envs, 1)), state, method=decoder.DecoderPolicy.step), jax.random.PRNGKey(0))
+    obs_perf.reset()
+    try:
+        act = str(jax.make_jaxpr(lambda w: policy.apply(w, ids, ids, jnp.ones((envs, 1)), state, method=decoder.DecoderPolicy.step))(weights))
+        update = str(jax.make_jaxpr(lambda w: policy.apply(w, chunk, chunk, jnp.ones((envs, steps)), state))(weights))
+        notes = dict(obs_perf._notes["expert_path"])
+    finally:
+        obs_perf.reset()
+    layers = range(cfg.dense_layers, cfg.layers)
+    assert envs <= decoder.EVERY_HELD_TOKENS < envs * steps and len(layers) == cfg.expert_layers > 0
+    assert "ragged_dot" not in act and update.count("ragged_dot_general[") == 3 * len(layers)
+    assert notes == {**{f"act_layer_{i}": "every_held" for i in layers}, **{f"layer_{i}": "grouped" for i in layers}}
+
+
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "moonlight"])
+def test_an_acting_steps_log_probabilities_are_the_updates_within_bfloat16_rounding(model, monkeypatch):
+    """A bfloat16 policy, every expert held, 4 rows x 40 tokens: the acting steps (one token a
+    row: every held expert) against the update's one pass over the whole chunk (160 tokens:
+    grouped products), log-probabilities of every vocabulary entry.  The acting step is as
+    near the update as it was through grouped products, within one bfloat16 rounding of a
+    log-probability, and the two acting paths are that near each other."""
+    ref, S = model_of(model, monkeypatch)
+    S = {**S, "experts_held": S["num_experts"]}
+    weights = ref.make_weights(S, 11)
+    n, t = 4, 40
+    tokens, prev, is_first = sequences(np.random.default_rng(1), n, t, S["vocab_held"], [(0, 25), (0, 13, 30), (0, 9, 10, 31), (0,)])
+    cfg = config_of(S)
+    policy = decoder.DecoderPolicy(cfg, jnp.bfloat16)
+    assert decoder.expert_path(n) == "every_held" and decoder.expert_path(n * t) == "grouped"
+    hidden = jax.jit(policy.apply)(weights, tokens, prev, is_first, decoder.zero_state(cfg, n, jnp.bfloat16))[0]
+    update = np.asarray(jax.nn.log_softmax(policy.apply(weights, hidden, method=decoder.DecoderPolicy.logits)))
+    acting = {}
+    for path, cutoff in (("every_held", decoder.EVERY_HELD_TOKENS), ("grouped", 0)):
+        monkeypatch.setattr(decoder, "EVERY_HELD_TOKENS", cutoff)
+        step = jax.jit(lambda state, tok, prv, first: policy.apply(weights, tok, prv, first, state, method=decoder.DecoderPolicy.step))
+        state, rows = decoder.zero_state(cfg, n, jnp.bfloat16), []
+        for i in range(t):
+            (logits,), _, state = step(state, tokens[:, i], prev[:, i], is_first[:, i : i + 1])
+            rows.append(np.asarray(jax.nn.log_softmax(logits)))
+        acting[path] = np.stack(rows, 1)
+    rounding = 2.0**-8 * np.abs(update).max()
+    gap = lambda a, b: float(np.abs(a - b).max())  # noqa: E731
+    assert gap(acting["every_held"], update) <= gap(acting["grouped"], update) + rounding
+    assert gap(acting["every_held"], acting["grouped"]) <= rounding
 
 
 def test_the_chunked_head_loss_is_the_whole_one():
