@@ -165,6 +165,17 @@ def acting_boundary(compiled, args) -> Dict[str, int]:
     }
 
 
+def epoch_keys(key: jax.Array, epochs: int) -> jax.Array:
+    """The key of each of an update's ``epochs``, from the update's own ``key``."""
+    return jax.random.split(key, epochs)
+
+
+def epoch_minibatches(epoch_key: jax.Array, num_envs: int, num_batches: int) -> jax.Array:
+    """``[num_batches, num_envs // num_batches]``: the envs of each sequence minibatch of one
+    epoch, a permutation of the envs drawn from the epoch's key."""
+    return jax.random.permutation(epoch_key, num_envs).reshape(num_batches, num_envs // num_batches)
+
+
 def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
     """Optimizer + the jitted BPTT sequence-minibatch update
     ``train_fn(params, opt_state, seq_data, state0, key, clip_coef, ent_coef)``; ``state0``
@@ -227,14 +238,14 @@ def make_ppo_recurrent_train_fn(ctx, agent, cfg, obs_keys):
             return (p, o_state), aux
 
         def epoch_step(carry, ekey):
-            perm = jax.random.permutation(ekey, num_envs).reshape(num_batches, mb_envs)
+            perm = epoch_minibatches(ekey, num_envs, num_batches)
             carry, auxs = jax.lax.scan(mb_step, carry, perm)
             means = jax.tree.map(jnp.mean, auxs)
             if is_decoder:  # the minibatch before the epoch's first step, not the mean over them
                 means["Health/ratio_first_epoch"] = auxs["Health/ratio_first_epoch"][0]
             return carry, means
 
-        keys = jax.random.split(key, cfg.algo.update_epochs)
+        keys = epoch_keys(key, cfg.algo.update_epochs)
         (p, o_state), per_epoch = jax.lax.scan(epoch_step, (p, o_state), keys)
         metrics = jax.tree.map(jnp.mean, per_epoch)
         if is_decoder:

@@ -1,15 +1,18 @@
 """A sparse-expert decoder as a sequence policy: RMSNorm, rotary embedding, grouped-query
 attention of a chunk against a carried cache, latent attention over a compressed cache
-that keys and values share, a gated short convolution with a carried tail, a dense or an
-expert feed-forward, and an expert layer that is told which experts it holds.
+that keys and values share, a gated short convolution with a carried tail, a Mamba-2
+state-space mixer with a carried state, a dense or an expert feed-forward, and an expert
+layer that is told which experts it holds.
 
 ``DecoderConfig`` describes layer kinds, not one model (``howto/decoder_policy.md`` gives
-the equations of the three published models that run on it).  A layer is ``h = x +
-Mixer(norm(x))``, ``out = h + FFN(norm(h))``.  Its *mixer* is per layer (``mixers``) full
-attention, sliding-window attention, latent attention, or a gated short convolution;
-attention may norm each head's queries and keys (``qk_norm``) and rotates them where
+the equations of the four published models that run on it).  A layer has two parts, ``h = x +
+Mixer(norm(x))``, ``out = h + FFN(norm(h))``, or (``hybrid_override_pattern``) one of them
+alone: ``out = x + Mixer(norm(x))`` (``ffn_layout`` 0) or ``out = x + FFN(norm(x))`` (mixer
+``none``).  Its *mixer* is per layer (``mixers``) full
+attention, sliding-window attention, latent attention, a gated short convolution, or a
+Mamba-2 mixer; attention may norm each head's queries and keys (``qk_norm``) and rotates them where
 ``rope_layout`` says.  Its *feed-forward* is dense in the ``dense_layers`` leading layers and
-an expert mixture after them, gated by ``activation``, with (``shared_width``) a dense part
+an expert mixture after them, gated by ``activation`` or (``relu2``) not gated, with (``shared_width``) a dense part
 beside it that every token passes.  The *router* keeps the ``experts_per_token``
 largest of a softmax over all ``num_experts``, or (``router="sigmoid"``) of sigmoid scores
 plus a selection bias that the weights do not see, and scales the kept weights by
@@ -35,21 +38,32 @@ sum.  A row is kept as ``[c, k_pe, zeros]``, ``latent_width`` wide (whole lanes)
 queries are padded alike.  ``W_kv_b`` is a weight wherever it multiplies, so it takes
 gradient through the cached rows a query sees, while the cache itself takes none.
 
+The *Mamba-2 mixer* (NemotronH's): ``[z | xBC | dt] = a W_in`` (``mamba_heads x mamba_head_dim``,
+that plus ``2 x ssm_groups x ssm_state``, ``mamba_heads`` wide); ``xBC = silu(taps(xBC) + bias)``
+over ``conv_taps`` inputs (``causal_taps``); ``xBC = [x | B | C]``; per head ``dt =
+softplus(dt + dt_bias)``, ``A = -exp(A_log)``, the scan of ``ops/ssd_scan.py`` (chunked over
+``ssm_chunk`` tokens for a chunk, the one-token recurrence for an acting step) plus ``D x``;
+then ``y = GroupRMSNorm(y * silu(z))`` over ``ssm_groups`` groups and ``out = y W_out``.
+
 The carry is a tree: ``{"pos": [B], "layers": (state of layer 0, ...)}`` with a layer's
 state by its mixer's kind: ``{"k", "v", "pos"}``, ``{"latent": [B, capacity, latent_width],
-"pos"}`` or ``{"conv": [B, taps - 1, D]}``.
+"pos"}``, ``{"conv": [B, taps - 1, D]}`` or ``{"ssm": [B, heads, head_dim, state], "conv":
+[B, taps - 1, width of xBC]}`` (the state in float32, the tail in the compute dtype); a layer
+without a mixer carries ``{}``.
 ``pos`` is the row's next position inside its episode; an attention layer's cache holds
 keys (rotated already) and values in ``capacity`` slots (full layers) or ``window`` slots
 (a ring), each with the position it holds (``-1``: empty), written at ``position %
 slots`` (``k`` and ``v`` are one array ``[B, slots, Hkv, hd]``, or, for heads narrower than
 the chip's ``LANES``, a tuple of ``[B, slots, 1, LANES]``: ``lane_grouped_attention``); a
-convolution layer's tail holds the last ``taps - 1`` gated inputs of the row's
-episode, oldest first (zeros before its first token).  The shapes are static, so a
+convolution's tail holds the last ``taps - 1`` inputs of its taps in the row's
+episode, oldest first (zeros before its first token); a state-space layer's state is the
+decayed sum of its episode so far.  The shapes are static, so a
 step's cost does not depend on the fill.  A chunk of ``T`` tokens attends to the cache
-and to itself by position (``ops.ring_attention.grouped_attention``) and convolves over
-the tail and itself by segment: acting is the chunk of one token, whose keys and gated
-input are then written; training reads the carry as it stood when the rollout began and
-writes nothing.  An episode that starts empties its row of every layer's state.
+and to itself by position (``ops.ring_attention.grouped_attention``), convolves over
+the tail and itself by segment and scans from the carried state by segment: acting is the
+chunk of one token, whose keys, taps' input and state are then written; training reads
+the carry as it stood when the rollout began and writes nothing.  An episode that starts
+empties its row of every layer's state.
 
 Attention takes one of two programs by the shape of its call.  A chunk (the update) goes
 blockwise through the cache, a key block at a time with an online softmax, the scores
@@ -60,6 +74,8 @@ way where its query rows a key head fill a whole bfloat16 tile (a latent layer's
 heads on its one key head), and otherwise forms its float32 scores over every slot whole.
 The update reports the share of key blocks it visited (``Attn/key_blocks_visited_share``);
 the trace notes ``blockwise_attention``, the acting call's tile too (``act_layer_<n>``).
+A chunk through a state-space layer reports the share of its scan's (row, chunk) pairs
+that an episode's start cuts (``SSM/resets_in_chunk_share``); the trace notes ``ssd_scan``.
 
 The expert layer, too, takes one of two programs by the shape of its call: a few tokens
 (an acting step's one a row) go through every held expert in one batched product a weight,
@@ -79,6 +95,7 @@ import jax.numpy as jnp
 
 from sheeprl_tpu.obs.perf import note, scope
 from sheeprl_tpu.ops.ring_attention import grouped_attention
+from sheeprl_tpu.ops.ssd_scan import chunks_of, resets_in_chunks, ssd_scan, ssd_step
 
 
 @dataclass(frozen=True)
@@ -94,20 +111,20 @@ class DecoderConfig:
     vocab_held: int
     layers: int
     window: int
-    mixers: Tuple[str, ...]  # per layer: "full" | "window" (attention) | "latent" (attention over a compressed cache) | "conv" (gated short convolution)
+    mixers: Tuple[str, ...]  # per layer: "full" | "window" (attention) | "latent" (attention over a compressed cache) | "conv" (gated short convolution) | "mamba" (Mamba-2) | "none"
     rope_layout: Tuple[int, ...]  # per attention layer: 1 = rotary embedding, 0 = no positional encoding
     rope_theta: float = 1.5e6
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
     expert_offset: int = 0
     capacity: int = 8192  # slots of a full-attention layer's cache
-    conv_taps: int = 3  # a convolution layer's kernel; it carries ``conv_taps - 1`` gated inputs
+    conv_taps: int = 3  # a convolution's kernel (a conv layer's, a Mamba-2 mixer's); it carries ``conv_taps - 1`` inputs
     qk_norm: bool = False  # RMSNorm of each head's queries and keys before the rotation
     dense_layers: int = 0  # leading layers whose feed-forward is dense, ``dense_width`` wide
     dense_width: int = 0
     router: str = "softmax"  # "sigmoid": chosen by score + ``expert_bias``, weighted by the score alone
     router_reads: str = "input"  # "input": the layer's input | "ffn_norm": the normed state the experts read
-    activation: str = "relu"  # the gate of the feed-forward: "relu" (ReGLU) | "silu" (SwiGLU)
+    activation: str = "relu"  # the gate of the feed-forward: "relu" (ReGLU) | "silu" (SwiGLU) | "relu2" (no gate: relu(m W_up)^2 W_down)
     tie_embeddings: bool = False  # the head is the embedding table
     # a "latent" layer: what it compresses keys and values to, a head's part without and with rotation, a head's values
     kv_lora_rank: int = 0
@@ -116,15 +133,29 @@ class DecoderConfig:
     v_head_dim: int = 0
     shared_width: int = 0  # of the dense feed-forward that every token passes beside its routed experts (0: none)
     routed_scale: float = 1.0  # on the routed experts' weights after their renormalisation; the shared part is not scaled
+    ffn_layout: Tuple[int, ...] = ()  # per layer: 1 = it has a feed-forward part, 0 = its mixer alone; () = every layer has one
+    # a "mamba" layer: heads of the scan and their width, groups of B and C, the state's width a head, the update's scan chunk
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_chunk: int = 128
 
     @classmethod
     def from_cfg(cls, d: Any) -> "DecoderConfig":
         layers = int(d["layers"])
         cyc = lambda xs: tuple(xs[i % len(xs)] for i in range(layers))  # noqa: E731
-        if d.get("layer_types") is not None:
+        ffn_layout: Tuple[int, ...] = ()
+        if d.get("hybrid_override_pattern"):  # one part a layer, by the published pattern's first ``layers`` blocks
+            pattern = str(d["hybrid_override_pattern"])
+            if len(pattern) < layers or set(pattern) - set(PATTERN):
+                raise ValueError(f"hybrid_override_pattern {pattern!r} does not give {layers} blocks of {sorted(PATTERN)}")
+            mixers, ffn_layout = (tuple(part) for part in zip(*(PATTERN[ch] for ch in pattern[:layers])))
+        elif d.get("layer_types") is not None:
             mixers = tuple(LAYER_TYPES[t] for t in cyc(d["layer_types"]))
         else:
             mixers = tuple("window" if w else "full" for w in cyc(d["sliding_window_layout"]))
+        shared = d.get("moe_shared_expert_intermediate_size")  # published where the shared expert is not a multiple of a routed one
         return cls(
             hidden_size=int(d["hidden_size"]),
             head_dim=int(d["head_dim"]),
@@ -156,16 +187,31 @@ class DecoderConfig:
             qk_nope_head_dim=int(d["qk_nope_head_dim"]),
             qk_rope_head_dim=int(d["qk_rope_head_dim"]),
             v_head_dim=int(d["v_head_dim"]),
-            shared_width=int(d["n_shared_experts"]) * int(d["moe_ffn_hidden_size"]),
+            shared_width=int(shared) if shared else int(d["n_shared_experts"]) * int(d["moe_ffn_hidden_size"]),
             routed_scale=float(d["routed_scaling_factor"]),
+            ffn_layout=ffn_layout,
+            mamba_heads=int(d.get("mamba_num_heads", 0)),
+            mamba_head_dim=int(d.get("mamba_head_dim", 0)),
+            ssm_groups=int(d.get("n_groups", 0)),
+            ssm_state=int(d.get("ssm_state_size", 0)),
+            ssm_chunk=int(d.get("chunk_size", 128)),
         )
 
     def slots(self, layer: int) -> int:
         return self.window if self.mixers[layer] == "window" else self.capacity
 
+    def feed_forward(self, layer: int) -> bool:
+        """Whether the layer has a feed-forward part."""
+        return not self.ffn_layout or bool(self.ffn_layout[layer])
+
     @property
     def expert_layers(self) -> int:
-        return self.layers - min(self.dense_layers, self.layers)
+        return sum(self.feed_forward(layer) for layer in range(min(self.dense_layers, self.layers), self.layers))
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """What a Mamba-2 mixer's taps convolve: ``x``, then ``B`` and ``C`` of every group."""
+        return self.mamba_heads * self.mamba_head_dim + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def lane_groups(self) -> int:
@@ -183,7 +229,12 @@ class DecoderConfig:
 
 #: the published configs' names for a layer's mixer -> ``DecoderConfig.mixers``
 LAYER_TYPES = {"conv": "conv", "full_attention": "full", "sliding_attention": "window", "latent_attention": "latent"}
-ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+#: a block of a published ``hybrid_override_pattern`` (NemotronH's) -> its mixer and whether it has a feed-forward part
+PATTERN = {"M": ("mamba", 0), "*": ("full", 0), "E": ("none", 1)}
+#: the feed-forward's activation.  "relu" and "silu" gate: ``(act(m W_gate) * (m W_up)) W_down``;
+#: those of ``UNGATED`` have no gate: ``act(m W_up) W_down`` ("relu2": relu squared)
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu, "relu2": lambda v: jnp.square(jax.nn.relu(v))}
+UNGATED = ("relu2",)
 ROUTER_EPS = 1e-6  # in the denominator of the sigmoid router's renormalisation
 #: the minor axis of the chip's memory tiles: a cache whose rows are narrower is given another
 #: layout on the device, and the acting step's one-row write then copies the cache whole, twice
@@ -196,6 +247,18 @@ LANES = 128
 #: mostly empty.  128 leaves room under the ridge; an acting step's 32 or 64 tokens lie under
 #: it, an update's thousands far over it, and those keep the grouped products.
 EVERY_HELD_TOKENS = 128
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -exp(A_log)`` of head ``h`` is ``-(h + 1)``: the family's initialisation."""
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of steps spread geometrically over the family's ``time_step_min``
+    .. ``time_step_max`` (0.001 .. 0.1)."""
+    step = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), shape[0])).astype(dtype)
+    return step + jnp.log(-jnp.expm1(-step))
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -264,8 +327,16 @@ def expert_path(tokens: int) -> str:
     return "every_held" if tokens <= EVERY_HELD_TOKENS else "grouped"
 
 
+def _hidden(activation: Callable[[jax.Array], jax.Array], product: Callable[[jax.Array], jax.Array], w_gate: Optional[jax.Array], w_up: jax.Array) -> jax.Array:
+    """A feed-forward's hidden activations from ``product(w)``, the input times ``w``: gated,
+    ``activation(x W_gate) * (x W_up)``, or without a gate (``w_gate`` ``None``), ``activation(x W_up)``."""
+    if w_gate is None:
+        return activation(product(w_up))
+    return activation(product(w_gate)) * product(w_up)
+
+
 def _every_held(
-    m: jax.Array, top_w: jax.Array, local: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+    m: jax.Array, top_w: jax.Array, local: jax.Array, w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array,
     activation: Callable[[jax.Array], jax.Array],
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:  # fmt: skip
     """``expert_layer`` for few tokens: every token through every held expert, one batched
@@ -273,23 +344,24 @@ def _every_held(
     weights are read once), the down product in the compute dtype as the grouped one leaves
     it; then, in float32, weighted by ``top_w`` where the token chose the expert, by 0 where
     it did not, and summed over the experts.  ``m`` and ``w_*`` are in the compute dtype."""
-    chosen = local[..., None] == jnp.arange(w_gate.shape[0])  # [N, K, E_held]
+    chosen = local[..., None] == jnp.arange(w_up.shape[0])  # [N, K, E_held]
     weight = jnp.where(chosen, top_w[..., None], 0.0).sum(1)  # [N, E_held]: one choice an expert at most
-    rows = jnp.broadcast_to(m, (w_gate.shape[0], *m.shape))
+    rows = jnp.broadcast_to(m, (w_up.shape[0], *m.shape))
     product = lambda a, w: jnp.einsum("end,edf->enf", a, w, precision=_precision(a))  # noqa: E731
-    y = product(activation(product(rows, w_gate)) * product(rows, w_up), w_down).astype(jnp.float32)
+    y = product(_hidden(activation, lambda w: product(rows, w), w_gate, w_up), w_down).astype(jnp.float32)
     load = chosen.sum((0, 1))
     counters = {"held": load.sum().astype(jnp.float32), "load_max": load.max().astype(jnp.float32), "dropped": jnp.float32(0.0)}
     return jnp.sum(y * weight.T[..., None], 0), counters
 
 
 def expert_layer(
-    m: jax.Array, top_w: jax.Array, top_i: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, offset: int, dtype: Any,
+    m: jax.Array, top_w: jax.Array, top_i: jax.Array, w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array, offset: int, dtype: Any,
     activation: Callable[[jax.Array], jax.Array] = jax.nn.relu,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:  # fmt: skip
     """The part of the mixture that the experts held here give: ``m``: ``[N, D]`` (normed),
     ``top_w`` / ``top_i``: ``[N, K]`` over all the experts, ``w_*``: the held experts'
-    weights ``[E_held, ...]``, which are experts ``offset .. offset + E_held - 1``.
+    weights ``[E_held, ...]``, which are experts ``offset .. offset + E_held - 1``; without
+    ``w_gate`` (``None``) an expert is not gated: ``activation(m W_up) W_down``.
 
     The program follows from ``N`` (``expert_path``).  Over ``EVERY_HELD_TOKENS`` the ``N *
     K`` assignments are sorted by expert, those of experts not held last; the held ones'
@@ -299,11 +371,12 @@ def expert_layer(
     every held expert (``_every_held``): the same products of each (token, chosen expert)
     in the same dtypes, and the others multiplied by 0."""
     N, K = top_i.shape
-    held_n = w_gate.shape[0]
+    held_n = w_up.shape[0]
     local = top_i - offset
     held = (local >= 0) & (local < held_n)
+    cast = lambda w: None if w is None else w.astype(dtype)  # noqa: E731
     if expert_path(N) == "every_held":
-        w_gate, w_up, w_down = w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype)
+        w_gate, w_up, w_down = cast(w_gate), w_up.astype(dtype), w_down.astype(dtype)
         return _every_held(m.astype(dtype), top_w, local, w_gate, w_up, w_down, activation)
     group = jnp.where(held, local, held_n).reshape(-1)  # [N * K]; held_n: not held here
     order = jnp.argsort(group, stable=True)
@@ -312,8 +385,8 @@ def expert_layer(
     n_held = group_sizes.sum()
     valid = (jnp.arange(N * K) < n_held)[:, None]
     rows = jnp.where(valid, m.astype(dtype)[token], 0)
-    w_gate, w_up, w_down = w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype)
-    h = activation(_grouped(rows, w_gate, group_sizes, valid)) * _grouped(rows, w_up, group_sizes, valid)
+    w_gate, w_up, w_down = cast(w_gate), w_up.astype(dtype), w_down.astype(dtype)
+    h = _hidden(activation, lambda w: _grouped(rows, w, group_sizes, valid), w_gate, w_up)
     y = _grouped(h, w_down, group_sizes, valid).astype(jnp.float32)
     weight = jnp.where(held, top_w, 0.0).reshape(-1)[order]
     out = jnp.zeros((N, m.shape[-1]), jnp.float32).at[token].add(y * weight[:, None])
@@ -398,8 +471,9 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, state, q_pos, q_seg):
         """``x``: ``[B, T, D]`` float32, ``state``: the layer's carried state -> the layer's
         output, what the chunk made for that state (keys and values ``[B, T, Hkv, hd]``,
-        or the gated inputs ``[B, T, D]``) and the layer's counters: the expert layer's, and
-        the key blocks of its cache that a chunk's attention had and visited."""
+        the gated inputs ``[B, T, D]``, or a state-space mixer's state after the chunk and
+        its taps' inputs) and the layer's counters: the expert layer's, and the key blocks
+        of its cache that a chunk's attention had and visited."""
         c, dt = self.cfg, self.dtype
         D, hd, Hq, Hkv = c.hidden_size, c.head_dim, c.heads_held, c.kv_heads_held
         init = nn.initializers.normal(0.02)
@@ -416,7 +490,7 @@ class DecoderLayer(nn.Module):
                 top_w, top_i, moved = route(r, w_router, c.experts_per_token, c.norm_topk_prob, bias)
                 return top_w * c.routed_scale, top_i, moved
 
-        if not dense and c.router_reads == "input":
+        if not dense and c.router_reads == "input" and c.feed_forward(self.layer):
             top_w, top_i, moved = routed(x.reshape(B * T, D))
         if kind == "conv":
             conv_norm = self.param("conv_norm", nn.initializers.ones, (D,))
@@ -456,6 +530,42 @@ class DecoderLayer(nn.Module):
                 o = jnp.einsum("bthr,rhd->bthd", o, w_uv, precision=_precision(o)).astype(dt)
                 h = x + _dot(o.reshape(B, T, Hq * dv), wo.astype(dt), preferred_element_type=jnp.float32)
             made = {"latent": own[:, :, 0]}
+        elif kind == "mamba":
+            H, P, G, N = c.mamba_heads, c.mamba_head_dim, c.ssm_groups, c.ssm_state
+            inner, width = H * P, c.ssm_conv_width
+            mamba_norm = self.param("mamba_norm", nn.initializers.ones, (D,))
+            mamba_in = self.param("mamba_in", init, (D, inner + width + H))
+            mamba_conv = self.param("mamba_conv", init, (c.conv_taps, width))
+            mamba_conv_bias = self.param("mamba_conv_bias", nn.initializers.zeros, (width,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
+            a_log = self.param("A_log", _a_log_init, (H,))
+            d_skip = self.param("D", nn.initializers.ones, (H,))
+            gate_norm = self.param("mamba_gate_norm", nn.initializers.ones, (inner,))
+            mamba_out = self.param("mamba_out", init, (inner, D))
+            with scope("policy/mamba"):
+                a = rms_norm(x, mamba_norm, c.rms_norm_eps).astype(dt)
+                z, xbc, dt_in = jnp.split(_dot(a, mamba_in.astype(dt)), [inner, inner + width], -1)  # xbc in the compute dtype, as the tail carries it
+                taps = jax.nn.silu(causal_taps(xbc, state["conv"], mamba_conv, q_seg) + mamba_conv_bias)
+                xs, b_in, c_in = jnp.split(taps, [inner, inner + G * N], -1)
+                xs, b_in, c_in = xs.reshape(B, T, H, P), b_in.reshape(B, T, G, N), c_in.reshape(B, T, G, N)
+                step_dt = jax.nn.softplus(dt_in.astype(jnp.float32) + dt_bias)
+                A = -jnp.exp(a_log.astype(jnp.float32))
+            with scope("policy/ssd_scan"):
+                if T == 1:  # an acting step: the recurrence on the carried state, empty where the episode starts
+                    start = jnp.where((q_seg[:, 0] == 0)[:, None, None, None], state["ssm"], 0.0)
+                    y, ssm = ssd_step(xs[:, 0], step_dt[:, 0], A, b_in[:, 0], c_in[:, 0], start, dt)
+                    y = y[:, None]
+                else:
+                    y, ssm = ssd_scan(xs, step_dt, A, b_in, c_in, q_seg, state["ssm"], c.ssm_chunk, dt)
+                    Q = min(c.ssm_chunk, T)
+                    note("ssd_scan", {f"layer_{self.layer}": {"chunk": Q, "chunks": chunks_of(T, Q)}})
+            with scope("policy/mamba"):
+                y = (y + d_skip[:, None] * xs) * jax.nn.silu(z.astype(jnp.float32)).reshape(B, T, H, P)
+                y = rms_norm(y.reshape(B, T, G, inner // G), gate_norm.reshape(G, inner // G), c.rms_norm_eps)
+                h = x + _dot(y.reshape(B, T, inner).astype(dt), mamba_out.astype(dt), preferred_element_type=jnp.float32)
+            made = {"ssm": ssm, "conv": xbc}
+        elif kind == "none":
+            h, made = x, {}
         else:
             attn_norm = self.param("attn_norm", nn.initializers.ones, (D,))
             wq = self.param("wq", init, (D, Hq * hd))
@@ -484,6 +594,9 @@ class DecoderLayer(nn.Module):
                 counters = _blocks_visited(visited, self.layer, T)
                 h = x + jnp.dot(o.reshape(B, T, Hq * hd), wo.astype(dt), preferred_element_type=jnp.float32)
             made = {"k": k, "v": v}
+        if not c.feed_forward(self.layer):
+            return h, made, counters
+        gated = c.activation not in UNGATED
         ffn_norm = self.param("ffn_norm", nn.initializers.ones, (D,))
         if dense:
             F = c.dense_width
@@ -494,7 +607,7 @@ class DecoderLayer(nn.Module):
                 m = rms_norm(h, ffn_norm, c.rms_norm_eps).astype(dt)
                 g = act(_dot(m, dense_gate.astype(dt))) * _dot(m, dense_up.astype(dt))
                 return h + _dot(g, dense_down.astype(dt), preferred_element_type=jnp.float32), made, counters
-        w_gate = self.param("w_gate", init, (c.experts_held, D, c.expert_width))
+        w_gate = self.param("w_gate", init, (c.experts_held, D, c.expert_width)) if gated else None
         w_up = self.param("w_up", init, (c.experts_held, D, c.expert_width))
         w_down = self.param("w_down", init, (c.experts_held, c.expert_width, D))
         with scope("policy/experts"):
@@ -508,12 +621,12 @@ class DecoderLayer(nn.Module):
         if moved is not None:
             counters["bias_moved"] = moved.sum().astype(jnp.float32)
         if c.shared_width:  # every token's, whatever it was routed to; every chip of a group computes it alike
-            shared_gate = self.param("shared_gate", init, (D, c.shared_width))
+            shared_gate = self.param("shared_gate", init, (D, c.shared_width)) if gated else None
             shared_up = self.param("shared_up", init, (D, c.shared_width))
             shared_down = self.param("shared_down", init, (c.shared_width, D))
             with scope("policy/shared_expert"):
                 ms = m.astype(dt)
-                g = act(_dot(ms, shared_gate.astype(dt))) * _dot(ms, shared_up.astype(dt))
+                g = _hidden(act, lambda w: _dot(ms, w.astype(dt)), shared_gate, shared_up)
                 y = y + _dot(g, shared_down.astype(dt), preferred_element_type=jnp.float32)
         return h + y.reshape(B, T, D), made, counters
 
@@ -558,6 +671,8 @@ class DecoderPolicy(nn.Module):
             hidden = rms_norm(x, self.final_norm, c.rms_norm_eps)
             values = (jnp.dot(hidden, self.value_w.astype(jnp.float32)) + self.value_b)[..., 0]
         aux = {}
+        if "mamba" in c.mixers and tokens.shape[1] > 1:  # a chunk went through the scan
+            aux["SSM/resets_in_chunk_share"] = resets_in_chunks(is_first, c.ssm_chunk)
         if "key_blocks" in totals:  # a chunk's attention went blockwise through the caches
             aux["Attn/key_blocks_visited_share"] = totals["key_blocks_visited"] / totals["key_blocks"]
         if "held" in totals:
@@ -587,6 +702,12 @@ class DecoderPolicy(nn.Module):
         rows, pos = jnp.arange(tokens.shape[0]), q_pos[:, 0]
         layers = []
         for old, made in zip(state["layers"], written):
+            if "ssm" in old:
+                layers.append({"ssm": made["ssm"], "conv": jnp.concatenate([old["conv"][:, 1:], made["conv"].astype(old["conv"].dtype)], 1)})
+                continue
+            if not old:  # a layer without a mixer carries nothing
+                layers.append(old)
+                continue
             if "conv" in old:
                 layers.append({"conv": jnp.concatenate([old["conv"][:, 1:], made["conv"].astype(old["conv"].dtype)], 1)})
                 continue
@@ -608,6 +729,10 @@ def _into_slot(cache, new: jax.Array, rows: jax.Array, slot: jax.Array):
 def emptied(layer_state: Dict[str, jax.Array], first: jax.Array) -> Dict[str, jax.Array]:
     """A layer's carried state with the rows of ``first`` (``[B]``) empty: an episode that
     starts forgets the one before it, whichever kind of state the layer carries."""
+    if "ssm" in layer_state:
+        return {"ssm": jnp.where(first[:, None, None, None], 0.0, layer_state["ssm"]), "conv": jnp.where(first[:, None, None], 0, layer_state["conv"])}
+    if not layer_state:
+        return layer_state
     if "conv" in layer_state:
         return {"conv": jnp.where(first[:, None, None], 0, layer_state["conv"])}
     return {**layer_state, "pos": jnp.where(first[:, None], -1, layer_state["pos"])}
@@ -624,6 +749,13 @@ def zero_state(sizes: DecoderConfig, n: int, dtype: Any) -> Dict[str, Any]:
     """The carry of ``n`` rows before their first token: every slot and every tail empty."""
     layers = []
     for i in range(sizes.layers):
+        if sizes.mixers[i] == "mamba":  # the state in float32 whatever the compute dtype: a decayed sum over the whole episode
+            ssm = jnp.zeros((n, sizes.mamba_heads, sizes.mamba_head_dim, sizes.ssm_state), jnp.float32)
+            layers.append({"ssm": ssm, "conv": jnp.zeros((n, sizes.conv_taps - 1, sizes.ssm_conv_width), dtype)})
+            continue
+        if sizes.mixers[i] == "none":
+            layers.append({})
+            continue
         if sizes.mixers[i] == "conv":
             layers.append({"conv": jnp.zeros((n, sizes.conv_taps - 1, sizes.hidden_size), dtype)})
             continue
@@ -641,7 +773,7 @@ def zero_state(sizes: DecoderConfig, n: int, dtype: Any) -> Dict[str, Any]:
 
 MATMUL_WEIGHTS = (
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head", "conv_in", "conv_out", "dense_gate", "dense_up", "dense_down",
-    "wkv_a", "wkv_b", "shared_gate", "shared_up", "shared_down",
+    "wkv_a", "wkv_b", "shared_gate", "shared_up", "shared_down", "mamba_in", "mamba_out",
 )  # fmt: skip
 #: leaves that are no trained weight: no gradient reaches them and the optimizer leaves them as they are
 BUFFERS = ("expert_bias",)
